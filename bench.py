@@ -17,41 +17,39 @@ chip) — a single-chip rate divided by a pod target would understate the
 port 32x.
 """
 
+import contextlib
 import json
 import os
 import sys
 import time
 
-# allow platform override for local debugging (e.g. TTS_BENCH_PLATFORM=cpu)
-if os.environ.get("TTS_BENCH_PLATFORM"):
-    os.environ["JAX_PLATFORMS"] = os.environ["TTS_BENCH_PLATFORM"]
-    import jax
-    jax.config.update("jax_platforms", os.environ["TTS_BENCH_PLATFORM"])
+import jax
+import numpy as np
 
-from tpu_tree_search.utils import device_info  # noqa: E402
-
-# Backend bootstrap: on a TPU-less host the pinned default backend
-# fails to initialize (the `RuntimeError: Unable to initialize backend`
-# every BENCH_r0*.json tail used to end in, rc=1). Degrade instead of
-# die: fall back to automatic selection, then to cpu, and STAMP the
-# resolved platform + a degraded flag on every emitted row so a CPU
-# rate can never masquerade as a TPU rate (tools/perf_sentry.py skips
-# rate comparison on degraded rows).
-PLATFORM, DEGRADED = device_info.resolve_backend()
-if DEGRADED:
-    print(f"# backend degraded: default platform unavailable, running "
-          f"on {PLATFORM!r}", file=sys.stderr)
-
-import numpy as np  # noqa: E402
-
-from tpu_tree_search.utils import compile_cache  # noqa: E402
+from tpu_tree_search.engine import device
+from tpu_tree_search.ops import batched
+from tpu_tree_search.problems import taillard
+from tpu_tree_search.tune import defaults as tune_defaults
+from tpu_tree_search.utils import compile_cache
 
 compile_cache.enable()
 
-from tpu_tree_search.engine import device  # noqa: E402
-from tpu_tree_search.ops import batched  # noqa: E402
-from tpu_tree_search.problems import taillard  # noqa: E402
-from tpu_tree_search.tune import defaults as tune_defaults  # noqa: E402
+
+def device_stamp() -> dict:
+    """The device every row ran on. A run that finds no TPU fails: a CPU
+    rate is measured only when JAX_PLATFORMS=cpu asks for it."""
+    devices = jax.devices()
+    platform = devices[0].platform
+    if platform != "tpu" and os.environ.get("JAX_PLATFORMS") != "cpu":
+        raise SystemExit(
+            f"bench.py: no TPU found (JAX platform {platform!r}); set "
+            "JAX_PLATFORMS=cpu to measure the CPU on purpose")
+    return {"platform": platform, "device_kind": devices[0].device_kind,
+            "device_count": len(devices)}
+
+
+STAMP = device_stamp()
+
 
 # north-star: 1e9 node-evals/s on a v5p-32 pod (BASELINE.json), so the
 # single-chip bar is its 1/32 share
@@ -120,10 +118,8 @@ def bench_segment_gap(p, ub, inst: int):
         "direction": "lower",
         "segments": int(n),
         "overlap": int(overlap),
-        "platform": PLATFORM,
+        **STAMP,
     }
-    if DEGRADED:
-        row["degraded"] = True
     print(json.dumps(row))
     print(f"# segment_gap mean={gap * 1e3:.3f}ms over {n} boundaries "
           f"(overlap={int(overlap)})", file=sys.stderr)
@@ -150,31 +146,25 @@ def bench_cold_start(p, inst: int):
               "cannot round-trip a serialized executable",
               file=sys.stderr)
         return
-    import jax
 
     mesh = worker_mesh(None)       # the full-mesh serving shape
     root = tempfile.mkdtemp(prefix="tts_aot_bench_")
-    # the module-level compile_cache.enable() would let XLA's
-    # persistent cache serve the "cold" pass's compile (any second
-    # round on the same host) — a near-warm value that would then own
-    # perf_sentry's lower-is-better cold reference forever and false-
-    # FAIL every genuinely-cold later round. Point the cache at this
-    # bench's own throwaway dir so cold means cold.
-    old_cache_dir = jax.config.jax_compilation_cache_dir
-    jax.config.update("jax_compilation_cache_dir",
-                      os.path.join(root, "xla_cache"))
     try:
         for mode in ("cold", "warm"):
             # fresh in-process caches each pass: the second lifetime
             # sees ONLY the disk entry the first one persisted — the
-            # restart scenario, not a memo hit
+            # restart scenario, not a memo hit. The cold pass runs with
+            # XLA's persistent cache off, so cold means cold on every
+            # later run of the bench too.
             aot = AOTCache(root)
             cache = ExecutorCache(aot=aot)
-            t0 = time.perf_counter()
-            how = distributed.prewarm(p, lb_kind=1, chunk=64,
-                                      capacity=1 << 16, mesh=mesh,
-                                      loop_cache=cache)
-            dt = time.perf_counter() - t0
+            with (compile_cache.disabled() if mode == "cold"
+                  else contextlib.nullcontext()):
+                t0 = time.perf_counter()
+                how = distributed.prewarm(p, lb_kind=1, chunk=64,
+                                          capacity=1 << 16, mesh=mesh,
+                                          loop_cache=cache)
+                dt = time.perf_counter() - t0
             aot.drain()
             aot.close()
             row = {
@@ -184,15 +174,12 @@ def bench_cold_start(p, inst: int):
                 "direction": "lower",
                 "cache_mode": mode,
                 "how": how,          # compile (cold) / disk (warm)
-                "platform": PLATFORM,
+                **STAMP,
             }
-            if DEGRADED:
-                row["degraded"] = True
             print(json.dumps(row))
             print(f"# cold_start mode={mode} how={how} "
                   f"executor_ready={dt:.3f}s", file=sys.stderr)
     finally:
-        jax.config.update("jax_compilation_cache_dir", old_cache_dir)
         shutil.rmtree(root, ignore_errors=True)
 
 
@@ -215,8 +202,6 @@ def bench_ramp_drain(inst: int):
     process with a shared executor cache; only the second (compile-
     free) pass is measured, so a cold XLA compile cannot read as ramp
     time. TTS_BENCH_RAMPDRAIN=0 skips."""
-    import jax
-
     from tpu_tree_search.engine import distributed
     from tpu_tree_search.service.executors import ExecutorCache
     from tpu_tree_search.utils import config as cfg
@@ -272,12 +257,10 @@ def bench_ramp_drain(inst: int):
         "unit": "seconds_below_half_chunk_occupancy",
         "direction": "lower", "ladder": int(ladder_on),
         "chunk": chunk, "segments": len(segs),
-        "wall_s": round(wall, 4), "platform": PLATFORM,
+        "wall_s": round(wall, 4), **STAMP,
     }
     if never_filled:
         base["never_filled"] = True
-    if DEGRADED:
-        base["degraded"] = True
     name = f"pfsp_ta{inst:03d}j{jobs}"
     for phase, value in (("ramp", ramp), ("drain", drain)):
         print(json.dumps({"metric": f"{name}_{phase}_s",
@@ -345,10 +328,8 @@ def bench_hbm_bytes(p, ub, inst, lbs):
             "chunk": chunk,
             "tile": tile,
             "fused": int(fused_mode != "off"),
-            "platform": PLATFORM,
+            **STAMP,
         }
-        if DEGRADED:
-            row["degraded"] = True
         print(json.dumps(row))
         print(f"# hbm_bytes lb={lb_kind} fused={fused_mode} "
               f"how={how} bytes={int(value):,}", file=sys.stderr)
@@ -414,10 +395,8 @@ def bench_serve_rps():
         "unit": "requests_per_sec",
         "requests": n,
         "megabatch": int(mb),
-        "platform": PLATFORM,
+        **STAMP,
     }
-    if DEGRADED:
-        row["degraded"] = True
     print(json.dumps(row))
     print(f"# serve_rps megabatch={int(mb)} n={n} wall={dt:.3f}s "
           f"rate={rate:.3f} req/s", file=sys.stderr)
@@ -528,10 +507,8 @@ def bench_portfolio_speedup():
         "race_evals": race_evals,
         "solo_evals_sum": sum(solo_evals),
         "solo_wall_sum": round(sum(solo_walls), 3),
-        "platform": PLATFORM,
+        **STAMP,
     }
-    if DEGRADED:
-        row["degraded"] = True
     print(json.dumps(row))
     print(f"# portfolio k={k} best={best} race_wall={race_wall:.3f}s "
           f"best_solo={solo_best:.3f}s solo_sum={sum(solo_walls):.3f}s "
@@ -624,12 +601,10 @@ def main():
             "unit": "node_evals_per_sec",
             "vs_baseline": round(rate / PER_CHIP_TARGET, 4),
             "baseline": BASELINE_LABEL,
-            "platform": PLATFORM,
+            **STAMP,
             **tuned_row,
             **fused_row,
         }
-        if DEGRADED:
-            row["degraded"] = True
         # with TTS_SEARCH_TELEMETRY=1 the row also captures SEARCH
         # efficiency (pruning quality, frontier position, pool
         # pressure), not just throughput — future BENCH_*.json rounds

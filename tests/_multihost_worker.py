@@ -28,18 +28,10 @@ def main():
     ckpt = sys.argv[5] if len(sys.argv) > 5 else None
     max_rounds = int(sys.argv[6]) if len(sys.argv) > 6 else None
     os.environ["JAX_PLATFORMS"] = "cpu"
-    # 4 virtual devices per process: newer jax takes a config knob, the
-    # pinned 0.4.x line only reads XLA_FLAGS at first backend init (the
-    # same fallback pair as tests/conftest.py)
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 
     import jax
 
-    jax.config.update("jax_platforms", "cpu")
-    try:
-        jax.config.update("jax_num_cpu_devices", 4)
-    except AttributeError:
-        pass
+    jax.config.update("jax_num_cpu_devices", 4)     # per process
     jax.distributed.initialize(coordinator_address=f"localhost:{port}",
                                num_processes=nproc, process_id=pid)
     assert jax.process_count() == nproc

@@ -20,14 +20,22 @@ mesh:
 - when serialization is unsupported (per-program or probe-wide) the
   cache degrades to in-memory-only, loudly but harmlessly;
 - the health layer's compile_storm rule does NOT fire on a boot-time
-  disk replay (true unplanned compiles still fire it).
+  disk replay (true unplanned compiles still fire it);
+- on XLA:CPU an executable served by XLA's persistent compilation
+  cache is never persisted (its bytes would load and then fail).
+
+Each test runs with XLA's persistent cache off (a CLI test earlier in
+the same worker turns it on): the contracts above are about the disk
+tier against fresh compiles.
 """
 
+import contextlib
 import json
 import os
 import sys
 import time
 
+import jax
 import pytest
 
 from tpu_tree_search.engine import distributed
@@ -39,6 +47,7 @@ from tpu_tree_search.service.aot_cache import (AOTCache, probe,
 from tpu_tree_search.service import aot_cache as aot_mod
 from tpu_tree_search.service import executors as ex_mod
 from tpu_tree_search.service.executors import ExecutorCache
+from tpu_tree_search.utils import compile_cache
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..",
                                 "tools"))
@@ -57,6 +66,31 @@ def run_search(p, cache, mesh=None, **kw):
                              mesh=mesh or worker_mesh(4),
                              loop_cache=cache, **args)
     return (got.explored_tree, got.explored_sol, got.best)
+
+
+@pytest.fixture(autouse=True)
+def _no_xla_cache():
+    with compile_cache.disabled():
+        yield
+
+
+@contextlib.contextmanager
+def xla_cache(path):
+    """XLA's persistent compilation cache on, in `path`, caching every
+    program however fast it compiles."""
+    from jax.experimental.compilation_cache import compilation_cache
+    names = ("jax_enable_compilation_cache", "jax_compilation_cache_dir",
+             "jax_persistent_cache_min_compile_time_secs")
+    old = [getattr(jax.config, n) for n in names]
+    for n, v in zip(names, (True, str(path), 0)):
+        jax.config.update(n, v)
+    compilation_cache.reset_cache()
+    try:
+        yield
+    finally:
+        for n, v in zip(names, old):
+            jax.config.update(n, v)
+        compilation_cache.reset_cache()
 
 
 @pytest.fixture
@@ -191,6 +225,63 @@ def test_fingerprint_mismatch_ignored_never_loaded(tmp_path,
     assert no_fresh_compiles == []
     assert aot_b2.snapshot()["hits"] == 1
     aot_b2.close()
+
+
+def test_xla_cache_served_executable_not_persisted(tmp_path):
+    """XLA:CPU serializes an executable that its persistent cache
+    served into bytes that load and then fail at execution ("Function
+    ... not found"). The disk tier must keep such an executable in
+    memory, so that a restart compiles instead of failing."""
+    inst = small(8, jobs=8)
+    root = tmp_path / "aot"
+    with xla_cache(tmp_path / "xla"):
+        ref = run_search(inst.p_times, ExecutorCache())   # fills XLA's
+        aot1 = AOTCache(root)
+        c1 = ExecutorCache(aot=aot1)
+        assert run_search(inst.p_times, c1) == ref
+        (rec,) = c1.ledger_snapshot()
+        assert rec["source"] == "compile" and rec["xla_cache_hit"]
+        aot1.drain()
+        assert aot1.snapshot()["writes"] == 0
+        aot1.close()
+        aot2 = AOTCache(root)
+        c2 = ExecutorCache(aot=aot2)
+        assert run_search(inst.p_times, c2) == ref
+        assert [e["source"] for e in c2.ledger_snapshot()] == ["compile"]
+        aot2.close()
+
+
+def test_old_format_entry_ignored_not_quarantined(tmp_path,
+                                                   no_fresh_compiles):
+    """An entry written in another payload layout (header ``v``) is a
+    wrong-world entry, like a fingerprint mismatch: ignored, counted,
+    recompiled over — never loaded, never quarantined as corrupt."""
+    inst = small(5, jobs=8)
+    root = tmp_path / "aot"
+    aot1 = AOTCache(root)
+    ref = run_search(inst.p_times, ExecutorCache(aot=aot1))
+    aot1.drain()
+    aot1.close()
+    no_fresh_compiles.clear()
+
+    (entry,) = [p for p in root.iterdir() if p.suffix == ".aot"]
+    blob = entry.read_bytes()
+    off = len(aot_mod.MAGIC)
+    (hdr_len,) = aot_mod._HDR_LEN.unpack_from(blob, off)
+    off += aot_mod._HDR_LEN.size
+    header = json.loads(blob[off:off + hdr_len])
+    assert header["v"] == aot_mod.FORMAT
+    old = json.dumps({**header, "v": aot_mod.FORMAT - 1}).encode()
+    entry.write_bytes(aot_mod.MAGIC + aot_mod._HDR_LEN.pack(len(old))
+                      + old + blob[off + hdr_len:])
+
+    aot2 = AOTCache(root)
+    assert run_search(inst.p_times, ExecutorCache(aot=aot2)) == ref
+    assert len(no_fresh_compiles) == 1
+    snap = aot2.snapshot()
+    assert snap["mismatches"] == 1 and snap["hits"] == 0
+    assert snap["quarantined"] == 0 and snap["errors"] == 0
+    aot2.close()
 
 
 @pytest.mark.parametrize("damage", ["flip", "truncate"])

@@ -140,7 +140,7 @@ def test_lb2_multiword_bitmask_matches_scalar(jobs, machines):
 
 
 def test_lb2_j500_matches_scalar():
-    """The 500-job envelope (VERDICT r4 #5): the XLA LB2 path at J=500
+    """The 500-job envelope: the XLA LB2 path at J=500
     (sched_words=16 bitmask words, int32 pool aux — aux_dtype's
     overflow fallback) against the scalar oracle. Parents sit near the
     leaves so the scalar side stays cheap (J - depth children each),

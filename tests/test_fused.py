@@ -9,11 +9,9 @@ next on-chip round — the kernel LOGIC is what CI can and must pin):
   blocks — across lb 1/2, tile-remainder chunk sizes, the distributed
   8-worker driver, and a ladder run that switches rungs mid-solve, all
   with the node-conservation audit hard-failing (TTS_AUDIT_HARD);
-- admission is the expand kernel's exact shape rule: a shape
-  pallas_expand.kernel_shape_ok rejects must NEVER reach the fused
-  kernels on the hardware route (fused_ok is THE shared gate), and the
-  hw route is TPU-backend-only; the interpreter route exists to
-  validate logic and admits any shape;
+- admission: the hardware route is refused (Mosaic cannot lower the
+  kernels' sort), and the interpreter route exists to validate logic
+  and admits any shape of the LB1/LB2 steps;
 - spill semantics: a chunk whose survivors outgrow the kernel's
   cap_width keeps an exact COUNT (stores stop, the counter keeps
   accumulating) and a valid pruned-bound histogram, and the stored
@@ -29,14 +27,13 @@ next on-chip round — the kernel LOGIC is what CI can and must pin):
 import numpy as np
 import pytest
 
-import jax
 import jax.numpy as jnp
 
 from tpu_tree_search.engine import device, distributed
 from tpu_tree_search.engine.ladder import (fused_for, rungs_for,
                                            rungs_from_profile)
 from tpu_tree_search.obs import tracelog
-from tpu_tree_search.ops import batched, pallas_expand, pallas_fused
+from tpu_tree_search.ops import batched, pallas_fused
 from tpu_tree_search.parallel.mesh import worker_mesh
 from tpu_tree_search.problems.pfsp import PFSPInstance
 
@@ -186,34 +183,15 @@ def test_fused_parity_ladder_switches_mid_solve(monkeypatch):
 # ------------------------------------------------------------ admission
 
 
-def test_fused_ok_shares_the_expand_shape_rule(monkeypatch):
-    # the hardware route sits behind kernel_shape_ok EXACTLY: a shape
-    # the expand kernel rejects must never reach the fused kernels
-    # (the negative half is the PR's gating fix)
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    accepted = (20, 1024, 1, 20)
-    rejected = (8, 64, 1, 3)        # below min_tile(8): expand says no
-    assert pallas_expand.kernel_shape_ok(*accepted[:3],
-                                         machines=accepted[3])
-    assert pallas_fused.fused_ok("hw", *accepted)
-    assert not pallas_expand.kernel_shape_ok(*rejected[:3],
-                                             machines=rejected[3])
-    assert not pallas_fused.fused_ok("hw", *rejected)
-    # the LB2 lane-budget halving is part of the rule too
-    assert not pallas_expand.kernel_shape_ok(20, 1024, 2, machines=20)
-    assert not pallas_fused.fused_ok("hw", 20, 1024, 2, 20)
-
-
-def test_fused_ok_gates(monkeypatch):
-    # off mode admits nothing; unknown bounds admit nothing; the hw
-    # route is TPU-backend-only regardless of shape; the interpreter
-    # route validates logic and admits any shape
-    assert not pallas_fused.fused_ok("off", 20, 1024, 1, 20)
-    assert not pallas_fused.fused_ok("interpret", 20, 1024, 0, 20)
-    assert not pallas_fused.fused_ok("interpret", 20, 1024, 3, 20)
-    assert jax.default_backend() != "tpu"
-    assert not pallas_fused.fused_ok("hw", 20, 1024, 1, 20)
-    assert pallas_fused.fused_ok("interpret", 8, 64, 1, 3)
+def test_fused_ok_gates():
+    # off mode admits nothing; unknown bounds admit nothing; no hw
+    # route exists; the interpreter route admits LB1 and LB2
+    assert not pallas_fused.fused_ok("off", 1)
+    assert not pallas_fused.fused_ok("interpret", 0)
+    assert not pallas_fused.fused_ok("interpret", 3)
+    assert not pallas_fused.fused_ok("hw", 1)
+    assert pallas_fused.fused_ok("interpret", 1)
+    assert pallas_fused.fused_ok("interpret", 2)
 
 
 def test_resolve_mode(monkeypatch):
@@ -230,15 +208,14 @@ def test_resolve_mode(monkeypatch):
     # explicit strings pass through (the tests' control channel)
     assert pallas_fused.resolve_mode("off") == "off"
     assert pallas_fused.resolve_mode("interpret") == "interpret"
-    # a TPU backend resolves OFF (one warning) until the Mosaic
-    # lowering's first on-chip validation round — the hw kernels are
-    # reachable only through the explicit fused="hw" channel
+    # Mosaic cannot lower the kernels' sort: asking for the hardware
+    # route, explicitly or through the env on a TPU, names the refusal
+    with pytest.raises(RuntimeError, match="Mosaic.*sort"):
+        pallas_fused.resolve_mode("hw")
     monkeypatch.setattr(pallas_fused.jax, "default_backend",
                         lambda: "tpu")
-    monkeypatch.setattr(pallas_fused, "_HW_WARNED", False)
-    with pytest.warns(RuntimeWarning, match="Mosaic"):
-        assert pallas_fused.resolve_mode(None) == "off"
-    assert pallas_fused.resolve_mode("hw") == "hw"
+    with pytest.raises(RuntimeError, match="Mosaic.*sort"):
+        pallas_fused.resolve_mode(None)
 
 
 # ---------------------------------------------------------------- spill

@@ -94,8 +94,7 @@ def test_native_matches_reference_lb1(case):
     """LB1/LB1_d counting semantics against the reference's own library
     (PFSP_lib.c:7-43; sgpu_launch.sh:84 pins -l 1) — including exact
     500k-popped-parent prefixes of the billion-node ta022/27/29/30
-    trees, the instances whose LB1 counts underpin the BENCHMARKS.md
-    baseline-reframing finding (VERDICT r4 missing-item 3)."""
+    trees (their full counts: tests/golden/pfsp_20x20_full.jsonl)."""
     p = taillard.processing_times(case["inst"])
     ub = taillard.optimal_makespan(case["inst"])
     tree, sol, best, _ = native.search(
@@ -121,8 +120,7 @@ def _matrix_id(c):
 @pytest.mark.parametrize("case", MATRIX_CASES, ids=_matrix_id)
 def test_native_matches_reference_deep_wide(case):
     """>=10^4-node trees with jobs > 32: the native engine against the
-    reference's own library on arbitrary matrices (VERDICT r2 #3 — the
-    round-2 wide goldens only pinned 0-3-node trees)."""
+    reference's own library on arbitrary matrices."""
     p = np.asarray(case["p"], np.int32).reshape(case["machines"],
                                                 case["jobs"])
     tree, sol, best, _ = native.search(p, lb_kind=2, init_ub=case["ub"])

@@ -22,21 +22,6 @@ from tpu_tree_search.problems.pfsp import PFSPInstance
 
 WORKER = pathlib.Path(__file__).parent / "_multihost_worker.py"
 
-# The 2-process CPU simulation needs a jax whose CPU backend implements
-# cross-process collectives; the pinned 0.4.x line raises
-# `XlaRuntimeError: Multiprocess computations aren't implemented on the
-# CPU backend` inside the compiled loop (the worker's device-count
-# config is already version-portable). Known seed noise, tracked in
-# ROADMAP ("multihost CPU simulation needs jax >= 0.5"); the code paths
-# themselves (_to_mesh/_fetch/checkpoint._to_np rank-gating) stay
-# exercised on real multi-host TPU runtimes.
-_mh_xfail = pytest.mark.xfail(
-    reason="jax 0.4.x CPU backend lacks multiprocess computations "
-           "(see ROADMAP: multihost follow-on); passes on jax >= 0.5 "
-           "or a real multi-controller runtime",
-    strict=False)
-
-
 def _free_port() -> int:
     with socket.socket() as s:
         s.bind(("localhost", 0))
@@ -79,7 +64,6 @@ def _launch_pair(*extra_args):
     return results
 
 
-@_mh_xfail
 def test_two_process_multihost_matches_single_controller():
     results = _launch_pair()
 
@@ -102,7 +86,6 @@ def test_two_process_multihost_matches_single_controller():
     assert results[0]["best"] == want.best
 
 
-@_mh_xfail
 def test_two_process_multihost_kill_resume(tmp_path):
     """Multihost DURABILITY (the tier the reference's MPI flagship has no
     answer to): a 2-process segmented run truncated mid-search writes a

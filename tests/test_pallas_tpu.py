@@ -198,7 +198,7 @@ def test_wide_class_two_phase_matches_oracle():
 
 
 def test_j500_engine_matches_native():
-    """The 500-job envelope (VERDICT r4 #5): a full bounded-subtree
+    """The 500-job envelope: a full bounded-subtree
     solve at J=500 on chip — int32 pool aux (the aux_dtype fallback),
     16 bitmask words, the XLA LB2 route (every pallas tile cap is out
     of range at J=500) — against the native sequential oracle on the
@@ -292,11 +292,10 @@ def test_lb2_bigj_kernel_matches_scan_on_hardware():
 
 
 def test_j200_two_phase_engine_runs_on_hardware():
-    """The 200x20 campaign class end-to-end on chip through the new
-    route: pallas LB1 expand at the jobs>=128 tile floor of 64, LB1
-    pre-prune, streaming big-J pair sweeps over survivor tiers. The
-    TB=64 kernel must match the XLA oracle bit-for-bit, and a bounded
-    window of the full engine must push nodes."""
+    """The 200x20 campaign class end-to-end on chip: XLA LB1 expand (its
+    TB=64 tile is refused by Mosaic, so the kernel is not admitted),
+    LB1 pre-prune, streaming big-J pair sweeps over survivor tiers. A
+    bounded window of the full engine must push nodes."""
     from tpu_tree_search.engine import device
 
     rng = np.random.default_rng(17)
@@ -304,15 +303,8 @@ def test_j200_two_phase_engine_runs_on_hardware():
     tables = batched.make_tables(p)
 
     tile = pallas_expand.effective_tile(200, 1024, 1024, 1, machines=20)
-    assert tile == 64  # the jobs>=128 floor this test exists to pin
-    assert pallas_expand.kernel_ok(200, tile, 1, machines=20)
-    args = _random_parents(p, 1024, seed=23)
-    bounds_t = pallas_expand.expand_bounds(tables, *args, lb_kind=1,
-                                           tile=tile)
-    bounds_x = pallas_expand.expand_bounds_xla(tables, *args, lb_kind=1,
-                                               tile=tile)
-    np.testing.assert_array_equal(np.asarray(bounds_t),
-                                  np.asarray(bounds_x))
+    assert tile == 64
+    assert not pallas_expand.kernel_ok(200, tile, 1, machines=20)
 
     state = device.init_state(200, 1 << 19, 13000, p_times=p)
     out = device.run(tables, state, 2, 1024, max_iters=20)
@@ -322,9 +314,9 @@ def test_j200_two_phase_engine_runs_on_hardware():
 
 def test_j200_seeded_matches_native():
     """J=200 bounded-subtree parity on chip — the big-J analogue of
-    test_j500_engine_matches_native, now through the ROUND-5 route:
-    pallas LB1 expand at the jobs>=128 TB=64 floor, LB1 pre-prune, and
-    the streaming big-J pair-sweep kernel over survivor tiers. Near-leaf
+    test_j500_engine_matches_native: XLA LB1 expand (TB=64 is not
+    admitted), LB1 pre-prune, and the streaming big-J pair-sweep kernel
+    over survivor tiers. Near-leaf
     bounds are exactly tight here too (ub=best0 explores 0 nodes —
     measured on the native oracle), so the invariant follows the repo's
     ub=inf convention: both engines must prove the same subtree optimum

@@ -436,11 +436,14 @@ def test_kernel_ok_admits_only_validated_tile_family(monkeypatch):
 
     monkeypatch.setattr(pallas_expand.jax, "default_backend",
                         lambda: "tpu")
-    # the validated families stay admitted
+    # the validated family stays admitted
     assert pallas_expand.kernel_ok(20, 1024, 1)     # 128-aligned tile
-    assert pallas_expand.kernel_ok(200, 64, 1)      # TB=64, even big J
+    assert pallas_expand.kernel_ok(200, 128, 1)
+    # TB=64 at big J: Mosaic refuses its reshape on the installed jax
+    # (tests/test_tpu_compile.py), so it takes the XLA fallback
+    assert not pallas_expand.kernel_ok(200, 64, 1)
     # the relaxed-arithmetic shapes the old branch silently admitted
-    # (never run on hardware) now take the XLA fallback
+    # (never run on hardware) take the XLA fallback
     assert not pallas_expand.kernel_ok(130, 192, 1)  # 130*192 % 128 == 0
     assert not pallas_expand.kernel_ok(128, 96, 1)   # 128*96 % 128 == 0
     assert not pallas_expand.kernel_ok(129, 64, 1)   # odd J at TB=64

@@ -1,0 +1,125 @@
+"""The main path's Pallas kernels compile for a described TPU v5e chip.
+
+No chip is attached: `topologies.get_topology_desc` describes one, and
+each kernel is lowered from shapes placed on its first device and
+compiled by the TPU compiler installed here. A compile that passes is
+not a chip run; it catches what the Pallas interpreter cannot (tile
+alignment, VMEM limits, primitives Mosaic cannot lower) at no chip
+time. Shapes are the Taillard classes the engine serves: ta021 (20x20),
+ta031 (50x5) and ta101 (200x20).
+
+The topology is described inside a module fixture, never at import:
+only one process may load the TPU library, and every xdist worker
+imports this file.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from tpu_tree_search.ops import batched, pallas_expand, pallas_fused
+from tpu_tree_search.problems import taillard
+from tpu_tree_search.utils import compile_cache
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # the persistent cache would store these compiles and fail to read
+    # them back without a chip: keep it off for this module
+    with compile_cache.disabled():
+        yield SingleDeviceSharding(topo.devices[0])
+
+
+def _sds(sharding, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=sharding)
+
+
+def _tables(sharding, inst):
+    t = batched.make_tables(taillard.processing_times(inst))
+    return jax.tree.map(
+        lambda x: _sds(sharding, np.shape(x), np.asarray(x).dtype), t)
+
+
+def _parents(sharding, jobs, machines, batch):
+    return (_sds(sharding, (jobs, batch), jnp.int16),
+            _sds(sharding, (1, batch), jnp.int32),
+            _sds(sharding, (machines, batch), jnp.int32))
+
+
+def _compiled_text(fn, *args, **static):
+    return jax.jit(fn, static_argnames=tuple(static)).lower(
+        *args, **static).compile().as_text()
+
+
+@pytest.mark.parametrize("lb_kind", [0, 1])
+@pytest.mark.parametrize("kernel", ["expand_tpu", "expand_bounds_tpu"])
+def test_expand_kernels_compile_at_ta021(one_chip, kernel, lb_kind):
+    fn = getattr(pallas_expand, kernel)
+    text = _compiled_text(fn, _tables(one_chip, 21),
+                          *_parents(one_chip, 20, 20, 2048),
+                          lb_kind=lb_kind, tile=1024)
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("inst", [21, 31, 101])
+def test_lb2_pair_sweep_compiles(one_chip, inst):
+    # the same tile rule and kernel choice as pallas_expand.lb2_bounds:
+    # the resident-table kernel up to J=64, the streaming one beyond
+    machines, jobs = taillard.processing_times(inst).shape
+    tables = _tables(one_chip, inst)
+    pairs = int(tables.ma0.shape[0])
+    width = jobs * 2048 if jobs <= 64 else 4096
+    tile = pallas_expand.lb2_sweep_tile(jobs, pairs, machines, width)
+    assert tile > 0
+    fits = pallas_expand.lb2_kernel_fits(jobs, pairs)
+    assert fits == (jobs <= 64)
+    fn = (pallas_expand.lb2_bounds_tpu if fits
+          else pallas_expand.lb2_bounds_bigj_tpu)
+    text = _compiled_text(fn, tables,
+                          _sds(one_chip, (machines, width), jnp.int32),
+                          _sds(one_chip, (jobs, width), jnp.bfloat16),
+                          tile=tile)
+    assert "tpu_custom_call" in text
+
+
+def test_fused_kernel_is_refused_by_mosaic(one_chip):
+    # why pallas_fused.resolve_mode raises for the hardware route: the
+    # in-kernel compaction sort has no Mosaic lowering. When this test
+    # fails, the kernel compiles and ROADMAP A2 can measure it.
+    args = (_tables(one_chip, 21), *_parents(one_chip, 20, 20, 2048),
+            _sds(one_chip, (), jnp.int32), _sds(one_chip, (), jnp.int32))
+    with jax.enable_x64(False), \
+            pytest.raises(NotImplementedError, match="sort"):
+        _compiled_text(pallas_fused.fused_expand, *args, tile=1024,
+                       cap_width=4096)
+
+
+def test_tile64_expand_is_refused_and_not_admitted(one_chip):
+    # the 200x20 class's TB=64 expand tile: the installed Mosaic cannot
+    # lower its (J, 64) -> (1, J*64) reshape, so kernel_shape_ok must
+    # send it to the XLA fallback. When the compile passes, TB=64 can be
+    # admitted again after a run on the chip.
+    assert pallas_expand.effective_tile(200, 1024, 1024, 1,
+                                        machines=20) == 64
+    assert not pallas_expand.kernel_shape_ok(200, 64, 1, machines=20)
+    rng = np.random.default_rng(17)
+    tables = jax.tree.map(
+        lambda x: _sds(one_chip, np.shape(x), np.asarray(x).dtype),
+        batched.make_tables(rng.integers(1, 100, (20, 200))
+                            .astype(np.int32)))
+    # jax raises a private MosaicError (a plain Exception subclass)
+    with pytest.raises(Exception, match="unsupported shape cast"):
+        _compiled_text(pallas_expand.expand_bounds_tpu, tables,
+                       *_parents(one_chip, 200, 20, 1024), lb_kind=1,
+                       tile=64)

@@ -1,4 +1,4 @@
-"""Measure the balance exchange at PRODUCTION shapes (VERDICT r3 #6).
+"""Measure the balance exchange at PRODUCTION shapes.
 
 Times `_balance_round` on the 8-worker virtual CPU mesh with
 20x20-class pools at chunk 32768 and a sweep of transfer_cap values

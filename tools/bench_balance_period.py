@@ -1,6 +1,6 @@
 """Ground the balance-period default with ON-CHIP cost data.
 
-VERDICT r4 #9: the round-3 sensitivity table measured balance_period on
+The round-3 sensitivity table measured balance_period on
 the virtual CPU mesh, where collectives serialize on the host — its
 wall-clock preference for sparse periods (16 beat 4 by 1.7x) is an
 artifact of that backend, and the default was never defended.
@@ -14,7 +14,7 @@ run, so this sweep and the tuner can never measure different things;
 this file is the thin CLI that survives for operators who want the
 hand-run sweep. The spread side of the tradeoff (per-worker tree CV vs
 period) is backend-independent and comes from the round-3 CPU-mesh
-table (BENCHMARKS.md); this measurement supplies the cost side.
+table; this measurement supplies the cost side.
 
     python tools/bench_balance_period.py [--inst 21] [--lb 2]
 """

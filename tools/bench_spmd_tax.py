@@ -1,6 +1,6 @@
 """Measure the SPMD program's per-step tax on ONE real chip.
 
-VERDICT r4 #4: the pod-scale projection multiplies the single-device
+The pod-scale projection multiplies the single-device
 chip rate by the CPU-mesh's device-count invariance; the missing term
 is what the distributed program itself costs per step on real hardware
 — shard_map, the cond-gated balance round, the pmin incumbent fold.

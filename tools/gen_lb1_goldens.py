@@ -1,7 +1,7 @@
 """Generate LB1 / LB1_d goldens from the reference's own library.
 
-VERDICT r4 missing-item 3: the repo's LB1 tree counts (the basis of the
-"published V100 table is de facto LB2" finding, BENCHMARKS.md) were
+The repo's LB1 tree counts (the basis of the "published V100 table is
+de facto LB2" finding, tests/golden/pfsp_20x20_full.jsonl) were
 never goldened against the reference the way the LB2 counts are
 (tests/golden/pfsp_lb2_ub1.jsonl). This script drives the reference's
 verbatim decompose/lb1_bound/lb1_children_bounds through the

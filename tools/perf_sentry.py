@@ -14,11 +14,10 @@ trajectory a gate:
   earlier rounds (plus any ``published`` number in BASELINE.json),
   with a per-metric relative threshold (default 10%; LB2's window is
   shorter and noisier, so it gets 15%);
-- **degraded rows don't lie**: a row stamped ``degraded: true`` (the
-  bench ran on a fallback platform, see bench.py's backend bootstrap)
-  is never rate-compared against non-degraded history — a CPU rate
-  "regressing" from a TPU rate is not a finding — but its rc still
-  gates, platform recorded in the report.
+- **platforms don't mix**: a row is never rate-compared against
+  history from another platform (bench.py stamps ``platform`` on every
+  row) — a CPU rate "regressing" from a TPU rate is not a finding —
+  but its rc still gates, platform recorded in the report.
 
 Inputs it understands: the driver's wrapper objects
 (``{"rc": ..., "tail": ..., "parsed": ...}`` — metric rows are
@@ -202,8 +201,6 @@ def load_history(directory: str, before_round: int,
         if src["rc"] != 0:
             continue
         for row in src["rows"]:
-            if row.get("degraded"):
-                continue            # fallback-platform rate: not a bar
             offer(row.get("metric"), row.get("value"), src["source"],
                   row.get("platform"), row_mode(row))
     if baseline_path and os.path.exists(baseline_path):
@@ -249,8 +246,7 @@ def judge(sources: list[dict], history: dict,
             metric = row.get("metric", "?")
             value = row.get("value")
             v = {"source": name, "metric": metric, "value": value,
-                 "platform": row.get("platform"),
-                 "degraded": bool(row.get("degraded"))}
+                 "platform": row.get("platform")}
             # rows carry their measurement mode precisely so an
             # overlap-off gap is never judged against an overlap-on
             # ~0.0 reference, and a cold-cache executor-ready latency
@@ -276,21 +272,19 @@ def judge(sources: list[dict], history: dict,
             # exact false-FAIL this machinery exists to prevent
             mode_mismatch = (ref is not None and mode is not None
                              and refmode != mode)
-            if ref is not None and (v["degraded"] or plat_mismatch
-                                    or mode_mismatch):
-                # a fallback-platform (or different-platform, or
-                # different-mode) value compared against the reference
-                # best would always "regress" — a CPU rate is not a
-                # TPU finding, a sync gap not a pipelined one, a cold
-                # compile not a warm replay
+            if ref is not None and (plat_mismatch or mode_mismatch):
+                # a different-platform (or different-mode) value
+                # compared against the reference best would always
+                # "regress" — a CPU rate is not a TPU finding, a sync
+                # gap not a pipelined one, a cold compile not a warm
+                # replay
                 ref_mode_desc = (repr(refmode[1]) if refmode
                                  else "unknown (modeless baseline)")
                 why = (f"{mode[0]} mode {mode[1]!r} vs "
                        f"reference mode {ref_mode_desc}"
                        if mode_mismatch
-                       else f"platform {row.get('platform')!r}"
-                       + (" (degraded)" if v["degraded"] else "")
-                       + f" vs reference platform {refplat!r}")
+                       else f"platform {row.get('platform')!r} vs "
+                       f"reference platform {refplat!r}")
                 v.update(verdict=SKIP,
                          detail=f"{why}; rate not compared "
                                 f"(reference {ref[0]:.4g})")
@@ -351,7 +345,7 @@ def render_json(verdicts: list[dict], latest_round: int) -> dict:
             {k: v.get(k) for k in
              ("verdict", "source", "metric", "value", "reference",
               "reference_source", "delta", "threshold", "direction",
-              "platform", "degraded", "detail")}
+              "platform", "detail")}
             for v in verdicts],
     }
 

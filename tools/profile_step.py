@@ -15,7 +15,7 @@ buckets, shared with tools/trace_selftime.py, tools/search_report.py and
 tools/validate_attribution.py). The tool's own wall-clock phases
 (warm-up, traced window) are flight-recorded as obs/tracelog spans, so a
 `TTS_TRACE_FILE=...` run leaves a timeline of the measurement itself.
-This is the measurement VERDICT r2 items 8/9 ask for: what the two-phase
+It measures what the two-phase
 LB2 step (resp. the LB1 step) actually spends its time on.
 """
 

@@ -6,7 +6,7 @@ pop + mask + dense bound — the same semantics as the reference's
 kernel timer, which wraps the whole evaluate_gpu region including
 copies and launch (PFSP_statistic.c:69-112) — NOT the bound op alone.
 This script therefore reports TWO ground truths per bound, each with
-its own error bar (VERDICT r3 #9 / r4 #8):
+its own error bar:
 
 - bracket vs traced bracket: the attributed per-step kernel cost
   against the device self-time of an independently traced

@@ -563,6 +563,24 @@ class _DistDriver:
         return SearchState(*(_to_mesh(self.mesh, s, x)
                              for s, x in zip(self.spec_state, state)))
 
+    def _pin_empty(self, state: SearchState) -> SearchState:
+        """Re-commit zero-size leaves (the telemetry block with the flag
+        off) to the worker axis. The loop returns them replicated, and
+        an AOT executable compiled for the axis-sharded signature
+        rejects them on the next call. Pinning the loop's output
+        shardings in the jit instead crashes the TPU compiler's Shardy
+        import on a 4-chip mesh (seen compiling for a described v5e
+        2x2, PR 21). Moving a zero-size array costs nothing. Multi-
+        controller runs have no AOT executables and cannot reshard a
+        global array this way, so they keep the leaves as they are."""
+        from jax.sharding import NamedSharding
+        if jax.process_count() > 1:
+            return state
+        return SearchState(*(
+            jax.device_put(x, NamedSharding(self.mesh, s))
+            if x.size == 0 else x
+            for s, x in zip(self.spec_state, state)))
+
     @staticmethod
     def _cap(bound_cap) -> jnp.ndarray:
         return jnp.asarray(I32_MAX if bound_cap is None else bound_cap,
@@ -581,9 +599,9 @@ class _DistDriver:
                    else int(max_iters))
         while True:
             capacity = state.prmu.shape[-1]
-            out = SearchState(*self._loop(capacity)(
+            out = self._pin_empty(SearchState(*self._loop(capacity)(
                 self.tables, jnp.asarray(ceiling, jnp.int64),
-                self._cap(bound_cap), *state))
+                self._cap(bound_cap), *state)))
             if not bool(_fetch(out.overflow).any()):
                 return out
             grown = checkpoint.grow(fetch_state(out), capacity * 2)
@@ -603,9 +621,10 @@ class _DistDriver:
         with _warnings.catch_warnings():
             _warnings.filterwarnings(
                 "ignore", message="Some donated buffers were not usable")
-            return SearchState(*self._loop(capacity, donate=True)(
-                self.tables, jnp.asarray(int(max_iters), jnp.int64),
-                self._cap(bound_cap), *state))
+            return self._pin_empty(SearchState(
+                *self._loop(capacity, donate=True)(
+                    self.tables, jnp.asarray(int(max_iters), jnp.int64),
+                    self._cap(bound_cap), *state)))
 
     def seed(self, frontier: Frontier, capacity: int, jobs: int,
              init_best: int) -> SearchState:
@@ -909,16 +928,13 @@ def search(p_times: np.ndarray, lb_kind: int = 1, init_ub: int | None = None,
     """Distributed B&B over all available devices (the flagship engine;
     capability parity with pfsp_dist_multigpu_cuda.c's pfsp_search).
 
-    `balance_period=4` is a MEASURED default (round 4): on real TPU
-    hardware the cond-gated balance round is free — the full SPMD
-    program costs 6.40 ms/iter at period 4 vs 6.64 at period 1 and
-    6.53 at period 16 on identical ta021 state
-    (tools/bench_balance_period.py, ±2% noise) — so the period is
-    chosen for SPREAD, where the CPU-mesh sensitivity table
-    (BENCHMARKS.md) shows per-worker tree CV 0.16 at period 4 vs 0.20
-    at period 16. The CPU mesh's wall-clock preference for sparse
-    periods is an artifact of host-serialized collectives; do not
-    retune this knob on the virtual mesh.
+    `balance_period=4` was chosen on chip in round 4
+    (tools/bench_balance_period.py found the cond-gated balance round's
+    cost flat across periods; not measured on chip this round), so the
+    period is chosen for SPREAD of per-worker trees. The CPU mesh's
+    wall-clock preference for sparse periods is an artifact of
+    host-serialized collectives; do not retune this knob on the
+    virtual mesh.
 
     With `segment_iters`/`checkpoint_path` the loop runs in bounded
     segments with heartbeat + checkpoint/resume between them — the

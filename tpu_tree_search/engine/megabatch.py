@@ -69,34 +69,6 @@ from .distributed import DistResult
 AX = WORKER_AXIS
 
 
-def _register_barrier_batching() -> None:
-    """jax 0.4.x ships no vmap rule for `optimization_barrier` (the
-    fusion fence the PFSP step leans on — engine/device._regather), so
-    vmapping the step would raise NotImplementedError. The rule is
-    trivially shape-transparent — bind the barrier on the batched
-    operands, pass the batch dims through — and this is exactly the
-    rule later jax versions ship upstream; registration is gated so a
-    pin that already has one keeps it."""
-    try:
-        from jax._src.lax import lax as _lax_src
-        from jax.interpreters import batching
-        prim = getattr(_lax_src, "optimization_barrier_p", None)
-        if prim is None or prim in batching.primitive_batchers:
-            return
-
-        def _ob_batcher(args, dims, **params):
-            return prim.bind(*args, **params), dims
-
-        batching.primitive_batchers[prim] = _ob_batcher
-    except Exception:  # noqa: BLE001 — a moved private module on a
-        # future pin must not break import; the loop build would then
-        # surface the missing rule loudly
-        pass
-
-
-_register_barrier_batching()
-
-
 class MemberIncompatible(ValueError):
     """One member's RESUME STATE cannot join this batch (cross-problem
     checkpoint, legacy aux dtype, different telemetry width) — the

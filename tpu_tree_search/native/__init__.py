@@ -21,9 +21,12 @@ _lib = None
 
 
 def build(force: bool = False) -> pathlib.Path:
+    # no -march=native: a checkout is copied between hosts (to the
+    # chip's machine among them), and a library tuned for another CPU
+    # dies there with SIGILL
     if force or not _LIB.exists() or _LIB.stat().st_mtime < _SRC.stat().st_mtime:
         subprocess.run(
-            ["g++", "-O3", "-march=native", "-std=c++17", "-shared", "-fPIC",
+            ["g++", "-O3", "-std=c++17", "-shared", "-fPIC",
              "-pthread", str(_SRC), "-o", str(_LIB)],
             check=True, capture_output=True,
         )

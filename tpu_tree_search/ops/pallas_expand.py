@@ -51,14 +51,8 @@ from .batched import BoundTables
 
 def _x64_off():
     """Scope a trace to x32 (see the load-bearing comment at the LB2
-    pallas call). `jax.enable_x64(False)` only exists on newer jax; the
-    pinned 0.4.x line spells it `jax.experimental.disable_x64()` — the
-    seed suite's three big-J interpret tests failed on exactly this
-    AttributeError."""
-    if hasattr(jax, "enable_x64"):
-        return jax.enable_x64(False)
-    from jax.experimental import disable_x64
-    return disable_x64()
+    pallas call)."""
+    return jax.enable_x64(False)
 
 I32_MAX = jnp.int32(2**31 - 1)
 
@@ -294,28 +288,19 @@ def kernel_ok(jobs: int, eff_tile: int, lb_kind: int,
 def kernel_shape_ok(jobs: int, eff_tile: int, lb_kind: int,
                     machines: int | None = None) -> bool:
     """The backend-independent SHAPE half of :func:`kernel_ok` — the
-    hardware-validated tile-family rule (including the jobs >= 128
-    eff_tile == 64 admission) plus the lane and scoped-VMEM caps. Split
-    out so the FUSED bound+prune+compact entry points
-    (ops/pallas_fused.fused_ok) enforce the exact same rule on their
-    hardware route: a shape the expand kernel rejects must never reach
-    the fused kernels either (the fused math is the expand math)."""
+    tile-family rule plus the lane and scoped-VMEM caps. Split out so
+    the rule can be checked without a chip (tests/test_tpu_compile.py),
+    and so a fused hardware route, once its kernel lowers (ROADMAP A2),
+    can share it: the fused math is the expand math."""
     lane_cap = MAX_TILE_LANES // 2 if lb_kind == 2 else MAX_TILE_LANES
     return (eff_tile >= min_tile(jobs)
             # lane-aligned reshapes: the kernel's (J, TB) -> (1, J*TB)
-            # flattening needs the flat lane count 128-aligned; TB
-            # itself only has to be 128-aligned down to the hardware-
-            # validated TB=64 family (min_tile's jobs >= 128 floor,
-            # J*64 still 128-aligned at even J — validated bit-exact at
-            # 200x20, tests/test_pallas_tpu.py). A trusted
-            # caller-supplied tile below 64 (TB=32, TB=16...) can also
-            # satisfy the raw (jobs*eff_tile) % 128 == 0 arithmetic,
-            # but no such mosaic layout has ever run on hardware —
-            # admit ONLY the validated family and let everything else
-            # take the XLA fallback (ADVICE.md round 5).
-            and (eff_tile % 128 == 0
-                 or (jobs >= 128 and eff_tile == 64
-                     and (jobs * eff_tile) % 128 == 0))
+            # flattening needs TB 128-aligned. The TB=64 family at
+            # jobs >= 128 (min_tile's floor) ran on an older jax; the
+            # installed Mosaic refuses its reshape ("unsupported shape
+            # cast", tests/test_tpu_compile.py), so those shapes take
+            # the XLA fallback like every other unaligned tile.
+            and eff_tile % 128 == 0
             and jobs * eff_tile <= lane_cap
             and (machines is None
                  or jobs * machines * eff_tile <= EXPAND_TILE_UNITS))
@@ -894,9 +879,9 @@ def min_tile(jobs: int) -> int:
     general; 128 is validated for the wide classes (jobs >= 64 keeps
     the J*tile lane count >= 8192 — measured bit-exact at J=100/TB=128,
     which the 100x20 class needs to fit the scoped-VMEM stack); 64 for
-    jobs >= 128 (lane count still >= 8192; the 200x20 class needs
-    TB=64 to fit the J*M*TB scoped-VMEM unit cap — validated bit-exact
-    at J=200/TB=64 on hardware, tests/test_pallas_tpu.py)."""
+    jobs >= 128, where the 200x20 class needs TB=64 to fit the J*M*TB
+    scoped-VMEM unit cap — a tile kernel_shape_ok no longer admits, so
+    that class expands on the XLA path."""
     if jobs >= 128:
         return 64
     return 128 if jobs >= 64 else 256

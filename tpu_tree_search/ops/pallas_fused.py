@@ -36,27 +36,19 @@ Compaction inside the kernel uses the engine's packed-key partition
 trick (device._partition): flag in bit 31, column index in the low
 bits, one unstable u32 sort — deterministic because every key is
 unique, and stable-in-column-order because tiles are visited in grid
-order and the cursor advances monotonically. The in-kernel sort and
-the cross-grid-step dynamic stores are validated under the Pallas
-INTERPRETER on the CPU mesh (the CI `fused-interpret` leg and the
-tests/test_fused.py parity suite); the Mosaic hardware lowering of
-both (sort -> cumsum+gather, cursor stores -> ANY-space async copies)
-is the next hardware round's work, which is why `fused_ok` admits the
-hardware route only behind the exact expand-kernel shape rule
-(pallas_expand.kernel_shape_ok) AND the TTS_FUSED flag — a shape the
-expand kernel rejects must never reach the fused kernels either.
+order and the cursor advances monotonically. The kernels run under the
+Pallas INTERPRETER on the CPU mesh only (the tests/test_fused.py parity
+suite): Mosaic refuses to lower the in-kernel sort for the TPU
+(:data:`MOSAIC_REFUSAL`), so there is no hardware route until the
+kernel is rewritten without it (ROADMAP A2).
 
 Mode resolution (all env reads HOST-side — the traced step receives
 the resolved mode as a static argument, never reads the environment):
 
 - ``off``       fused disabled (the default; bit-identical legacy path)
-- ``hw``        the TPU kernels behind the expand shape rule —
-                reachable ONLY through an explicit fused="hw" argument
-                until the Mosaic lowering's first on-chip validation
-                round: TTS_FUSED=1 on a TPU backend resolves "off"
-                with a one-time warning (resolve_mode), because a
-                serve boot must not be the place a never-compiled
-                lowering error surfaces
+- ``hw``        the TPU kernels; asking for them (fused="hw", or
+                TTS_FUSED=1 on a TPU backend) raises, naming the
+                Mosaic refusal
 - ``interpret`` TTS_FUSED=1 + TTS_FUSED_INTERPRET=1 on a non-TPU
                 backend: the kernels run under pl.pallas_call's
                 interpreter inside the compiled step — the CI leg that
@@ -95,18 +87,26 @@ def store_sub(n_cols: int) -> int:
 FUSED_FLAG = "TTS_FUSED"
 FUSED_INTERPRET_FLAG = "TTS_FUSED_INTERPRET"
 
-_HW_WARNED = False      # one boot-time warning, not one per executor
+MOSAIC_REFUSAL = (
+    "the fused kernels cannot be compiled for the TPU: Mosaic rejects "
+    "their in-kernel sort ('Unimplemented primitive in Pallas TPU "
+    "lowering for KernelType.TC: sort'). Unset TTS_FUSED; the rewrite "
+    "is ROADMAP A2.")
 
 
 def resolve_mode(flag: bool | str | None = None) -> str:
-    """HOST-side resolution of the fused dispatch mode: "off" | "hw" |
+    """HOST-side resolution of the fused dispatch mode: "off" |
     "interpret". `flag` None reads the TTS_FUSED env knob; an explicit
     string mode passes through (the tests' control channel); True
     resolves against the backend like the env flag. The result is a
     STATIC argument of the compiled step — flipping the env mid-process
-    retraces rather than silently reusing a stale executable."""
+    retraces rather than silently reusing a stale executable. A request
+    for the hardware kernels raises (:data:`MOSAIC_REFUSAL`)."""
     if isinstance(flag, str):
-        assert flag in ("off", "hw", "interpret"), flag
+        if flag == "hw":
+            raise RuntimeError(MOSAIC_REFUSAL)
+        if flag not in ("off", "interpret"):
+            raise ValueError(f"unknown fused mode {flag!r}")
         return flag
     from ..utils import config as _cfg
     if flag is None:
@@ -114,48 +114,18 @@ def resolve_mode(flag: bool | str | None = None) -> str:
     if not flag:
         return "off"
     if jax.default_backend() == "tpu":
-        # the Mosaic lowering of the in-kernel sort and the cursor
-        # stores is the NEXT hardware round's work (module docstring):
-        # the env flag must not route a production boot onto a
-        # never-compiled path — a serve boot is not the place to
-        # discover a lowering error. The hardware round drives the
-        # kernels through the explicit fused="hw" control channel
-        # (device.run(fused="hw") / the string passthrough above)
-        # until the lowering is validated on chip, then flips this
-        # gate open.
-        global _HW_WARNED
-        if not _HW_WARNED:
-            _HW_WARNED = True
-            import warnings
-            warnings.warn(
-                "TTS_FUSED=1: the fused kernels' TPU (Mosaic) "
-                "lowering is pending its first on-chip validation "
-                "round — running the unfused pipeline. Drive "
-                "fused=\"hw\" explicitly to validate the lowering.",
-                RuntimeWarning, stacklevel=2)
-        return "off"
+        raise RuntimeError(MOSAIC_REFUSAL)
     if _cfg.env_flag(FUSED_INTERPRET_FLAG):
         return "interpret"
     return "off"
 
 
-def fused_ok(mode: str, jobs: int, eff_tile: int, lb_kind: int,
-             machines: int | None = None) -> bool:
-    """THE fused-route admission rule (device.step's gate and the
-    tuner's probe gate share it). The hardware route sits behind the
-    exact expand-kernel shape rule — kernel_shape_ok's lane floors,
-    the hardware-validated eff_tile==64 family admission and the
-    scoped-VMEM unit cap — so a shape the expand kernel rejects can
-    never reach the fused kernels. The interpreter route has no Mosaic
-    layout constraints (it exists to validate kernel LOGIC on the CPU
-    mesh) and admits any shape."""
-    if mode == "off" or lb_kind not in (1, 2):
-        return False
-    if mode == "hw":
-        return (jax.default_backend() == "tpu"
-                and pallas_expand.kernel_shape_ok(jobs, eff_tile, lb_kind,
-                                                  machines=machines))
-    return mode == "interpret"
+def fused_ok(mode: str, lb_kind: int) -> bool:
+    """THE fused-route admission rule (device.step's gate). Only the
+    interpreter route exists (resolve_mode refuses the hardware one);
+    it has no Mosaic layout constraints — it validates kernel LOGIC on
+    the CPU mesh — and admits any shape of the LB1/LB2 steps."""
+    return mode == "interpret" and lb_kind in (1, 2)
 
 
 def _tile_lanes(x: jax.Array, reps: int) -> jax.Array:
@@ -177,8 +147,7 @@ def _fused_kernel(J: int, M: int, TB: int, W: int, SW: int, BINS: int,
     ``SW`` > 0 additionally emits the scheduled-set bitmask words of
     every survivor (the two-phase LB2 route's pair-sweep input);
     ``BINS`` > 0 emits the per-tile pruned-bound histogram (engine
-    telemetry's bound_hist binning, int64 math — exact, the interpret
-    path runs under the package's ambient x64)."""
+    telemetry's bound_hist binning, exact in int32)."""
     out = list(refs)
     children_ref, caux_ref = out[:2]
     out = out[2:]
@@ -274,10 +243,14 @@ def _fused_kernel(J: int, M: int, TB: int, W: int, SW: int, BINS: int,
         # pruned-bound histogram, telemetry.bound_hist's exact binning:
         # the only trace the pruned children leave
         pruned = (maskv & ~is_leaf & ~push).reshape(-1)
-        b64 = lb.reshape(-1).astype(jnp.int64)
-        ref64 = jnp.maximum(cap_ref[0, 0].astype(jnp.int64), 1)
-        gap = jnp.abs(b64 - ref64)
-        bins = jnp.minimum(gap * BINS // ref64, BINS - 1)
+        # bin = min(gap * BINS // ref, BINS - 1), counted as the
+        # thresholds ceil(k * ref / BINS) that gap reaches, so no
+        # product leaves int32 (ref is INT_MAX before an incumbent)
+        ref = jnp.maximum(cap_ref[0, 0], 1)
+        gap = jnp.abs(lb.reshape(-1) - ref)
+        q, r = ref // BINS, ref % BINS
+        bins = sum((gap >= k * q + (k * r + BINS - 1) // BINS)
+                   .astype(jnp.int32) for k in range(1, BINS))
         hist_ref[:, :] = jnp.stack(
             [jnp.sum(pruned & (bins == k), dtype=jnp.int32)
              for k in range(BINS)]).reshape(BINS, 1)
@@ -330,7 +303,6 @@ def _fused_kernel(J: int, M: int, TB: int, W: int, SW: int, BINS: int,
     # of an output adds a whole-buffer copy).
     SUB = store_sub(N)
     cur = cur_ref[0]
-    zero = jnp.int32(0)
 
     for k in range(0, N, SUB):
         wk = min(SUB, N - k)
@@ -338,16 +310,12 @@ def _fused_kernel(J: int, M: int, TB: int, W: int, SW: int, BINS: int,
         @pl.when((jnp.int32(k) < n_tile) & (cur + k <= jnp.int32(W)))
         def _store(k=k, wk=wk):
             at = cur + k
-            pl.store(children_ref, (pl.ds(zero, J), pl.ds(at, wk)),
-                     children_c[:, k:k + wk])
-            pl.store(caux_ref, (pl.ds(zero, M + 1), pl.ds(at, wk)),
-                     caux_c[:, k:k + wk])
+            children_ref[:, pl.ds(at, wk)] = children_c[:, k:k + wk]
+            caux_ref[:, pl.ds(at, wk)] = caux_c[:, k:k + wk]
             if BNDS:
-                pl.store(bounds_ref, (pl.ds(zero, 1), pl.ds(at, wk)),
-                         bounds_c[:, k:k + wk])
+                bounds_ref[:, pl.ds(at, wk)] = bounds_c[:, k:k + wk]
             if SW:
-                pl.store(sched_ref, (pl.ds(zero, SW), pl.ds(at, wk)),
-                         sched_c[:, k:k + wk])
+                sched_ref[:, pl.ds(at, wk)] = sched_c[:, k:k + wk]
 
     cur_ref[0] = cur + n_tile
     cnt_ref[0, 0] = cur + n_tile
@@ -430,24 +398,29 @@ def fused_expand(tables: BoundTables, prmu_T, depth2, front_T,
         out_specs.append(pl.BlockSpec((BINS, 1), lambda g: (0, g)))
         out_shape.append(jax.ShapeDtypeStruct((BINS, G), jnp.int32))
 
-    call = pl.pallas_call(
-        kernel,
-        grid=(G,),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.VMEM),          # p
-            pl.BlockSpec(memory_space=pltpu.VMEM),          # tails
-            pl.BlockSpec((J, TB), lambda g: (0, g)),        # prmu
-            pl.BlockSpec((1, TB), lambda g: (0, g)),        # depth
-            pl.BlockSpec((M, TB), lambda g: (0, g)),        # front
-            pl.BlockSpec(memory_space=pltpu.SMEM),          # n_valid
-            pl.BlockSpec(memory_space=pltpu.SMEM),          # bound_cap
-        ],
-        out_specs=out_specs,
-        out_shape=out_shape,
-        scratch_shapes=[pltpu.SMEM((1,), jnp.int32)],       # cursor
-        interpret=interpret,
-    )
-    outs = list(call(p_f32, tails, prmu_T, depth2, front_T, n2, cap2))
+    # x32 trace, as at pallas_expand's LB2 call: under the package's
+    # global x64 the grid index maps and `iota % TB` trace as i64,
+    # which Mosaic cannot lower
+    with pallas_expand._x64_off():
+        call = pl.pallas_call(
+            kernel,
+            grid=(G,),
+            in_specs=[
+                pl.BlockSpec(memory_space=pltpu.VMEM),          # p
+                pl.BlockSpec(memory_space=pltpu.VMEM),          # tails
+                pl.BlockSpec((J, TB), lambda g: (0, g)),        # prmu
+                pl.BlockSpec((1, TB), lambda g: (0, g)),        # depth
+                pl.BlockSpec((M, TB), lambda g: (0, g)),        # front
+                pl.BlockSpec(memory_space=pltpu.SMEM),          # n_valid
+                pl.BlockSpec(memory_space=pltpu.SMEM),          # bound_cap
+            ],
+            out_specs=out_specs,
+            out_shape=out_shape,
+            scratch_shapes=[pltpu.SMEM((1,), jnp.int32)],       # cursor
+            interpret=interpret,
+        )
+        outs = list(call(p_f32, tails, prmu_T, depth2, front_T, n2,
+                         cap2))
     children, caux = outs[:2]
     outs = outs[2:]
     bounds = outs.pop(0) if with_bounds else None

@@ -64,7 +64,7 @@ def partition_submeshes(n_submeshes: int,
 
 
 def shard_map(fn, mesh, in_specs, out_specs):
-    """Version-tolerant shard_map wrapper.
+    """shard_map with the engine's settings.
 
     check_vma is disabled: the engine's scan/while carries are seeded from
     unvarying constants but updated from worker-varying pool data, which
@@ -72,9 +72,5 @@ def shard_map(fn, mesh, in_specs, out_specs):
     correct SPMD computation (collectives appear only at the balance and
     termination points, by construction).
     """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

@@ -27,6 +27,9 @@ Safety model — a stale entry can never load into the wrong runtime:
   (problem kind, shape, bound, chunk, aux dtype, submesh device ids,
   capacity, balance knobs, row limit, donation variant) — everything
   the trace specializes on.
+- **Format**: the header's ``v`` names the payload layout
+  (:data:`FORMAT`); an entry of another layout is ignored like a
+  fingerprint mismatch, and this runtime's compile overwrites it.
 - **Fingerprint**: each entry's header embeds :func:`runtime_fingerprint`
   (jax/jaxlib versions, platform, device topology/kind, process count,
   telemetry block width) and is IGNORED on mismatch — the telemetry
@@ -71,6 +74,15 @@ __all__ = ["AOTCache", "probe", "runtime_fingerprint"]
 MAGIC = b"TTSAOT1\n"
 _HDR_LEN = struct.Struct("<Q")
 QUARANTINE_SUFFIX = ".corrupt"
+# payload layout: 2 = (payload, in_tree, out_tree, device ids); 1 had
+# no device ids and loaded a submesh program onto the wrong devices
+FORMAT = 2
+
+
+def _cache_served_breaks() -> bool:
+    import jax
+    return jax.default_backend() == "cpu"
+
 
 _probe_lock = threading.Lock()
 _probe_result: bool | None = None
@@ -119,18 +131,42 @@ def probe() -> bool:
         return _probe_result
 
 
+def _serialize(compiled) -> bytes:
+    """Pickle a compiled program with the ids of the devices it was
+    compiled for, in its device-assignment order: a plain
+    ``deserialize_and_load`` places a submesh program on the first
+    devices of the process and fails at execution."""
+    from jax.experimental import serialize_executable as se
+    ids = [d.id for d in compiled.runtime_executable().local_devices()]
+    return pickle.dumps((*se.serialize(compiled), ids))
+
+
+def _deserialize(blob: bytes):
+    import jax
+    from jax.experimental import serialize_executable as se
+    payload, in_tree, out_tree, ids = pickle.loads(blob)
+    by_id = {d.id: d for d in jax.devices()}
+    return se.deserialize_and_load(
+        payload, in_tree, out_tree,
+        execution_devices=[by_id[i] for i in ids])
+
+
 def _probe_impl() -> bool:
+    """Round-trip a program sharded over the process's LAST devices in
+    reverse order (one device where there is only one), the placement a
+    non-first submesh gets."""
     try:
         import jax
         import jax.numpy as jnp
-        from jax.experimental import serialize_executable as se
+        import numpy as np
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
-        fn = jax.jit(lambda x: x * 2 + 1)
-        x = jnp.arange(4, dtype=jnp.int32)
-        compiled = fn.lower(x).compile()
-        payload, in_tree, out_tree = se.serialize(compiled)
-        blob = pickle.dumps((payload, in_tree, out_tree))
-        loaded = se.deserialize_and_load(*pickle.loads(blob))
+        devs = jax.devices()[-2:][::-1]
+        mesh = Mesh(np.array(devs), ("w",))
+        x = jax.device_put(jnp.arange(4 * len(devs), dtype=jnp.int32),
+                           NamedSharding(mesh, PartitionSpec("w")))
+        compiled = jax.jit(lambda x: x * 2 + 1).lower(x).compile()
+        loaded = _deserialize(_serialize(compiled))
         ok = bool((loaded(x) == compiled(x)).all())
     except Exception as e:  # noqa: BLE001 — any failure means "cannot"
         tracelog.event("aot_cache.probe", supported=False, error=repr(e))
@@ -250,8 +286,7 @@ class AOTCache:
         if payload is None:
             return None
         try:
-            from jax.experimental import serialize_executable as se
-            compiled = se.deserialize_and_load(*pickle.loads(payload))
+            compiled = _deserialize(payload)
         except Exception as e:  # noqa: BLE001 — bytes are CRC-clean but
             # the runtime rejects them (a drift the fingerprint missed):
             # this entry will never load better, quarantine it
@@ -284,11 +319,12 @@ class AOTCache:
         except Exception as e:  # noqa: BLE001 — torn/truncated/garbled
             self._quarantine(path, repr(e))
             return None
-        if header.get("fingerprint") != self.fingerprint:
+        if (header.get("v") != FORMAT
+                or header.get("fingerprint") != self.fingerprint):
             # a DIFFERENT runtime's entry (jax bump, topology change,
-            # telemetry flag flip): valid bytes, wrong world — ignore
-            # it (this runtime's compile will overwrite it) but never
-            # load it
+            # telemetry flag flip) or payload layout: valid bytes,
+            # wrong world — ignore it (this runtime's compile will
+            # overwrite it) but never load it
             with self._lock:
                 self.mismatches += 1
             self._count("_misses_c", "misses")
@@ -324,11 +360,20 @@ class AOTCache:
 
     # ---------------------------------------------------------- store
 
-    def store(self, key: tuple, compiled, key_repr: str = "") -> None:
+    def store(self, key: tuple, compiled, key_repr: str = "",
+              xla_cache_hit: bool = False) -> None:
         """Queue persistence of a freshly compiled executable (writer
         thread does serialize + CRC + atomic write). Serialization
         failures are counted, never raised — a program the pin cannot
-        serialize still serves from memory."""
+        serialize still serves from memory.
+
+        An executable that XLA's persistent compilation cache served
+        (`xla_cache_hit`) stays in memory on XLA:CPU: its bytes load,
+        then fail at execution ("Function ... not found"), so a
+        restarted server's request would fail."""
+        if xla_cache_hit and _cache_served_breaks():
+            tracelog.event("aot_cache.skip_xla_cache_hit", key=key_repr)
+            return
         with self._close_lock:
             if self._closed:
                 return
@@ -362,10 +407,9 @@ class AOTCache:
                 self._q.task_done()
 
     def _write(self, task: dict) -> None:
-        from jax.experimental import serialize_executable as se
         path: pathlib.Path = task["path"]
         try:
-            payload = pickle.dumps(se.serialize(task["compiled"]))
+            payload = _serialize(task["compiled"])
         except Exception as e:  # noqa: BLE001 — per-program capability:
             # the probe passing does not guarantee EVERY program
             # round-trips on this pin; fall back to in-memory-only for
@@ -375,7 +419,7 @@ class AOTCache:
                            key=task["key_repr"], error=repr(e))
             return
         header = json.dumps({
-            "v": 1, "fingerprint": self.fingerprint,
+            "v": FORMAT, "fingerprint": self.fingerprint,
             "key": task["key_repr"], "created_unix": time.time(),
             "payload_len": len(payload),
             "payload_crc32": zlib.crc32(payload),

@@ -55,7 +55,23 @@ from __future__ import annotations
 import threading
 import time
 
+import jax
+
 from ..obs import tracelog
+
+# Persistent-cache hits seen by each thread: jax records the event in
+# the thread that compiles, so _compile_fresh can tell whether XLA's
+# persistent cache served its executable (see AOTCache.store).
+_XLA_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+_seen = threading.local()
+
+
+def _on_jax_event(event: str, **_) -> None:
+    if event == _XLA_CACHE_HIT:
+        _seen.hits = getattr(_seen, "hits", 0) + 1
+
+
+jax.monitoring.register_event_listener(_on_jax_event)
 
 
 class _Entry:
@@ -138,12 +154,15 @@ class _Entry:
         or compiles (tests monkeypatch it to pin the zero-compile
         restart-replay contract). Returns (compiled, trace_s,
         compile_s); raises when the AOT path cannot handle the
-        program/backend."""
+        program/backend. Records in the ledger whether XLA's persistent
+        compilation cache served the executable (``xla_cache_hit``)."""
+        hits = getattr(_seen, "hits", 0)
         t0 = time.perf_counter()
         lowered = self.fn.lower(*args)
         t1 = time.perf_counter()
         compiled = lowered.compile()
         t2 = time.perf_counter()
+        self.record["xla_cache_hit"] = getattr(_seen, "hits", 0) > hits
         return compiled, t1 - t0, t2 - t1
 
     def warm(self, *abstract_args, via: str = "prewarm") -> str:
@@ -178,8 +197,8 @@ class _Entry:
             self._measured = True
             self._record_measured()
             if self._aot is not None:
-                self._aot.store(self._key, compiled,
-                                key_repr=rec["key"])
+                self._aot.store(self._key, compiled, key_repr=rec["key"],
+                                xla_cache_hit=rec.get("xla_cache_hit"))
             return "compile"
 
     def _first_call(self, *args):        # holds: self._lock
@@ -212,8 +231,8 @@ class _Entry:
             self._measured = True
             self._record_measured()
             if self._aot is not None:
-                self._aot.store(self._key, compiled,
-                                key_repr=rec["key"])
+                self._aot.store(self._key, compiled, key_repr=rec["key"],
+                                xla_cache_hit=rec.get("xla_cache_hit"))
             return compiled(*args)
         # fallback: the first jit call IS trace+compile (+ one execute)
         t0 = time.perf_counter()
@@ -377,6 +396,16 @@ class ExecutorCache:
         with self._lock:
             return {"entries": len(self._fns), "hits": self.hits,
                     "misses": self.misses}
+
+    def executables(self) -> list:
+        """The executable of every entry that has run or been warmed,
+        oldest first: a jax Compiled, or None for an entry that serves
+        through plain jit (nothing to inspect)."""
+        with self._lock:
+            entries = sorted(self._fns.values(),
+                             key=lambda e: e.record["created_unix"])
+        return [e.compiled for e in entries
+                if e.record.get("source") is not None]
 
     def ledger_snapshot(self) -> list[dict]:
         """Per-entry compile-cost records, oldest first. `trace_s` /

@@ -16,7 +16,7 @@ Same safety model as the AOT cache, scaled to JSON-sized entries:
   wrong-runtime entry (a TPU optimum read on the CPU mesh, a topology
   change) is IGNORED — and overwritten by the next probe — but never
   consumed. The chunk optimum moved 256 → 32768 → 65536 across
-  hardware/kernel changes (ROUND5_NOTES.md); a cache that served a
+  hardware/kernel changes; a cache that served a
   stale platform's winner would silently re-introduce exactly the
   drift the tuner exists to kill.
 - **Integrity**: entries are written temp + fsync + atomic rename with

@@ -10,18 +10,18 @@ on shared submeshes). This module is the ONE table all three consume —
 and the tier the Autotuner (tune/tuner.py) falls back to when no
 probed entry exists for a shape.
 
-Provenance of the measured rows (do not "clean up" these numbers
-without a measurement — each was a perf round):
+Provenance of the rows (do not "clean up" these numbers without a
+measurement on the chip). The builder records of the round-5 chip
+sweeps were deleted in PR 21 (they ran through a retired remote
+runtime); their rates are not measured on chip this round:
 
-- ``bench`` 20x20 chunk 65536: ROUND5_NOTES.md — 73.5M evals/s at
-  65536 vs 67.8M at 32768 on v5e after the bf16 act matmul made the
-  pair sweeps ~4x cheaper (81920/98304/131072 regress; pow2 keeps the
-  lanes aligned).
+- ``bench`` 20x20 chunk 65536: the round-5 chip sweep picked 65536
+  over 32768 after the bf16 act matmul made the pair sweeps cheaper
+  (pow2 keeps the lanes aligned).
 - ``balance_period=4`` everywhere: tools/bench_balance_period.py
-  on-chip — 6.40 ms/iter at period 4 vs 6.64 at 1 and 6.53 at 16 on
-  identical ta021 state (±2% noise), so the period is chosen for
-  SPREAD (per-worker tree CV 0.16 at 4 vs 0.20 at 16, BENCHMARKS.md).
-  The CPU mesh's preference for sparse periods is a host-serialized-
+  on-chip found the balance round's cost flat across periods, so the
+  period is chosen for SPREAD (per-worker tree variation). The CPU
+  mesh's preference for sparse periods is a host-serialized-
   collectives artifact; never retune this knob on the virtual mesh.
 - ``serving`` chunk 64: the service's preemption/deadline reaction
   granularity — stop flags land at segment boundaries, and a

@@ -63,8 +63,7 @@ class ProbeHarness:
     """Warm ONCE per (instance, bound), measure MANY candidates on the
     identical state. Single-device mesh by construction (the same-state
     method needs one canonical pool; the per-worker program cost is
-    what the knobs move — spread effects are documented separately in
-    BENCHMARKS.md's sensitivity table).
+    what the knobs move — spread effects are not measured here).
 
     `problem` (registry name or plugin object, default "pfsp")
     generalizes the harness to every registered workload: the pool is

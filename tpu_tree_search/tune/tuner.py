@@ -2,7 +2,7 @@
 
 Every perf round so far re-tuned the engine's dispatch knobs BY HAND:
 chunk went 256 → 32768 → 65536 when the bf16 matmul changed the cost
-structure (ROUND5_NOTES.md), and balance_period=4 came from a one-off
+structure, and balance_period=4 came from a one-off
 tools/bench_balance_period.py sweep the ROADMAP warns cannot be
 re-derived on the virtual mesh. The Autotuner retires that ritual:
 
@@ -259,9 +259,9 @@ class Autotuner:
         # the winner chunk to its measured winner pipeline at serve
         # time. Probes stay PFSP-only (the fused kernels are the PFSP
         # fast path) and interpret admits every shape; when the hw
-        # route returns (on-chip round), this gate must also consult
-        # pallas_fused.fused_ok per shape so a kernel-rejected shape
-        # never pays fused probes the step would silently run unfused.
+        # route returns (ROADMAP A2), this gate must also apply the
+        # expand kernel's shape rule per shape so a kernel-rejected
+        # shape never pays fused probes the step would run unfused.
         from ..engine import ladder as _ladder
         from ..ops import pallas_fused
         from ..problems import get as _get_problem

@@ -503,9 +503,8 @@ KNOBS: dict[str, Knob] = _knob_table(
     Knob("TTS_FUSED", "flag", False,
          "fused Pallas bound+prune+compact route (ops/pallas_fused): "
          "pruned children never touch HBM; static per executable, "
-         "bit-identical counts on/off. On a TPU backend resolves OFF "
-         "(one warning) until the Mosaic lowering's first on-chip "
-         "validation round"),
+         "bit-identical counts on/off. On a TPU backend it raises: "
+         "Mosaic cannot lower the kernels' sort (ROADMAP A2)"),
     Knob("TTS_FUSED_INTERPRET", "flag", False,
          "run the fused kernels under the Pallas interpreter on "
          "non-TPU backends (the CI kernel-logic leg; no effect on "
@@ -730,14 +729,7 @@ KNOBS: dict[str, Knob] = _knob_table(
     Knob("TTS_REMEDIATE_PROBE_S", "float", REMEDIATE_PROBE_S_DEFAULT,
          "canary-probe cooldown: seconds after a quarantine (or a "
          "failed probe) before the synthetic micro-request retries"),
-    # --- XLA persistent compile cache
-    Knob("TTS_NO_COMPILE_CACHE", "flag", False,
-         "opt out of XLA's persistent compilation cache"),
-    Knob("TTS_COMPILE_CACHE_DIR", "str", None,
-         "redirect the XLA persistent compilation cache directory"),
     # --- bench.py
-    Knob("TTS_BENCH_PLATFORM", "str", None,
-         "bench: force a jax platform before backend init", "bench"),
     Knob("TTS_BENCH_INSTANCE", "int", 21,
          "bench: Taillard instance id", "bench"),
     Knob("TTS_BENCH_CHUNK", "int", None,

@@ -24,7 +24,7 @@ programmatically via :func:`configure`:
                                              # (slow dispatch)
     TTS_FAULTS="fail_host_fetch=1"           # first 1 host fetches raise
                                              # InjectedFault (transient
-                                             # device/tunnel error)
+                                             # device error)
     TTS_FAULTS="delay_every=0.05"            # sleep 0.05 s before EVERY
                                              # segment (uniform slowdown —
                                              # makes short searches span
