@@ -1200,11 +1200,12 @@ def run_segmented(run_fn, state: SearchState, segment_iters: int = 2048,
         if results_ready_t is not None:
             gap_hist.observe(max(0.0, time.monotonic() - results_ready_t))
         with tracelog.span("segment", segment=seg + 1) as seg_span:
-            state = _retry(
-                lambda: _with_watchdog(
-                    lambda: run_fn(prev_state, target),
-                    segment_timeout_s, f"segment {seg + 1}"),
-                "segment execution", retry_attempts, retry_base_s)
+            with tracelog.span("segment.dispatch", segment=seg + 1):
+                state = _retry(
+                    lambda: _with_watchdog(
+                        lambda: run_fn(prev_state, target),
+                        segment_timeout_s, f"segment {seg + 1}"),
+                    "segment execution", retry_attempts, retry_base_s)
             if post_segment is not None:
                 state = post_segment(state)
             seg += 1
@@ -1214,16 +1215,18 @@ def run_segmented(run_fn, state: SearchState, segment_iters: int = 2048,
             # so a hung device computation lets run_fn return its
             # futures instantly and the block happens HERE, waiting on
             # the results
-            fetched = _retry(
-                lambda: _with_watchdog(
-                    lambda: _fetch_many(
-                        (state.iters, state.tree, state.sol,
-                         state.size, state.best, state.steals,
-                         state.overflow, state.evals)
-                        + ((state.telemetry,) if folder.tele_w
-                           else ())),
-                    segment_timeout_s, f"segment {seg} result fetch"),
-                "per-segment host fetch", retry_attempts, retry_base_s)
+            with tracelog.span("segment.wait", segment=seg):
+                fetched = _retry(
+                    lambda: _with_watchdog(
+                        lambda: _fetch_many(
+                            (state.iters, state.tree, state.sol,
+                             state.size, state.best, state.steals,
+                             state.overflow, state.evals)
+                            + ((state.telemetry,) if folder.tele_w
+                               else ())),
+                        segment_timeout_s, f"segment {seg} result fetch"),
+                    "per-segment host fetch", retry_attempts,
+                    retry_base_s)
             results_ready_t = time.monotonic()
             f_ovf = fetched[6]
             seg_span.set(iters=int(fetched[0].max()),
@@ -1240,7 +1243,8 @@ def run_segmented(run_fn, state: SearchState, segment_iters: int = 2048,
             "segment wall latency (execute+fetch)"
             ).observe(seg_span.dur)
         if heartbeat is not None:
-            heartbeat(report)
+            with tracelog.span("segment.heartbeat", segment=seg):
+                heartbeat(report)
         if checkpoint_path and seg % checkpoint_every == 0:
             do_save(state, seg)
         # preemption injection point: fires at the END of segment k,
@@ -1326,23 +1330,28 @@ def _run_segmented_overlap(run_fn, state: SearchState, *, segment_iters,
             else dict(checkpoint_meta or {})
         return {**base, "segment": seg_no}
 
+    def dispatch(s, seg_no):
+        with tracelog.span("segment.dispatch", segment=seg_no):
+            return run_fn(s, target_for(seg_no))
+
     def fetch_counters(cur, seg_no):
         # the ONLY per-segment fetch on the hot path: the small
         # counter/telemetry block (the full state is fetched solely on
         # checkpoint segments, via the writer's prepare())
-        return _retry(
-            lambda: _with_watchdog(
-                lambda: _fetch_many(
-                    (cur.iters, cur.tree, cur.sol, cur.size, cur.best,
-                     cur.steals, cur.overflow, cur.evals)
-                    + ((cur.telemetry,) if folder.tele_w else ())),
-                segment_timeout_s, f"segment {seg_no} result fetch"),
-            "per-segment host fetch", retry_attempts, retry_base_s)
+        with tracelog.span("segment.wait", segment=seg_no):
+            return _retry(
+                lambda: _with_watchdog(
+                    lambda: _fetch_many(
+                        (cur.iters, cur.tree, cur.sol, cur.size, cur.best,
+                         cur.steals, cur.overflow, cur.evals)
+                        + ((cur.telemetry,) if folder.tele_w else ())),
+                    segment_timeout_s, f"segment {seg_no} result fetch"),
+                "per-segment host fetch", retry_attempts, retry_base_s)
 
     try:
         faults.fire("segment_start", segment=1)
         dispatch_t = time.monotonic()
-        cur = run_fn(state, target_for(1))
+        cur = dispatch(state, 1)
         halting = False
         results_ready_t = None
         while True:
@@ -1364,7 +1373,7 @@ def _run_segmented_overlap(run_fn, state: SearchState, *, segment_iters,
                 faults.fire("segment_start", segment=seg + 1)
                 next_fired = True
                 spec_t = time.monotonic()
-                spec = run_fn(cur, target_for(seg + 1))
+                spec = dispatch(cur, seg + 1)
 
             fetched = fetch_counters(cur, seg)
             prev_ready_t = results_ready_t
@@ -1379,7 +1388,7 @@ def _run_segmented_overlap(run_fn, state: SearchState, *, segment_iters,
             while bool(f_ovf.any()) and grow_fn is not None:
                 if spec is not None:
                     cur, spec = spec, None
-                cur = run_fn(grow_fn(cur), target_for(seg))
+                cur = dispatch(grow_fn(cur), seg)
                 fetched = fetch_counters(cur, seg)
                 results_ready_t = time.monotonic()
                 (f_iters, f_tree, f_sol, sizes, f_best, f_steals,
@@ -1391,20 +1400,22 @@ def _run_segmented_overlap(run_fn, state: SearchState, *, segment_iters,
                 # prepare() on this thread, then dispatch, then hand
                 # the compress+fsync to the writer (enqueue may block
                 # on back-pressure, but the device is already running)
-                task = _retry(
-                    lambda: _with_watchdog(
-                        lambda: writer.prepare(
-                            checkpoint_path, cur, meta_now(seg),
-                            segment=seg),
-                        segment_timeout_s,
-                        f"segment {seg} checkpoint fetch"),
-                    "checkpoint state fetch", retry_attempts,
-                    retry_base_s)
+                with tracelog.span("segment.wait", segment=seg,
+                                   checkpoint=True):
+                    task = _retry(
+                        lambda: _with_watchdog(
+                            lambda: writer.prepare(
+                                checkpoint_path, cur, meta_now(seg),
+                                segment=seg),
+                            segment_timeout_s,
+                            f"segment {seg} checkpoint fetch"),
+                        "checkpoint state fetch", retry_attempts,
+                        retry_base_s)
                 if can_speculate():
                     faults.fire("segment_start", segment=seg + 1)
                     next_fired = True
                     spec_t = time.monotonic()
-                    spec = run_fn(cur, target_for(seg + 1))
+                    spec = dispatch(cur, seg + 1)
                 writer.enqueue(task)
 
             tracelog.span_at("segment", this_dispatch_t,
@@ -1420,7 +1431,8 @@ def _run_segmented_overlap(run_fn, state: SearchState, *, segment_iters,
             report = folder.fold(fetched, seg)
             iters, size = report.iters, report.pool_size
             if heartbeat is not None:
-                heartbeat(report)
+                with tracelog.span("segment.heartbeat", segment=seg):
+                    heartbeat(report)
             faults.fire("post_segment", segment=seg)
 
             overflow_exit = bool(f_ovf.any())
@@ -1468,7 +1480,7 @@ def _run_segmented_overlap(run_fn, state: SearchState, *, segment_iters,
                     # and break overlap-vs-sync injection parity
                     faults.fire("segment_start", segment=seg + 1)
                 dispatch_t = time.monotonic()
-                cur = run_fn(cur, target_for(seg + 1))
+                cur = dispatch(cur, seg + 1)
     finally:
         if writer is not None:
             # success paths drained above; this is the unwind valve —

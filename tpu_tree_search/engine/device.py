@@ -37,6 +37,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs import tracelog
 from ..ops import batched, pallas_expand, pallas_fused, reference as ref
 from ..ops.batched import BoundTables
 from ..utils import config as _cfg
@@ -644,7 +645,8 @@ def _lb2_tail(tables: BoundTables, state: SearchState, children, caux,
         # return an empty tail table whose (0, frame) pair-max has no
         # identity — so ONE full sweep over the LB1 survivors is the
         # whole LB2.
-        lb2b = _sweep_tiers(tables, caux[:M], sched, ncand, N, J, M)
+        with jax.named_scope("bound"):
+            lb2b = _sweep_tiers(tables, caux[:M], sched, ncand, N, J, M)
         live = ncand
         if TELE:
             head_hp = jnp.zeros(tele.BOUND_BINS, jnp.int64)
@@ -659,50 +661,57 @@ def _lb2_tail(tables: BoundTables, state: SearchState, children, caux,
         # max(head, tail) = full LB2, so explored trees are
         # bit-identical to the single-sweep path.
         SW = pallas_expand.sched_words(J)
-        head_t, tail_t = batched.pair_split(tables, KH)
-        lb2h = _sweep_tiers(head_t, caux[:M], sched, ncand, N, J, M)
-        keep = ((jnp.arange(W_) < ncand)
-                & (lb2h.reshape(-1) < best))
+        with jax.named_scope("bound"):
+            head_t, tail_t = batched.pair_split(tables, KH)
+            lb2h = _sweep_tiers(head_t, caux[:M], sched, ncand, N, J, M)
+        with jax.named_scope("prune"):
+            keep = ((jnp.arange(W_) < ncand)
+                    & (lb2h.reshape(-1) < best))
         if TELE:
             # pruned by the strong-pair head sweep: binned at the
             # partial bound that pruned them (a sound lower bound —
             # partial max <= LB2)
             head_hp = tele.bound_hist(
                 lb2h, (jnp.arange(W_) < ncand) & ~keep, best)
-        nkeep = keep.sum(dtype=jnp.int32)
-        permh = _partition_prefix(keep, ncand, N, two_phase=True,
-                                  cap=W_)
-        # the partial bound rides the compaction as an extra row
-        # (three structural variants were tried and measured WORSE: an
-        # index-composed final gather that skips re-gathering children
-        # — the composing (N,) take lowers to a ~4.7 ms serialized
-        # gather; one combined i32 block per compaction — +60% gather
-        # time, byte-bound at 40+ rows; and gathering these blocks in
-        # the pool's int16 aux dtype — TPU column gathers are
-        # element/latency-bound, i16 made them SLOWER (+18%), so the
-        # narrow dtype lives only at the pool boundary, see step())
-        aux_plus = jnp.concatenate([caux, sched, lb2h], axis=0)
-        children, aux_plus = _tiered_compact(
-            _take_block(children, aux_plus), permh, nkeep, N,
-            two_phase=True, cap=W_)
-        # barrier: the tail sweep's pallas call must see the
-        # mid-compaction's switch outputs materialized — without this,
-        # XLA's fusion of the slice chain miscompiles the compiled
-        # (jitted) step on TPU and the tail sweep reads stale columns,
-        # silently over-pruning (eager and debug-tapped traces are
-        # correct — caught by test_prefilter_branch_matches_oracle on
-        # hardware)
-        aux_plus = jax.lax.optimization_barrier(aux_plus)
-        caux = aux_plus[:M + 1]
-        sched = aux_plus[M + 1:M + 1 + SW]
-        lb2h_c = aux_plus[M + 1 + SW:M + 2 + SW]
-        lb2t = _sweep_tiers(tail_t, caux[:M], sched, nkeep, N, J, M)
-        lb2b = jnp.maximum(lb2h_c, lb2t)
+        with jax.named_scope("prune"):
+            nkeep = keep.sum(dtype=jnp.int32)
+        with jax.named_scope("compact"):
+            permh = _partition_prefix(keep, ncand, N, two_phase=True,
+                                      cap=W_)
+            # the partial bound rides the compaction as an extra row
+            # (three structural variants were tried and measured WORSE:
+            # an index-composed final gather that skips re-gathering
+            # children — the composing (N,) take lowers to a ~4.7 ms
+            # serialized gather; one combined i32 block per compaction
+            # — +60% gather time, byte-bound at 40+ rows; and gathering
+            # these blocks in the pool's int16 aux dtype — TPU column
+            # gathers are element/latency-bound, i16 made them SLOWER
+            # (+18%), so the narrow dtype lives only at the pool
+            # boundary, see step())
+            aux_plus = jnp.concatenate([caux, sched, lb2h], axis=0)
+            children, aux_plus = _tiered_compact(
+                _take_block(children, aux_plus), permh, nkeep, N,
+                two_phase=True, cap=W_)
+            # barrier: the tail sweep's pallas call must see the
+            # mid-compaction's switch outputs materialized — without
+            # this, XLA's fusion of the slice chain miscompiles the
+            # compiled (jitted) step on TPU and the tail sweep reads
+            # stale columns, silently over-pruning (eager and
+            # debug-tapped traces are correct — caught by
+            # test_prefilter_branch_matches_oracle on hardware)
+            aux_plus = jax.lax.optimization_barrier(aux_plus)
+            caux = aux_plus[:M + 1]
+            sched = aux_plus[M + 1:M + 1 + SW]
+            lb2h_c = aux_plus[M + 1 + SW:M + 2 + SW]
+        with jax.named_scope("bound"):
+            lb2t = _sweep_tiers(tail_t, caux[:M], sched, nkeep, N, J, M)
+            lb2b = jnp.maximum(lb2h_c, lb2t)
         live = nkeep
 
-    push = ((jnp.arange(W_) < live)
-            & (lb2b.reshape(-1) < best))
-    n_push = push.sum(dtype=jnp.int32)
+    with jax.named_scope("prune"):
+        push = ((jnp.arange(W_) < live)
+                & (lb2b.reshape(-1) < best))
+        n_push = push.sum(dtype=jnp.int32)
     if TELE:
         # branched buckets + bound histograms, computed while caux
         # still aligns column-for-column with push/lb2b (the final
@@ -726,18 +735,21 @@ def _lb2_tail(tables: BoundTables, state: SearchState, children, caux,
 
     # final compaction: direct prefix gather of the already-built
     # block (sources are the compacted (features, W_) arrays)
-    perm2 = _partition_prefix(push, live, N, two_phase=True, cap=W_)
-    children, child_aux = _tiered_compact(
-        _take_block(children, caux), perm2, n_push, N,
-        two_phase=True, cap=W_)
-    child_depth = child_aux[M].astype(jnp.int16)
+    with jax.named_scope("compact"):
+        perm2 = _partition_prefix(push, live, N, two_phase=True, cap=W_)
+        children, child_aux = _tiered_compact(
+            _take_block(children, caux), perm2, n_push, N,
+            two_phase=True, cap=W_)
+        child_depth = child_aux[M].astype(jnp.int16)
 
     # pool write inside the branch: the written block is W_-wide, so
     # the steady branch moves a quarter of the bytes (_write_block
     # owns the overflow scratch-margin routing, shared with the common
     # path)
-    prmu, depth, aux = _write_block(
-        state, children, child_depth, child_aux, start, n_push, limit)
+    with jax.named_scope("push"):
+        prmu, depth, aux = _write_block(
+            state, children, child_depth, child_aux, start, n_push,
+            limit)
     out = (prmu, depth, aux, n_push, hsum, tsum)
     if TELE:
         out += (tele_tail,)
@@ -808,10 +820,11 @@ def _fused_step(tables: BoundTables, lb_kind: int, route, chunk: int,
     N = B * J
     TELE = state.telemetry.shape[-1] > 0
 
-    leaf_best, n_leaf, evals_cnt = _leaf_scan(tables, p_prmu, p_depth,
-                                              p_aux, valid)
-    best = jnp.minimum(state.best, leaf_best)
-    sol = state.sol + n_leaf
+    with jax.named_scope("prune"):
+        leaf_best, n_leaf, evals_cnt = _leaf_scan(tables, p_prmu, p_depth,
+                                                  p_aux, valid)
+        best = jnp.minimum(state.best, leaf_best)
+        sol = state.sol + n_leaf
     if TELE:
         d = p_depth.reshape(-1)
         wb = tele.depth_bucket(d, J)
@@ -846,13 +859,14 @@ def _fused_step(tables: BoundTables, lb_kind: int, route, chunk: int,
     # re-bounds survivors with the pair sweeps), and the LB1 caux
     # block can ride the pool's own narrow aux dtype — every output
     # byte of the kernel is the fused route's whole HBM footprint
-    kch, kaux, kbnd, ksched, n_surv, khist = pallas_fused.fused_expand(
-        tables, p_prmu, p_depth, p_aux, n, best, lb_kind=1, tile=TB,
-        cap_width=W, with_sched=(route == "prefilter"),
-        tele_bins=tele.BOUND_BINS if TELE else 0,
-        with_bounds=(lb_kind != 2 and TELE),
-        aux_i16=(lb_kind != 2 and state.aux.dtype == jnp.int16),
-        interpret=(mode == "interpret"))
+    with jax.named_scope("bound"):
+        kch, kaux, kbnd, ksched, n_surv, khist = pallas_fused.fused_expand(
+            tables, p_prmu, p_depth, p_aux, n, best, lb_kind=1, tile=TB,
+            cap_width=W, with_sched=(route == "prefilter"),
+            tele_bins=tele.BOUND_BINS if TELE else 0,
+            with_bounds=(lb_kind != 2 and TELE),
+            aux_i16=(lb_kind != 2 and state.aux.dtype == jnp.int16),
+            interpret=(mode == "interpret"))
     if limit is None:
         limit = row_limit(capacity, B, J)
 
@@ -882,12 +896,13 @@ def _fused_step(tables: BoundTables, lb_kind: int, route, chunk: int,
 
     if lb_kind != 2:
         def fused_fit(_):
-            children = narrow_to_W(kch, J)
-            caux = narrow_to_W(kaux, M + 1)
-            child_depth = caux[M].astype(jnp.int16)
-            prmu, depth, aux = _write_block(
-                state, children, child_depth, caux, start, n_surv,
-                limit)
+            with jax.named_scope("push"):
+                children = narrow_to_W(kch, J)
+                caux = narrow_to_W(kaux, M + 1)
+                child_depth = caux[M].astype(jnp.int16)
+                prmu, depth, aux = _write_block(
+                    state, children, child_depth, caux, start, n_surv,
+                    limit)
             out = (prmu, depth, aux, n_surv)
             if TELE:
                 bnd = narrow_to_W(kbnd, 1)
@@ -911,9 +926,10 @@ def _fused_step(tables: BoundTables, lb_kind: int, route, chunk: int,
             delta = tele.step_delta(popped_b, bh[:DB],
                                     evalnl_b - bh[:DB],
                                     khist, bh[DB:])
-        return _commit(state, prmu, depth, aux, n_push, best, sol,
-                       jnp.asarray(evals_cnt), limit, start,
-                       tele_delta=delta)
+        with jax.named_scope("push"):
+            return _commit(state, prmu, depth, aux, n_push, best, sol,
+                           jnp.asarray(evals_cnt), limit, start,
+                           tele_delta=delta)
 
     # --- route == "prefilter": the kernel was the fused LB1 prefilter
     P = int(tables.ma0.shape[0])
@@ -923,21 +939,25 @@ def _fused_step(tables: BoundTables, lb_kind: int, route, chunk: int,
     ncand = n_surv
 
     def fused_tail(_):
-        children = narrow_to_W(kch, J)
-        caux = narrow_to_W(kaux, M + 1)
-        sched = narrow_to_W(ksched, SW)
+        with jax.named_scope("compact"):
+            children = narrow_to_W(kch, J)
+            caux = narrow_to_W(kaux, M + 1)
+            sched = narrow_to_W(ksched, SW)
         return _lb2_tail(tables, state, children, caux, sched, ncand,
                          W, N, best, start, limit, debug_tap, TELE)
 
     def spill_tail(_):
-        lb1b = pallas_expand.expand_bounds(
-            tables, p_prmu, p_depth, p_aux, lb_kind=1, tile=TB)
-        _, mask, is_leaf = dense_masks()
-        cand = (mask & ~is_leaf & (lb1b < best)).reshape(-1)
-        perm1 = _partition(cand)
-        children, caux, sched = _compact_from_parents(
-            tables, p_prmu, p_depth, p_aux, perm1, ncand, TB, N,
-            with_sched=True, two_phase=True, cap=N)
+        with jax.named_scope("bound"):
+            lb1b = pallas_expand.expand_bounds(
+                tables, p_prmu, p_depth, p_aux, lb_kind=1, tile=TB)
+        with jax.named_scope("prune"):
+            _, mask, is_leaf = dense_masks()
+            cand = (mask & ~is_leaf & (lb1b < best)).reshape(-1)
+        with jax.named_scope("compact"):
+            perm1 = _partition(cand)
+            children, caux, sched = _compact_from_parents(
+                tables, p_prmu, p_depth, p_aux, perm1, ncand, TB, N,
+                with_sched=True, two_phase=True, cap=N)
         return _lb2_tail(tables, state, children, caux, sched, ncand,
                          N, N, best, start, limit, debug_tap, TELE)
 
@@ -956,9 +976,10 @@ def _fused_step(tables: BoundTables, lb_kind: int, route, chunk: int,
         delta = tele.step_delta(
             popped_b, branched_b, evalnl_b - branched_b,
             khist + outs[6][DB:DB + BB], outs[6][DB + BB:])
-    return _commit(state, prmu, depth, aux, n_push, best, sol,
-                   jnp.asarray(evals_cnt), limit, start,
-                   tele_delta=delta)
+    with jax.named_scope("push"):
+        return _commit(state, prmu, depth, aux, n_push, best, sol,
+                       jnp.asarray(evals_cnt), limit, start,
+                       tele_delta=delta)
 
 
 def step(tables: BoundTables, lb_kind: int, chunk: int,
@@ -992,16 +1013,17 @@ def step(tables: BoundTables, lb_kind: int, chunk: int,
     G = B // TB
     N = B * J
 
-    p_prmu, p_depth, p_aux, n, start, valid = pop_chunk(state, B, M)
-    # The pool stores aux in the narrow per-instance dtype (aux_dtype:
-    # int16 for every class whose completion times fit); intra-step
-    # blocks are all i32 — measured on v5e: TPU column gathers are
-    # element/latency-bound, so narrow GATHERS buy nothing (+18% step
-    # time when tried), while the sequential push block-write IS
-    # byte-bound and pays half, and the balance all_to_all + checkpoint
-    # + pool HBM footprint halve too. The cast back happens at the
-    # write below.
-    p_aux = p_aux.astype(jnp.int32)
+    with jax.named_scope("pop"):
+        p_prmu, p_depth, p_aux, n, start, valid = pop_chunk(state, B, M)
+        # The pool stores aux in the narrow per-instance dtype
+        # (aux_dtype: int16 for every class whose completion times
+        # fit); intra-step blocks are all i32 — measured on v5e: TPU
+        # column gathers are element/latency-bound, so narrow GATHERS
+        # buy nothing (+18% step time when tried), while the sequential
+        # push block-write IS byte-bound and pays half, and the balance
+        # all_to_all + checkpoint + pool HBM footprint halve too. The
+        # cast back happens at the write below.
+        p_aux = p_aux.astype(jnp.int32)
 
     # --- fused bound+prune+compact route (ops/pallas_fused): STATIC
     # gate — `fused` is a static argument threaded from the host-side
@@ -1017,7 +1039,8 @@ def step(tables: BoundTables, lb_kind: int, chunk: int,
 
     # --- masks in the kernel's child-slot column order (shared with
     # the fused spill branches — _child_masks)
-    depth_c, mask = _child_masks(p_depth, valid, G, J, TB)     # (1, N)
+    with jax.named_scope("bound"):
+        depth_c, mask = _child_masks(p_depth, valid, G, J, TB)  # (1, N)
 
     # --- search telemetry (STATIC Python branch: with the block off the
     # traced program contains zero telemetry ops). Common inputs shared
@@ -1049,17 +1072,20 @@ def step(tables: BoundTables, lb_kind: int, chunk: int,
         # is static. The explored set is identical either way (the final
         # prune uses the same exact LB2 values), matching the
         # reference's single code path (bounds_gpu.cu:252-316).
-        _, _, lb2b = pallas_expand.expand(
-            tables, p_prmu, p_depth, p_aux, lb_kind=2, tile=TB)
+        with jax.named_scope("bound"):
+            _, _, lb2b = pallas_expand.expand(
+                tables, p_prmu, p_depth, p_aux, lb_kind=2, tile=TB)
 
-        is_leaf = ((depth_c + 1) == J) & mask
-        sol = state.sol + is_leaf.sum(dtype=jnp.int64)
-        # a complete schedule's LB2 == its makespan
-        leaf_best = jnp.where(is_leaf, lb2b, I32_MAX).min()
-        best = jnp.minimum(state.best, leaf_best)
+        with jax.named_scope("prune"):
+            is_leaf = ((depth_c + 1) == J) & mask
+            sol = state.sol + is_leaf.sum(dtype=jnp.int64)
+            # a complete schedule's LB2 == its makespan
+            leaf_best = jnp.where(is_leaf, lb2b, I32_MAX).min()
+            best = jnp.minimum(state.best, leaf_best)
 
-        push = (mask & ~is_leaf & (lb2b.reshape(1, -1) < best)).reshape(-1)
-        n_push = push.sum(dtype=jnp.int32)
+            push = (mask & ~is_leaf
+                    & (lb2b.reshape(1, -1) < best)).reshape(-1)
+            n_push = push.sum(dtype=jnp.int32)
         if TELE:
             branched_b = tele.bucket_counts(child_b, push)
             hist_surv = tele.bound_hist(lb2b, push, best)
@@ -1077,11 +1103,12 @@ def step(tables: BoundTables, lb_kind: int, chunk: int,
         # materialization is cheap relative to the cliff-priced dense
         # gathers this replaces (measured: ta033 1.21M -> 1.65M
         # pushed/s).
-        perm = _partition(push)
-        children, child_aux = _compact_from_parents(
-            tables, p_prmu, p_depth, p_aux, perm, n_push, TB, N,
-            two_phase=True)
-        child_depth = child_aux[M].astype(jnp.int16)
+        with jax.named_scope("compact"):
+            perm = _partition(push)
+            children, child_aux = _compact_from_parents(
+                tables, p_prmu, p_depth, p_aux, perm, n_push, TB, N,
+                two_phase=True)
+            child_depth = child_aux[M].astype(jnp.int16)
     elif route == "prefilter":
         # Two-phase LB2 (TPU): bound every child with the near-free LB1
         # first (LB1 <= LB2, so LB1-pruning is sound and the explored
@@ -1091,17 +1118,19 @@ def step(tables: BoundTables, lb_kind: int, chunk: int,
         # UB=opt LB1 removes ~85% of the child grid. The reference gets
         # its version of this saving from the per-child early exit the
         # vector unit cannot take (c_bound_johnson.c:231-233).
-        lb1b = pallas_expand.expand_bounds(
-            tables, p_prmu, p_depth, p_aux, lb_kind=1, tile=TB)
+        with jax.named_scope("bound"):
+            lb1b = pallas_expand.expand_bounds(
+                tables, p_prmu, p_depth, p_aux, lb_kind=1, tile=TB)
 
-        is_leaf = ((depth_c + 1) == J) & mask
-        sol = state.sol + is_leaf.sum(dtype=jnp.int64)
-        # a complete schedule's LB1 == LB2 == its makespan
-        leaf_best = jnp.where(is_leaf, lb1b, I32_MAX).min()
-        best = jnp.minimum(state.best, leaf_best)
+        with jax.named_scope("prune"):
+            is_leaf = ((depth_c + 1) == J) & mask
+            sol = state.sol + is_leaf.sum(dtype=jnp.int64)
+            # a complete schedule's LB1 == LB2 == its makespan
+            leaf_best = jnp.where(is_leaf, lb1b, I32_MAX).min()
+            best = jnp.minimum(state.best, leaf_best)
 
-        cand = (mask & ~is_leaf & (lb1b < best)).reshape(-1)
-        ncand = cand.sum(dtype=jnp.int32)
+            cand = (mask & ~is_leaf & (lb1b < best)).reshape(-1)
+            ncand = cand.sum(dtype=jnp.int32)
         if TELE:
             # children the LB1 prefilter pruned, binned at the bound
             # that pruned them (the tail sweep's prunes bin at their
@@ -1109,7 +1138,8 @@ def step(tables: BoundTables, lb_kind: int, chunk: int,
             hist_lb1_pruned = tele.bound_hist(
                 lb1b, (mask & ~is_leaf).reshape(-1) & ~cand, best)
 
-        perm1 = _partition(cand)
+        with jax.named_scope("compact"):
+            perm1 = _partition(cand)
         debug_tap = bool(__debug__ and P > KH and _DEBUG_STEP)
         if limit is None:
             limit = row_limit(capacity, B, J)
@@ -1134,9 +1164,10 @@ def step(tables: BoundTables, lb_kind: int, chunk: int,
             evals/s), which is why the narrow width is threaded through
             the whole pipeline rather than applied at the gathers."""
             def f(_):
-                children, caux, sched = _compact_from_parents(
-                    tables, p_prmu, p_depth, p_aux, perm1, ncand, TB, N,
-                    with_sched=True, two_phase=True, cap=W_)
+                with jax.named_scope("compact"):
+                    children, caux, sched = _compact_from_parents(
+                        tables, p_prmu, p_depth, p_aux, perm1, ncand, TB,
+                        N, with_sched=True, two_phase=True, cap=W_)
                 return _lb2_tail(tables, state, children, caux, sched,
                                  ncand, W_, N, best, start, limit,
                                  debug_tap, TELE)
@@ -1177,25 +1208,29 @@ def step(tables: BoundTables, lb_kind: int, chunk: int,
                 popped_b, branched_b, evalnl_b - branched_b,
                 hist_lb1_pruned + outs[6][DB:DB + BB],
                 outs[6][DB + BB:])
-        return _commit(state, prmu, depth, aux, n_push, best, sol, mask,
-                       limit, start, tele_delta=delta)
+        with jax.named_scope("push"):
+            return _commit(state, prmu, depth, aux, n_push, best, sol,
+                           mask, limit, start, tele_delta=delta)
     else:
         # --- bounds of the dense child grid (Pallas on TPU; the children
         # themselves are never materialized — survivors are rebuilt from
         # their parents below)
-        bounds = pallas_expand.expand_bounds(
-            tables, p_prmu, p_depth, p_aux, lb_kind=lb_kind, tile=TB)
+        with jax.named_scope("bound"):
+            bounds = pallas_expand.expand_bounds(
+                tables, p_prmu, p_depth, p_aux, lb_kind=lb_kind, tile=TB)
 
-        # --- leaves: complete schedules; count + tighten incumbent
-        # (reference: the depth==jobs branch of decompose, PFSP_lib.c:24-32)
-        is_leaf = ((depth_c + 1) == J) & mask
-        sol = state.sol + is_leaf.sum(dtype=jnp.int64)
-        leaf_best = jnp.where(is_leaf, bounds, I32_MAX).min()
-        best = jnp.minimum(state.best, leaf_best)
+        with jax.named_scope("prune"):
+            # --- leaves: complete schedules; count + tighten incumbent
+            # (reference: the depth==jobs branch of decompose,
+            # PFSP_lib.c:24-32)
+            is_leaf = ((depth_c + 1) == J) & mask
+            sol = state.sol + is_leaf.sum(dtype=jnp.int64)
+            leaf_best = jnp.where(is_leaf, bounds, I32_MAX).min()
+            best = jnp.minimum(state.best, leaf_best)
 
-        # --- prune + push surviving internal children
-        push = (mask & ~is_leaf & (bounds < best)).reshape(-1)
-        n_push = push.sum(dtype=jnp.int32)
+            # --- prune + push surviving internal children
+            push = (mask & ~is_leaf & (bounds < best)).reshape(-1)
+            n_push = push.sum(dtype=jnp.int32)
         if TELE:
             branched_b = tele.bucket_counts(child_b, push)
             hist_surv = tele.bound_hist(bounds, push, best)
@@ -1211,21 +1246,24 @@ def step(tables: BoundTables, lb_kind: int, chunk: int,
         # The top chunk*J rows of the pool are a scratch margin (see
         # row_limit) so the block write stays in bounds even when the
         # live region is full.
-        perm = _partition(push)
-        children, child_aux = _compact_from_parents(
-            tables, p_prmu, p_depth, p_aux, perm, n_push, TB, N)
-        child_depth = child_aux[M].astype(jnp.int16)
+        with jax.named_scope("compact"):
+            perm = _partition(push)
+            children, child_aux = _compact_from_parents(
+                tables, p_prmu, p_depth, p_aux, perm, n_push, TB, N)
+            child_depth = child_aux[M].astype(jnp.int16)
 
     if limit is None:
         limit = row_limit(capacity, B, J)
-    prmu, depth, aux = _write_block(state, children, child_depth,
-                                    child_aux, start, n_push, limit)
+    with jax.named_scope("push"):
+        prmu, depth, aux = _write_block(state, children, child_depth,
+                                        child_aux, start, n_push, limit)
     delta = (tele.step_delta(popped_b, branched_b,
                              evalnl_b - branched_b,
                              hist_pruned, hist_surv)
              if TELE else None)
-    return _commit(state, prmu, depth, aux, n_push, best, sol, mask,
-                   limit, start, tele_delta=delta)
+    with jax.named_scope("push"):
+        return _commit(state, prmu, depth, aux, n_push, best, sol, mask,
+                       limit, start, tele_delta=delta)
 
 
 @functools.partial(jax.jit,
@@ -1497,21 +1535,38 @@ def search(p_times: np.ndarray, lb_kind: int = 1, init_ub: int | None = None,
     search RESUMES from exactly where it stopped (checkpoint.grow) — the
     lossless static-shape replacement for the reference's
     realloc-on-push (round 1 restarted from scratch here).
+
+    Flight-recorded as a `search` span whose children split the host's
+    part from the wait on the chip: `search.prepare` (tables, seeded
+    state), `search.dispatch` (run's pool check and the jitted call,
+    with any trace, lowering and compile), `search.wait` (the first
+    host read of the result, the loop's one sync), `search.fetch` (the
+    counters) and, on overflow, `search.grow`.
     """
     from . import checkpoint
 
-    if tables is None:
-        tables = batched.make_tables(p_times)
     jobs = p_times.shape[1]
-    state = init_state(jobs, capacity, init_ub, p_times=p_times)
-    while True:
-        out = run(tables, state, lb_kind, chunk, max_iters, tile=tile)
-        if not bool(out.overflow):
-            return SearchResult(
-                explored_tree=int(out.tree), explored_sol=int(out.sol),
-                best=int(out.best), iters=int(out.iters),
-                evals=int(out.evals), overflow=False,
-                complete=int(out.size) == 0,
-            )
-        capacity *= 2
-        state = checkpoint.grow(out, capacity)
+    with tracelog.span("search", jobs=jobs, machines=p_times.shape[0],
+                       lb_kind=lb_kind, chunk=chunk, capacity=capacity):
+        with tracelog.span("search.prepare"):
+            if tables is None:
+                tables = batched.make_tables(p_times)
+            state = init_state(jobs, capacity, init_ub, p_times=p_times)
+        while True:
+            with tracelog.span("search.dispatch"):
+                out = run(tables, state, lb_kind, chunk, max_iters,
+                          tile=tile)
+            with tracelog.span("search.wait"):
+                overflow = bool(out.overflow)
+            if not overflow:
+                with tracelog.span("search.fetch"):
+                    return SearchResult(
+                        explored_tree=int(out.tree),
+                        explored_sol=int(out.sol),
+                        best=int(out.best), iters=int(out.iters),
+                        evals=int(out.evals), overflow=False,
+                        complete=int(out.size) == 0,
+                    )
+            capacity *= 2
+            with tracelog.span("search.grow", capacity=capacity):
+                state = checkpoint.grow(out, capacity)

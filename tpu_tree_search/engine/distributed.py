@@ -602,7 +602,10 @@ class _DistDriver:
             out = self._pin_empty(SearchState(*self._loop(capacity)(
                 self.tables, jnp.asarray(ceiling, jnp.int64),
                 self._cap(bound_cap), *state)))
-            if not bool(_fetch(out.overflow).any()):
+            # the overflow read is the host's wait on the loop
+            with tracelog.span("segment.wait"):
+                overflow = bool(_fetch(out.overflow).any())
+            if not overflow:
                 return out
             grown = checkpoint.grow(fetch_state(out), capacity * 2)
             state = self.commit(grown)
@@ -1030,340 +1033,346 @@ def search(p_times: np.ndarray, lb_kind: int = 1, init_ub: int | None = None,
     from ..utils import config as _cfg
     from . import checkpoint, hybrid, incumbent as inc_mod
 
-    prob = _resolve_problem(problem)
-    table = np.asarray(p_times)
-    if mesh is None:
-        mesh = worker_mesh(n_devices)
-    n_dev = mesh.devices.size
-    jobs = prob.slots(table)
-    if host_fraction > 0 and not prob.supports_host_tier:
-        from ..problems.base import HostTierUnsupported
-        raise HostTierUnsupported(prob.name)
-    rung_profile = None
-    fused_mode = pallas_fused.resolve_mode(None)
-    if chunk is None or balance_period is None:
-        # adaptive-dispatch resolution for the knobs the caller left
-        # open: tuned cache entry (zero probes — the hot path must
-        # never probe) else the measured-defaults table
-        from ..tune import defaults as tune_defaults
-        if tuner is not None:
-            params = tuner.resolve(jobs, table.shape[0], lb_kind,
-                                   n_workers=n_dev, allow_probe=False,
-                                   problem=prob.name)
-        else:
-            params = tune_defaults.params_for("serving", jobs,
-                                              table.shape[0],
-                                              problem=prob.name)
-        if chunk is None:
-            chunk = params.chunk
-            if transfer_cap is None and params.transfer_cap:
-                transfer_cap = params.transfer_cap
-        if balance_period is None:
-            balance_period = params.balance_period
-        # the tuner's per-rung kernel-vs-matmul profitability mask
-        # (Params.rung_modes) rides into rung construction below
-        rung_profile = params.rung_modes
-        tracelog.event("tuner.resolve", chunk=chunk,
-                       balance_period=balance_period,
-                       source=params.source,
-                       evals_per_s=params.evals_per_s,
-                       fused=fused_mode,
-                       rung_profile=bool(rung_profile))
-    if tables is None:
-        tables = prob.make_tables(table)
-    adt = prob.aux_dtype(table)
-    resumed = None
-    if checkpoint_path and checkpoint.resume_path(checkpoint_path):
-        # load BEFORE sizing the balance buffers: resume keeps the
-        # SAVED pools' aux dtype (an old int32-aux checkpoint stays
-        # int32, and a pre-aux legacy file is RECONSTRUCTED as int32 by
-        # checkpoint.load), so the byte budget must be priced off the
-        # loaded state, not the fresh-run dtype
-        resumed = checkpoint.load_resilient(
-            checkpoint_path,
-            p_times=table if prob.name == "pfsp" else None)[:2]
-        # a snapshot records its problem (pre-stamp legacy snapshots
-        # are all PFSP); a cross-problem resume is refused — the pool
-        # rows only mean anything under the problem that wrote them
-        saved_prob = resumed[1].get("problem")
-        saved_prob = ("pfsp" if saved_prob is None
-                      else str(np.asarray(saved_prob)))
-        if saved_prob != prob.name:
-            raise ValueError(
-                f"checkpoint {checkpoint_path} was written by problem "
-                f"{saved_prob!r}; refusing to resume it as "
-                f"{prob.name!r} (pick a fresh tag/checkpoint path)")
-        adt = np.asarray(resumed[0].aux).dtype
-    if ladder is None:
-        ladder = _cfg.env_flag(_cfg.LADDER_FLAG)
-    # the ladder switches at segment boundaries, so it engages only
-    # when segmented execution will run; a host tier keeps the single
-    # driver (its per-segment merge is enough moving parts) and
-    # multi-controller stays on the one-loop path, like overlap
-    use_ladder = (bool(ladder)
-                  and (segment_iters is not None
-                       or checkpoint_path is not None
-                       or stop_event is not None
-                       or should_stop is not None)
-                  and host_fraction == 0
-                  and jax.process_count() == 1)
-    ladder_drivers = None
-    if use_ladder:
-        # rung drivers get the caller's EXPLICIT transfer knobs (None
-        # derives per rung) and one unified limit — see _ladder_plan
-        rungs, ladder_drivers = _ladder_plan(
-            prob, mesh, tables, table, lb_kind, chunk, balance_period,
-            transfer_cap, min_transfer, adt, loop_cache,
-            rung_profile=rung_profile, fused_mode=fused_mode)
-        if len(rungs) < 2:
-            ladder_drivers = None      # chunk too small to ladder:
-            #                            plain single-driver path
-    if transfer_cap is None:
-        transfer_cap = default_transfer_cap(chunk, jobs,
-                                            prob.aux_rows(table),
-                                            mesh.devices.size,
-                                            aux_itemsize=adt.itemsize)
-    min_transfer = min_transfer or 2 * chunk
-
-    if ladder_drivers is not None:
-        driver = ladder_drivers[chunk]   # the tuned top rung — also
-        #   the seed/resume/commit driver (all rungs share its limit)
-    else:
-        from .ladder import fused_for
-        driver = _problem_driver(prob, mesh, tables, table, lb_kind,
-                                 chunk, balance_period, transfer_cap,
-                                 min_transfer, adt, loop_cache,
-                                 fused=fused_for(chunk, rung_profile,
-                                                 fused_mode))
-
-    session = None
-    meta_rung = None          # the checkpoint's recorded ladder rung
-    h_prmu = np.zeros((0, jobs), np.int16)
-    h_depth = np.zeros(0, np.int16)
-    if resumed is not None:
-        host_state, meta = resumed
-        if "ladder_rung" in meta:
-            # resume replays on the rung the checkpoint recorded: the
-            # pool snapshot alone would misread a mid-ramp save
-            meta_rung = int(np.asarray(meta["ladder_rung"]))
-        shape = np.asarray(host_state.prmu).shape
-        if len(shape) != 3 or shape[0] != n_dev:
-            # elastic resume: re-split the snapshot's pools across THIS
-            # mesh (preemption rarely hands back the same topology)
-            old_workers = shape[0] if len(shape) == 3 else 1
-            import warnings
-            warnings.warn(
-                f"resharding checkpoint {checkpoint_path} from "
-                f"{old_workers} to {n_dev} workers (elastic resume)",
-                RuntimeWarning, stacklevel=2)
-            # audit hook: the elastic reshard must conserve every
-            # summed counter, the pooled node count and the incumbent
-            # (obs/audit — a drift here is silent wrong answers later)
-            pre_sums = (obs_audit.state_sums(host_state)
-                        if obs_audit.enabled() else None)
-            host_state = checkpoint.reshard_state(host_state, n_dev)
-            if pre_sums is not None:
-                obs_audit.check_reshard(pre_sums, host_state,
-                                        edge="elastic_resume")
-        # re-home into a capacity whose usable-row limit (scratch margin
-        # + balance headroom) covers the fullest resharded pool
-        cap0 = cap = host_state.prmu.shape[-1]
-        need = int(np.asarray(host_state.size).max())
-        while driver.limit(cap) < max(need, 1):
-            cap *= 2
-        if cap != cap0:
-            host_state = checkpoint.grow(host_state, cap)
-        # a checkpoint written by a -C run carries the host tier's seed
-        # nodes (they were carved OUT of the pools): resume must either
-        # re-seed the session from them or push them back — dropping
-        # them would silently lose subtrees
-        saved_p = np.asarray(meta.get("host_prmu",
-                                      np.zeros((0, jobs))), np.int16)
-        saved_d = np.asarray(meta.get("host_depth", np.zeros(0)),
-                             np.int16)
-        if host_fraction > 0:
-            if len(saved_d):
-                h_prmu, h_depth = saved_p, saved_d
+    # the host's part before the first dispatch, one span: tuner
+    # resolve, tables, driver and loop-cache lookup, state build and
+    # shard (the segments that follow are spanned in run_segmented)
+    with tracelog.span("request.prepare", lb_kind=lb_kind):
+        prob = _resolve_problem(problem)
+        table = np.asarray(p_times)
+        if mesh is None:
+            mesh = worker_mesh(n_devices)
+        n_dev = mesh.devices.size
+        jobs = prob.slots(table)
+        if host_fraction > 0 and not prob.supports_host_tier:
+            from ..problems.base import HostTierUnsupported
+            raise HostTierUnsupported(prob.name)
+        rung_profile = None
+        fused_mode = pallas_fused.resolve_mode(None)
+        if chunk is None or balance_period is None:
+            # adaptive-dispatch resolution for the knobs the caller left
+            # open: tuned cache entry (zero probes — the hot path must
+            # never probe) else the measured-defaults table
+            from ..tune import defaults as tune_defaults
+            if tuner is not None:
+                params = tuner.resolve(jobs, table.shape[0], lb_kind,
+                                       n_workers=n_dev, allow_probe=False,
+                                       problem=prob.name)
             else:
-                host_state, h_prmu, h_depth = hybrid.pop_host_share(
-                    host_state, host_fraction)
+                params = tune_defaults.params_for("serving", jobs,
+                                                  table.shape[0],
+                                                  problem=prob.name)
+            if chunk is None:
+                chunk = params.chunk
+                if transfer_cap is None and params.transfer_cap:
+                    transfer_cap = params.transfer_cap
+            if balance_period is None:
+                balance_period = params.balance_period
+            # the tuner's per-rung kernel-vs-matmul profitability mask
+            # (Params.rung_modes) rides into rung construction below
+            rung_profile = params.rung_modes
+            tracelog.event("tuner.resolve", chunk=chunk,
+                           balance_period=balance_period,
+                           source=params.source,
+                           evals_per_s=params.evals_per_s,
+                           fused=fused_mode,
+                           rung_profile=bool(rung_profile))
+        if tables is None:
+            tables = prob.make_tables(table)
+        adt = prob.aux_dtype(table)
+        resumed = None
+        if checkpoint_path and checkpoint.resume_path(checkpoint_path):
+            # load BEFORE sizing the balance buffers: resume keeps the
+            # SAVED pools' aux dtype (an old int32-aux checkpoint stays
+            # int32, and a pre-aux legacy file is RECONSTRUCTED as int32 by
+            # checkpoint.load), so the byte budget must be priced off the
+            # loaded state, not the fresh-run dtype
+            resumed = checkpoint.load_resilient(
+                checkpoint_path,
+                p_times=table if prob.name == "pfsp" else None)[:2]
+            # a snapshot records its problem (pre-stamp legacy snapshots
+            # are all PFSP); a cross-problem resume is refused — the pool
+            # rows only mean anything under the problem that wrote them
+            saved_prob = resumed[1].get("problem")
+            saved_prob = ("pfsp" if saved_prob is None
+                          else str(np.asarray(saved_prob)))
+            if saved_prob != prob.name:
+                raise ValueError(
+                    f"checkpoint {checkpoint_path} was written by problem "
+                    f"{saved_prob!r}; refusing to resume it as "
+                    f"{prob.name!r} (pick a fresh tag/checkpoint path)")
+            adt = np.asarray(resumed[0].aux).dtype
+        if ladder is None:
+            ladder = _cfg.env_flag(_cfg.LADDER_FLAG)
+        # the ladder switches at segment boundaries, so it engages only
+        # when segmented execution will run; a host tier keeps the single
+        # driver (its per-segment merge is enough moving parts) and
+        # multi-controller stays on the one-loop path, like overlap
+        use_ladder = (bool(ladder)
+                      and (segment_iters is not None
+                           or checkpoint_path is not None
+                           or stop_event is not None
+                           or should_stop is not None)
+                      and host_fraction == 0
+                      and jax.process_count() == 1)
+        ladder_drivers = None
+        if use_ladder:
+            # rung drivers get the caller's EXPLICIT transfer knobs (None
+            # derives per rung) and one unified limit — see _ladder_plan
+            rungs, ladder_drivers = _ladder_plan(
+                prob, mesh, tables, table, lb_kind, chunk, balance_period,
+                transfer_cap, min_transfer, adt, loop_cache,
+                rung_profile=rung_profile, fused_mode=fused_mode)
+            if len(rungs) < 2:
+                ladder_drivers = None      # chunk too small to ladder:
+                #                            plain single-driver path
+        if transfer_cap is None:
+            transfer_cap = default_transfer_cap(chunk, jobs,
+                                                prob.aux_rows(table),
+                                                mesh.devices.size,
+                                                aux_itemsize=adt.itemsize)
+        min_transfer = min_transfer or 2 * chunk
+
+        if ladder_drivers is not None:
+            driver = ladder_drivers[chunk]   # the tuned top rung — also
+            #   the seed/resume/commit driver (all rungs share its limit)
+        else:
+            from .ladder import fused_for
+            driver = _problem_driver(prob, mesh, tables, table, lb_kind,
+                                     chunk, balance_period, transfer_cap,
+                                     min_transfer, adt, loop_cache,
+                                     fused=fused_for(chunk, rung_profile,
+                                                     fused_mode))
+
+        session = None
+        meta_rung = None          # the checkpoint's recorded ladder rung
+        h_prmu = np.zeros((0, jobs), np.int16)
+        h_depth = np.zeros(0, np.int16)
+        if resumed is not None:
+            host_state, meta = resumed
+            if "ladder_rung" in meta:
+                # resume replays on the rung the checkpoint recorded: the
+                # pool snapshot alone would misread a mid-ramp save
+                meta_rung = int(np.asarray(meta["ladder_rung"]))
+            shape = np.asarray(host_state.prmu).shape
+            if len(shape) != 3 or shape[0] != n_dev:
+                # elastic resume: re-split the snapshot's pools across THIS
+                # mesh (preemption rarely hands back the same topology)
+                old_workers = shape[0] if len(shape) == 3 else 1
+                import warnings
+                warnings.warn(
+                    f"resharding checkpoint {checkpoint_path} from "
+                    f"{old_workers} to {n_dev} workers (elastic resume)",
+                    RuntimeWarning, stacklevel=2)
+                # audit hook: the elastic reshard must conserve every
+                # summed counter, the pooled node count and the incumbent
+                # (obs/audit — a drift here is silent wrong answers later)
+                pre_sums = (obs_audit.state_sums(host_state)
+                            if obs_audit.enabled() else None)
+                host_state = checkpoint.reshard_state(host_state, n_dev)
+                if pre_sums is not None:
+                    obs_audit.check_reshard(pre_sums, host_state,
+                                            edge="elastic_resume")
+            # re-home into a capacity whose usable-row limit (scratch margin
+            # + balance headroom) covers the fullest resharded pool
+            cap0 = cap = host_state.prmu.shape[-1]
+            need = int(np.asarray(host_state.size).max())
+            while driver.limit(cap) < max(need, 1):
+                cap *= 2
+            if cap != cap0:
+                host_state = checkpoint.grow(host_state, cap)
+            # a checkpoint written by a -C run carries the host tier's seed
+            # nodes (they were carved OUT of the pools): resume must either
+            # re-seed the session from them or push them back — dropping
+            # them would silently lose subtrees
+            saved_p = np.asarray(meta.get("host_prmu",
+                                          np.zeros((0, jobs))), np.int16)
+            saved_d = np.asarray(meta.get("host_depth", np.zeros(0)),
+                                 np.int16)
+            if host_fraction > 0:
+                if len(saved_d):
+                    h_prmu, h_depth = saved_p, saved_d
+                else:
+                    host_state, h_prmu, h_depth = hybrid.pop_host_share(
+                        host_state, host_fraction)
+                if len(h_depth):
+                    session = hybrid.make_session(
+                        prob, table, h_prmu, h_depth, lb_kind,
+                        int(np.asarray(host_state.best).min()),
+                        n_threads=host_threads)
+            elif len(saved_d):
+                host_state = hybrid.restore_host_share(
+                    host_state, saved_p, saved_d, table, problem=prob)
+            fr = Frontier(prmu=np.zeros((0, jobs), np.int16),
+                          depth=np.zeros(0, np.int16),
+                          tree=int(meta.get("warmup_tree", 0)),
+                          sol=int(meta.get("warmup_sol", 0)),
+                          best=int(np.asarray(host_state.best).min()))
+            state = driver.commit(host_state)
+        else:
+            with tracelog.span("bfs_warmup", problem=prob.name,
+                               target=min_seed * n_dev) as ws:
+                fr = prob.warmup(table, lb_kind, init_ub,
+                                 target=min_seed * n_dev)
+                ws.set(frontier=len(fr.depth), tree=fr.tree)
+            init_best = (fr.best if init_ub is None
+                         else min(fr.best, int(init_ub)))
+            dmask, h_prmu, h_depth = hybrid.split_host_share(
+                fr.prmu, fr.depth, host_fraction)
             if len(h_depth):
-                session = hybrid.make_session(
-                    prob, table, h_prmu, h_depth, lb_kind,
-                    int(np.asarray(host_state.best).min()),
-                    n_threads=host_threads)
-        elif len(saved_d):
-            host_state = hybrid.restore_host_share(
-                host_state, saved_p, saved_d, table, problem=prob)
-        fr = Frontier(prmu=np.zeros((0, jobs), np.int16),
-                      depth=np.zeros(0, np.int16),
-                      tree=int(meta.get("warmup_tree", 0)),
-                      sol=int(meta.get("warmup_sol", 0)),
-                      best=int(np.asarray(host_state.best).min()))
-        state = driver.commit(host_state)
-    else:
-        with tracelog.span("bfs_warmup", problem=prob.name,
-                           target=min_seed * n_dev) as ws:
-            fr = prob.warmup(table, lb_kind, init_ub,
-                             target=min_seed * n_dev)
-            ws.set(frontier=len(fr.depth), tree=fr.tree)
-        init_best = (fr.best if init_ub is None
-                     else min(fr.best, int(init_ub)))
-        dmask, h_prmu, h_depth = hybrid.split_host_share(
-            fr.prmu, fr.depth, host_fraction)
-        if len(h_depth):
-            session = hybrid.make_session(prob, table, h_prmu, h_depth,
-                                          lb_kind, init_best,
-                                          n_threads=host_threads)
-            fr.prmu, fr.depth = fr.prmu[dmask], fr.depth[dmask]
-        fr.aux = prob.seed_aux(table, fr.prmu, fr.depth)
-        state = driver.seed(fr, capacity, jobs, init_best)
+                session = hybrid.make_session(prob, table, h_prmu, h_depth,
+                                              lb_kind, init_best,
+                                              n_threads=host_threads)
+                fr.prmu, fr.depth = fr.prmu[dmask], fr.depth[dmask]
+            fr.aux = prob.seed_aux(table, fr.prmu, fr.depth)
+            state = driver.seed(fr, capacity, jobs, init_best)
 
-    if overlap is None:
-        overlap = _cfg.env_flag(_cfg.OVERLAP_FLAG)
-    # the host tier's per-segment incumbent merge (post_segment) needs
-    # the synchronous boundary; overlap yields to it. Multi-controller
-    # must also stay sync HERE, not only in run_segmented's own guard:
-    # the choice of run_fn below follows use_overlap, and handing the
-    # sync driver the donating non-growing run_async would turn every
-    # overflow into a hard PoolOverflow instead of a lossless grow.
-    use_overlap = (bool(overlap) and session is None
-                   and jax.process_count() == 1)
+        if overlap is None:
+            overlap = _cfg.env_flag(_cfg.OVERLAP_FLAG)
+        # the host tier's per-segment incumbent merge (post_segment) needs
+        # the synchronous boundary; overlap yields to it. Multi-controller
+        # must also stay sync HERE, not only in run_segmented's own guard:
+        # the choice of run_fn below follows use_overlap, and handing the
+        # sync driver the donating non-growing run_async would turn every
+        # overflow into a hard PoolOverflow instead of a lossless grow.
+        use_overlap = (bool(overlap) and session is None
+                       and jax.process_count() == 1)
 
-    ladder_ctl = None
-    if ladder_drivers is not None:
-        from .ladder import RungController
-        ladder_ctl = RungController(ladder_drivers, n_dev)
-        ladder_ctl.start(int(np.atleast_1d(_fetch(state.size)).sum()),
-                         meta_rung=meta_rung)
-        # Pre-ready EVERY rung — the current one included — from
-        # abstract shapes, so a mid-search switch never stalls on a
-        # fresh trace+compile and all rung compiles are booked as
-        # PLANNED (via="ladder": the compile_storm rule must not read
-        # a ladder boot as executable-reuse breaking). Warming all
-        # rungs is also a CORRECTNESS requirement on the AOT path, not
-        # just a latency one: abstract warms pin every input/output to
-        # the explicit worker-axis sharding (_DistDriver.
-        # abstract_state), so any rung's output state feeds any other
-        # rung's strict AOT executable; an entry compiled from REAL
-        # first-call args instead infers a replicated sharding for the
-        # zero-width telemetry leaf and then REJECTS the cross-rung
-        # handoff ("input sharding does not match") — a booked jit
-        # fallback, correct but a silent perf and accounting loss.
-        cap_now = int(state.prmu.shape[-1])
-        for c, d in ladder_drivers.items():
-            d.warm(cap_now, jobs, prob.aux_rows(table), adt,
-                   donate=use_overlap, via="ladder")
+        ladder_ctl = None
+        if ladder_drivers is not None:
+            from .ladder import RungController
+            ladder_ctl = RungController(ladder_drivers, n_dev)
+            ladder_ctl.start(int(np.atleast_1d(_fetch(state.size)).sum()),
+                             meta_rung=meta_rung)
+            # Pre-ready EVERY rung — the current one included — from
+            # abstract shapes, so a mid-search switch never stalls on a
+            # fresh trace+compile and all rung compiles are booked as
+            # PLANNED (via="ladder": the compile_storm rule must not read
+            # a ladder boot as executable-reuse breaking). Warming all
+            # rungs is also a CORRECTNESS requirement on the AOT path, not
+            # just a latency one: abstract warms pin every input/output to
+            # the explicit worker-axis sharding (_DistDriver.
+            # abstract_state), so any rung's output state feeds any other
+            # rung's strict AOT executable; an entry compiled from REAL
+            # first-call args instead infers a replicated sharding for the
+            # zero-width telemetry leaf and then REJECTS the cross-rung
+            # handoff ("input sharding does not match") — a booked jit
+            # fallback, correct but a silent perf and accounting loss.
+            cap_now = int(state.prmu.shape[-1])
+            for c, d in ladder_drivers.items():
+                d.warm(cap_now, jobs, prob.aux_rows(table), adt,
+                       donate=use_overlap, via="ladder")
 
-    client = None
-    if incumbent_board is not None:
-        client = inc_mod.BoardClient(
-            incumbent_board,
-            incumbent_key or inc_mod.share_key(table,
-                                               problem=prob.name))
-        # seed the exchange with this search's starting incumbent (a
-        # resumed checkpoint's best, or the warm-up/init_ub bound) so
-        # same-instance peers tighten before our first segment lands
-        client.publish(int(np.atleast_1d(_fetch(state.best)).min()))
+        client = None
+        if incumbent_board is not None:
+            client = inc_mod.BoardClient(
+                incumbent_board,
+                incumbent_key or inc_mod.share_key(table,
+                                                   problem=prob.name))
+            # seed the exchange with this search's starting incumbent (a
+            # resumed checkpoint's best, or the warm-up/init_ub bound) so
+            # same-instance peers tighten before our first segment lands
+            client.publish(int(np.atleast_1d(_fetch(state.best)).min()))
 
-    max_iters = (None if max_rounds is None
-                 else max_rounds * balance_period)
-    stop_fn = None
-    if stop_event is not None or should_stop is not None:
-        def stop_fn(rep):
-            return ((stop_event is not None and stop_event.is_set())
-                    or (should_stop is not None and should_stop(rep)))
-    if (segment_iters is None and checkpoint_path is None
-            and session is None and stop_fn is None):
+        max_iters = (None if max_rounds is None
+                     else max_rounds * balance_period)
+        stop_fn = None
+        if stop_event is not None or should_stop is not None:
+            def stop_fn(rep):
+                return ((stop_event is not None and stop_event.is_set())
+                        or (should_stop is not None and should_stop(rep)))
+        one_shot = (segment_iters is None and checkpoint_path is None
+                    and session is None and stop_fn is None)
+        if not one_shot:
+            ckpt_meta = {"warmup_tree": fr.tree, "warmup_sol": fr.sol,
+                         # the snapshot's problem stamp: resume refuses a
+                         # cross-problem re-home (checked above)
+                         "problem": prob.name,
+                         # the host tier's seed rides every checkpoint so a
+                         # killed -C run can be resumed without losing the
+                         # carved subtrees (re-exploring the share from its
+                         # seed is exactly-once: the killed session's work
+                         # was never committed anywhere)
+                         "host_prmu": (h_prmu if session else
+                                       np.zeros((0, jobs), np.int16)),
+                         "host_depth": (h_depth if session else
+                                        np.zeros(0, np.int16))}
+            if checkpoint_meta_extra is not None:
+                base_meta = ckpt_meta
+
+                def ckpt_meta():
+                    extra = (checkpoint_meta_extra()
+                             if callable(checkpoint_meta_extra)
+                             else checkpoint_meta_extra)
+                    return {**base_meta, **extra}
+
+            if ladder_ctl is not None:
+                # the rung for the NEXT segment was chosen at the last
+                # boundary (hb's observe below); every rung driver shares
+                # the unified limit, so switching never invalidates the
+                # carried state
+                base_meta0 = ckpt_meta
+
+                def ckpt_meta():
+                    base = (base_meta0() if callable(base_meta0)
+                            else dict(base_meta0))
+                    return {**base, "ladder_rung": ladder_ctl.current_chunk}
+
+            grow_fn = stop_pending = None
+            if use_overlap:
+                # async dispatch with donated pool carries; overflow
+                # recovery and exit draining live in the overlapped driver
+                def run_fn(s, target):
+                    drv = (ladder_ctl.driver() if ladder_ctl is not None
+                           else driver)
+                    return drv.run_async(
+                        s, target, bound_cap=client.cap() if client else None)
+
+                def grow_fn(s):
+                    return driver.commit(checkpoint.grow(
+                        fetch_state(s), s.prmu.shape[-1] * 2))
+
+                if stop_event is not None:
+                    stop_pending = stop_event.is_set
+            else:
+                def run_fn(s, target):
+                    drv = (ladder_ctl.driver() if ladder_ctl is not None
+                           else driver)
+                    return drv.run(
+                        s, max_iters=target,
+                        bound_cap=client.cap() if client else None)
+
+            def hb(rep):
+                if ladder_ctl is not None:
+                    # rung selection for the NEXT dispatch: this boundary's
+                    # pool-occupancy signal (under overlap the next segment
+                    # is already in flight, so the switch lands one
+                    # boundary later — accounting is exact either way)
+                    ladder_ctl.observe(rep.pool_size, segment=rep.segment)
+                # resource-observability heartbeat hook: one device-memory
+                # / host-RSS sweep per segment (obs/resource publishes the
+                # tts_device_bytes_* gauges and a resource.sample trace
+                # event, which Perfetto renders as memory lanes beside the
+                # pool/steal counter lanes). Observation-only — a failed
+                # sweep must never stop the search.
+                try:
+                    from ..obs import resource as obs_resource
+                    obs_resource.sample_now()
+                except Exception:  # noqa: BLE001
+                    pass
+                if client is not None:
+                    # the cross-request exchange's publish half: fold this
+                    # submesh's freshest best into the board every segment
+                    client.publish(rep.best)
+                if heartbeat is not None:
+                    heartbeat(rep)
+
+    if one_shot:
         # the segmented path below is spanned per segment inside
         # run_segmented; this is the only otherwise-unobserved run shape
         with tracelog.span("engine.run", workers=n_dev):
             out = driver.run(state, max_iters,
                              bound_cap=client.cap() if client else None)
     else:
-        ckpt_meta = {"warmup_tree": fr.tree, "warmup_sol": fr.sol,
-                     # the snapshot's problem stamp: resume refuses a
-                     # cross-problem re-home (checked above)
-                     "problem": prob.name,
-                     # the host tier's seed rides every checkpoint so a
-                     # killed -C run can be resumed without losing the
-                     # carved subtrees (re-exploring the share from its
-                     # seed is exactly-once: the killed session's work
-                     # was never committed anywhere)
-                     "host_prmu": (h_prmu if session else
-                                   np.zeros((0, jobs), np.int16)),
-                     "host_depth": (h_depth if session else
-                                    np.zeros(0, np.int16))}
-        if checkpoint_meta_extra is not None:
-            base_meta = ckpt_meta
-
-            def ckpt_meta():
-                extra = (checkpoint_meta_extra()
-                         if callable(checkpoint_meta_extra)
-                         else checkpoint_meta_extra)
-                return {**base_meta, **extra}
-
-        if ladder_ctl is not None:
-            # the rung for the NEXT segment was chosen at the last
-            # boundary (hb's observe below); every rung driver shares
-            # the unified limit, so switching never invalidates the
-            # carried state
-            base_meta0 = ckpt_meta
-
-            def ckpt_meta():
-                base = (base_meta0() if callable(base_meta0)
-                        else dict(base_meta0))
-                return {**base, "ladder_rung": ladder_ctl.current_chunk}
-
-        grow_fn = stop_pending = None
-        if use_overlap:
-            # async dispatch with donated pool carries; overflow
-            # recovery and exit draining live in the overlapped driver
-            def run_fn(s, target):
-                drv = (ladder_ctl.driver() if ladder_ctl is not None
-                       else driver)
-                return drv.run_async(
-                    s, target, bound_cap=client.cap() if client else None)
-
-            def grow_fn(s):
-                return driver.commit(checkpoint.grow(
-                    fetch_state(s), s.prmu.shape[-1] * 2))
-
-            if stop_event is not None:
-                stop_pending = stop_event.is_set
-        else:
-            def run_fn(s, target):
-                drv = (ladder_ctl.driver() if ladder_ctl is not None
-                       else driver)
-                return drv.run(
-                    s, max_iters=target,
-                    bound_cap=client.cap() if client else None)
-
-        def hb(rep):
-            if ladder_ctl is not None:
-                # rung selection for the NEXT dispatch: this boundary's
-                # pool-occupancy signal (under overlap the next segment
-                # is already in flight, so the switch lands one
-                # boundary later — accounting is exact either way)
-                ladder_ctl.observe(rep.pool_size, segment=rep.segment)
-            # resource-observability heartbeat hook: one device-memory
-            # / host-RSS sweep per segment (obs/resource publishes the
-            # tts_device_bytes_* gauges and a resource.sample trace
-            # event, which Perfetto renders as memory lanes beside the
-            # pool/steal counter lanes). Observation-only — a failed
-            # sweep must never stop the search.
-            try:
-                from ..obs import resource as obs_resource
-                obs_resource.sample_now()
-            except Exception:  # noqa: BLE001
-                pass
-            if client is not None:
-                # the cross-request exchange's publish half: fold this
-                # submesh's freshest best into the board every segment
-                client.publish(rep.best)
-            if heartbeat is not None:
-                heartbeat(rep)
-
         out = checkpoint.run_segmented(
             run_fn, state, segment_iters=segment_iters or 2048,
             checkpoint_path=checkpoint_path, heartbeat=hb,

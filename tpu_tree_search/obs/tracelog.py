@@ -21,6 +21,9 @@ Record schema (one JSON object per line in the sink)::
      "dur":  0.25,               # spans only: seconds
      "seq":  417,                # process-wide ordering tiebreak
      "pid":  31337, "thread": "tts-service-exec-0",
+     "span_id":   88,            # spans only: this span's id
+     "parent_id": 87,            # the span open on this thread when
+                                 # the record began (null: none)
      ...flat attributes: request_id, submesh, segment, ...}
 
 The sink file starts with one ``{"kind": "meta", ...}`` line mapping the
@@ -33,6 +36,24 @@ each request's executor thread in ``context(request_id=..., submesh=...)``
 so the engine-level spans it drives (segments, checkpoint saves, retry
 events) are attributable to the request WITHOUT threading ids through
 every engine API.
+
+Profiler mirror: while a ``span`` is open it also holds a
+``jax.profiler.TraceAnnotation`` of the same name, carrying the
+identifying attributes only (:data:`MIRRORED`). With no capture running
+that costs well under a microsecond; inside a capture (``obs.profiler``:
+the ``profile`` CLI, ``POST /profile``, a benchmark's trace) every span
+lands in the xplane's host plane on the device ops' clock, so an idle
+gap on the chip can be matched to the span the host was in. jax is
+never imported here: a process that has not loaded it has nothing to
+profile. ``span_at`` emits after the fact and stays ring-only.
+
+Compile attribution: the first span opened once jax is loaded registers
+one ``jax.monitoring`` duration listener, which adds JAX's tracing,
+lowering, backend-compile and persistent-cache-read durations
+(:data:`COMPILE_EVENTS`) to the innermost span open on the calling
+thread of the process-global recorder, as ``trace_s``, ``lower_s``,
+``compile_s`` (which includes any cache read) and ``cache_read_s``;
+nested traces count once.
 
 Module-level :func:`span` / :func:`event` write to the process-global
 recorder (lazily built; ``TTS_TRACE_FILE`` configures its sink,
@@ -47,11 +68,24 @@ import contextlib
 import itertools
 import json
 import os
+import sys
 import threading
 import time
 
 __all__ = ["TraceLog", "get", "install", "span", "event", "context",
-           "span_at", "current_context"]
+           "span_at", "current_context", "MIRRORED", "COMPILE_EVENTS"]
+
+# the attributes a span hands its profiler annotation: what identifies
+# the work (ambient ones included), never the bulky rest
+MIRRORED = ("request_id", "segment", "inst", "jobs", "lb_kind")
+
+# jax.monitoring duration events -> the span attribute they add to
+COMPILE_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace_s",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_s",
+    "/jax/core/compile/backend_compile_duration": "compile_s",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_read_s",
+}
 
 
 def _json_safe(v):
@@ -74,13 +108,18 @@ class _Span:
     duration after exit (``.dur``) and accepts late attributes via
     :meth:`set` (e.g. a result computed inside the span)."""
 
-    __slots__ = ("name", "attrs", "t_start", "dur")
+    __slots__ = ("name", "attrs", "t_start", "dur", "span_id",
+                 "parent_id", "compiles")
 
-    def __init__(self, name: str, attrs: dict):
+    def __init__(self, name: str, attrs: dict, span_id: int,
+                 parent_id: int | None):
         self.name = name
         self.attrs = attrs
         self.t_start = 0.0
         self.dur = 0.0
+        self.span_id = span_id
+        self.parent_id = parent_id
+        self.compiles: dict = {}    # attribute -> [(start, end)]
 
     def set(self, **attrs) -> None:
         self.attrs.update(attrs)
@@ -99,6 +138,7 @@ class TraceLog:
         self._buf: collections.deque[dict] = collections.deque(
             maxlen=max(int(capacity), 1))   # guarded-by: self._lock
         self._seq = itertools.count()
+        self._ids = itertools.count(1)
         self._tls = threading.local()
         self._sink = None        # guarded-by: self._lock
         self._sink_bytes = 0     # guarded-by: self._lock
@@ -204,6 +244,17 @@ class TraceLog:
             out.update(layer)
         return out
 
+    def _open_spans(self) -> list:
+        """This thread's open spans, innermost last."""
+        stack = getattr(self._tls, "spans", None)
+        if stack is None:
+            stack = self._tls.spans = []
+        return stack
+
+    def _parent_id(self) -> int | None:
+        stack = getattr(self._tls, "spans", None)
+        return stack[-1].span_id if stack else None
+
     def current_context(self) -> dict:
         """This thread's merged ambient attributes — the hand-off for
         work delegated to ANOTHER thread (the async checkpoint writer
@@ -220,12 +271,15 @@ class TraceLog:
             self._buf.append(rec)
             if self._sink is not None:
                 try:
-                    line = json.dumps(rec) + "\n"
-                    self._sink.write(line)
-                    self._sink_bytes += len(line)
+                    # roll before the write, never after: the newest
+                    # record always lands in the live file
                     if self.max_sink_bytes \
                             and self._sink_bytes >= self.max_sink_bytes:
                         self._rotate_locked()
+                    if self._sink is not None:
+                        line = json.dumps(rec) + "\n"
+                        self._sink.write(line)
+                        self._sink_bytes += len(line)
                 except (OSError, ValueError):
                     # a torn sink (disk full, closed fd) must never take
                     # the search down; the ring buffer keeps recording
@@ -242,6 +296,7 @@ class TraceLog:
                "ts": round(time.monotonic() - self.t0, 6),
                "pid": os.getpid(),
                "thread": threading.current_thread().name,
+               "parent_id": self._parent_id(),
                **self._ambient(),
                **{k: _json_safe(v) for k, v in attrs.items()}}
         self._emit(rec)
@@ -252,9 +307,21 @@ class TraceLog:
         """Bracket a duration. One record is emitted at exit (so the
         ring holds only completed work); its ``ts`` is the span START.
         An exception inside the span is recorded as ``error=<repr>`` and
-        re-raised — a failed operation leaves a trace, not a hole."""
-        sp = _Span(name, {k: _json_safe(v) for k, v in attrs.items()})
+        re-raised — a failed operation leaves a trace, not a hole.
+        While open, the span is the parent of every record this thread
+        emits and holds the profiler annotation of the same name."""
+        stack = self._open_spans()
+        sp = _Span(name, {k: _json_safe(v) for k, v in attrs.items()},
+                   next(self._ids),
+                   stack[-1].span_id if stack else None)
         ambient = self._ambient()
+        stack.append(sp)
+        mirror = _annotation()
+        if mirror is not None:
+            ids = {**ambient, **sp.attrs}
+            mirror = mirror(name, **{k: ids[k] for k in MIRRORED
+                                     if ids.get(k) is not None})
+            mirror.__enter__()
         t_start = time.monotonic()
         sp.t_start = t_start - self.t0
         try:
@@ -264,11 +331,16 @@ class TraceLog:
             raise
         finally:
             sp.dur = time.monotonic() - t_start
+            if mirror is not None:
+                mirror.__exit__(None, None, None)
+            stack.remove(sp)
             self._emit({"kind": "span", "name": name,
                         "ts": round(sp.t_start, 6),
                         "dur": round(sp.dur, 6),
                         "pid": os.getpid(),
                         "thread": threading.current_thread().name,
+                        "span_id": sp.span_id,
+                        "parent_id": sp.parent_id,
                         **ambient, **sp.attrs})
 
     def span_at(self, name: str, t_start: float, t_end: float,
@@ -279,12 +351,16 @@ class TraceLog:
         ready] — an interval that straddles other host work and the
         NEXT segment's dispatch, so no ``with`` block can bracket it.
         Consecutive spans emitted this way may overlap in wall time;
-        gap analyses (tools/search_report.py) clamp negatives to 0."""
+        gap analyses (tools/search_report.py) clamp negatives to 0.
+        Its parent is the span open on this thread at emission; it has
+        no profiler annotation (the interval is over when it is known)."""
         self._emit({"kind": "span", "name": name,
                     "ts": round(t_start - self.t0, 6),
                     "dur": round(max(t_end - t_start, 0.0), 6),
                     "pid": os.getpid(),
                     "thread": threading.current_thread().name,
+                    "span_id": next(self._ids),
+                    "parent_id": self._parent_id(),
                     **self._ambient(),
                     **{k: _json_safe(v) for k, v in attrs.items()}})
 
@@ -303,6 +379,53 @@ class TraceLog:
     def __len__(self) -> int:
         with self._lock:
             return len(self._buf)
+
+
+# ------------------------------------------------- profiler and compiles
+
+_TraceAnnotation = None    # jax.profiler.TraceAnnotation once resolved
+_jax_lock = threading.Lock()
+
+
+def _annotation():
+    """`jax.profiler.TraceAnnotation` once this process has imported jax
+    (None before: nothing can be capturing yet). Resolving it registers
+    the compile listener, exactly once per process."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None and "jax" in sys.modules:
+        with _jax_lock:
+            if _TraceAnnotation is None:
+                import jax
+                jax.monitoring.register_event_duration_secs_listener(
+                    _on_compile_event)
+                _TraceAnnotation = jax.profiler.TraceAnnotation
+    return _TraceAnnotation
+
+
+def _on_compile_event(event: str, duration: float, **_) -> None:
+    """jax.monitoring listener: fold a compile-phase duration into the
+    innermost span open on the calling thread (JAX traces, lowers and
+    compiles on the thread that made the call). The event arrives as
+    its phase ends, so it covers [now - duration, now]. Tracing nests:
+    each jit met while tracing an outer one reports its own trace, and
+    the outer report covers it, so an interval drops those it holds and
+    the attribute is the length of what is left."""
+    key = COMPILE_EVENTS.get(event)
+    log = _global
+    if key is None or log is None:
+        return
+    stack = getattr(log._tls, "spans", None)
+    if not stack:
+        return
+    sp = stack[-1]
+    end = time.monotonic()
+    start = end - float(duration)
+    slack = 1e-4        # the report reaches us microseconds late
+    kept = [(s, e) for s, e in sp.compiles.get(key, ())
+            if s < start - slack or e > end + slack]
+    kept.append((start, end))
+    sp.compiles[key] = kept
+    sp.attrs[key] = round(sum(e - s for s, e in kept), 6)
 
 
 # ----------------------------------------------------------- global log

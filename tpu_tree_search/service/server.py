@@ -42,8 +42,9 @@ the obs layer landed, the snapshot's counters are a VIEW over the
 server's metrics registry (`self.metrics`, an obs/metrics.Registry —
 the same numbers `/metrics` exposes as Prometheus text), every
 lifecycle transition is flight-recorded (obs/tracelog: admit /
-dispatch / resume / preempt / terminal events, one `request.execute`
-span per dispatch), and each executor thread runs inside an ambient
+dispatch / resume / preempt / terminal events; per dispatch a
+`request.queued`, a `request.execute` and a `request.finish` span),
+and each executor thread runs inside an ambient
 `tracelog.context(request_id=..., submesh=...)` so the engine-level
 spans it drives (segments, checkpoint saves, retries, faults) are
 attributable to the request without threading ids through engine APIs.
@@ -2524,6 +2525,10 @@ class SearchServer:
                            submesh=slot.index, dispatch=rec.dispatches,
                            batch=bid, batch_size=len(recs),
                            queue_depth=len(self.queue))
+            if rec.queued_t:
+                tracelog.span_at("request.queued", rec.queued_t,
+                                 rec.started_t, request_id=rec.id,
+                                 dispatch=rec.dispatches, batch=bid)
             if rec.dispatches > 1:
                 tracelog.event("request.resume", request_id=rec.id,
                                submesh=slot.index,
@@ -2835,6 +2840,10 @@ class SearchServer:
         tracelog.event("request.dispatch", request_id=rec.id,
                        submesh=slot.index, dispatch=rec.dispatches,
                        queue_depth=len(self.queue))
+        if rec.queued_t:
+            tracelog.span_at("request.queued", rec.queued_t,
+                             rec.started_t, request_id=rec.id,
+                             dispatch=rec.dispatches)
         if rec.dispatches > 1:
             # re-dispatch of preempted/failed work — the flight
             # recorder's "resume" marker the span-sequence tests assert
@@ -3002,7 +3011,8 @@ class SearchServer:
             except Exception as e:  # noqa: BLE001 — FAILED terminal below
                 error = f"{type(e).__name__}: {e}"
                 rec.failures = self.service_retry_attempts + 1  # no retry
-            self._on_finished(slot, rec, res, error)
+            with tracelog.span("request.finish", dispatch=rec.dispatches):
+                self._on_finished(slot, rec, res, error)
 
     def _unit_costs(self, req) -> dict | None:
         """Resolve the phase-attribution unit costs for `req` (see the
