@@ -6,6 +6,9 @@ goes.
   `search.*` spans in the xplane's host plane (the device ops' clock);
 - every span record carries its own id and its parent's, and the
   ambient request id;
+- `search.prepare` holds `search.tables` (with a `tables.calibrate`
+  only where the tables compute the strong-pair order) and
+  `search.init_state`;
 - the compile listener attributes a first call's trace, lowering and
   compile to the innermost open span;
 - the compiled search loop names its phases (`pop`, `bound`, `prune`,
@@ -16,6 +19,9 @@ goes.
 """
 
 import glob
+import importlib.util
+import pathlib
+import types
 
 import jax
 import jax.numpy as jnp
@@ -30,6 +36,7 @@ from tpu_tree_search.problems.pfsp import PFSPInstance
 from tpu_tree_search.service import SearchRequest, SearchServer
 
 PHASES = ("pop", "bound", "prune", "compact", "push")
+ROOT = pathlib.Path(__file__).resolve().parents[1]
 
 
 @pytest.fixture
@@ -69,6 +76,46 @@ def test_capture_holds_search_spans_on_the_host_plane(log, tmp_path):
     assert kids == {"search.prepare", "search.dispatch", "search.wait",
                     "search.fetch"}
     assert top["jobs"] == 7 and top["lb_kind"] == 1
+
+
+@pytest.mark.parametrize("inst,calibrates", [(21, True), (11, False)])
+def test_prepare_spans_its_tables_and_state(log, inst, calibrates):
+    # 20x20: P = 190 pairs, so the tables compute the strong-pair
+    # order; 20x10: P = 45 <= 2 * PAIR_PREFILTER, so they never do
+    res = device.search(taillard.processing_times(inst), lb_kind=1,
+                        chunk=8, capacity=1 << 12, max_iters=2)
+    assert res.iters == 2
+    by_id = {r["span_id"]: r for r in _spans(log)}
+    prep, = _spans(log, "search.prepare")
+    kids = sorted(r["name"] for r in by_id.values()
+                  if r["parent_id"] == prep["span_id"])
+    assert kids == ["search.init_state", "search.tables"]
+    cal = _spans(log, "tables.calibrate")
+    if calibrates:
+        only, = cal
+        assert by_id[only["parent_id"]]["name"] == "search.tables"
+        assert only["pairs"] == 190 and only["samples"] == 2048
+    else:
+        assert cal == []
+    # solve_host_ms.table reads direct children of `search`: the new
+    # spans are grandchildren and leave its split as it was
+    top, = _spans(log, "search")
+    assert {r["name"] for r in by_id.values()
+            if r["parent_id"] == top["span_id"]} == {
+        "search.prepare", "search.dispatch", "search.wait", "search.fetch"}
+    spec = importlib.util.spec_from_file_location(
+        "solve_host_ms", ROOT / "benchmark" / "metrics"
+        / "solve_host_ms.table.py")
+    reader = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(reader)
+    start = log.t0 + top["ts"]
+    run = types.SimpleNamespace(window=(start, start + top["dur"]),
+                                notes={})
+    assert reader.read(run) > 0
+    split = run.notes["solve_host_split_ms"]
+    assert set(split) == {"prepare", "dispatch", "fetch", "grow",
+                          "unspanned"}
+    assert split["prepare"] == pytest.approx(1e3 * prep["dur"])
 
 
 def test_records_carry_span_parent_and_request_ids(log):

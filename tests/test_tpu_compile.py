@@ -123,3 +123,17 @@ def test_tile64_expand_is_refused_and_not_admitted(one_chip):
         _compiled_text(pallas_expand.expand_bounds_tpu, tables,
                        *_parents(one_chip, 200, 20, 1024), lb_kind=1,
                        tile=64)
+
+
+@pytest.mark.parametrize("inst", [21, 101])
+def test_pair_order_program_compiles(one_chip, inst):
+    # make_tables' strong-pair order, at the 20x20 class it serves
+    # and at J=200
+    machines, jobs = taillard.processing_times(inst).shape
+    pairs = machines * (machines - 1) // 2
+    n = len(batched._calibration_samples(jobs)[2])
+    shapes = [(jobs, machines), (machines,), (n, jobs), (n, jobs), (n,),
+              (pairs,), (pairs,)] + [(pairs, jobs)] * 4
+    args = [_sds(one_chip, s, jnp.int32) for s in shapes]
+    text = batched._strongest_first.lower(*args).compile().as_text()
+    assert "sort" in text

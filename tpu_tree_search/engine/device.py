@@ -1537,8 +1537,10 @@ def search(p_times: np.ndarray, lb_kind: int = 1, init_ub: int | None = None,
     realloc-on-push (round 1 restarted from scratch here).
 
     Flight-recorded as a `search` span whose children split the host's
-    part from the wait on the chip: `search.prepare` (tables, seeded
-    state), `search.dispatch` (run's pool check and the jitted call,
+    part from the wait on the chip: `search.prepare` (its children
+    `search.tables` and `search.init_state`: the bound tables, with a
+    `tables.calibrate` inside where the pair order is computed, and the
+    seeded state), `search.dispatch` (run's pool check and the jitted call,
     with any trace, lowering and compile), `search.wait` (the first
     host read of the result, the loop's one sync), `search.fetch` (the
     counters) and, on overflow, `search.grow`.
@@ -1550,8 +1552,10 @@ def search(p_times: np.ndarray, lb_kind: int = 1, init_ub: int | None = None,
                        lb_kind=lb_kind, chunk=chunk, capacity=capacity):
         with tracelog.span("search.prepare"):
             if tables is None:
-                tables = batched.make_tables(p_times)
-            state = init_state(jobs, capacity, init_ub, p_times=p_times)
+                with tracelog.span("search.tables"):
+                    tables = batched.make_tables(p_times)
+            with tracelog.span("search.init_state"):
+                state = init_state(jobs, capacity, init_ub, p_times=p_times)
         while True:
             with tracelog.span("search.dispatch"):
                 out = run(tables, state, lb_kind, chunk, max_iters,

@@ -256,6 +256,57 @@ def make_lb2_data(lb1: LB1Data) -> LB2Data:
                    johnson_schedules=johnson)
 
 
+def calibrate_pair_order(p, ma0, ma1, js, pt0, pt1, lag, min_tails,
+                         n_samples: int = 2048, seed: int = 0):
+    """Order machine pairs by how often each one attains the LB2 max on a
+    deterministic synthetic sample of partial schedules of THIS instance.
+
+    This realizes the reference's declared-but-never-implemented
+    `LB2_LEARN` variant (c_bound_johnson.h:29, hardcoded to FULL at :15):
+    the reference's scalar loop gets its savings from an early exit once
+    the running max crosses `best` (c_bound_johnson.c:231-233); a vector
+    unit cannot exit early, but it CAN sweep a strong prefix of pairs
+    first and only pay for the rest on the children that prefix fails to
+    prune — provided strong pairs sort first, which is what this order
+    delivers. Reordering pairs never changes the bound itself (integer
+    max over all pairs is order-invariant).
+
+    The host oracle of `batched.make_tables`, which computes the same
+    order in one compiled program."""
+    M, J = p.shape
+    P = len(ma0)
+    rng = np.random.default_rng(seed)
+    prmu = np.argsort(rng.random((n_samples, J)), axis=1)
+    lo = max(1, J // 4)
+    depth = rng.integers(lo, max(lo + 1, J - 1), n_samples)
+
+    front = np.zeros((n_samples, M), np.int64)
+    for q in range(J - 1):
+        act = q < depth
+        pj = p[:, prmu[:, q]].T                       # (n, M)
+        c = np.empty_like(front)
+        c[:, 0] = front[:, 0] + pj[:, 0]
+        for k in range(1, M):
+            c[:, k] = np.maximum(c[:, k - 1], front[:, k]) + pj[:, k]
+        front = np.where(act[:, None], c, front)
+    # job v is scheduled iff its position in the permutation < depth
+    # (a bool matrix, not a bitmask — no word-size cliff at any J)
+    sched = np.argsort(prmu, axis=1) < depth[:, None]   # (n, J)
+
+    t0 = front[:, ma0].T.astype(np.int64).copy()      # (P, n)
+    t1 = front[:, ma1].T.astype(np.int64).copy()
+    for j in range(J):
+        active = ~sched[:, js[:, j]].T                # (P, n)
+        n0 = t0 + pt0[:, j][:, None]
+        n1 = np.maximum(t1, n0 + lag[:, j][:, None]) + pt1[:, j][:, None]
+        t0 = np.where(active, n0, t0)
+        t1 = np.where(active, n1, t1)
+    per_pair = np.maximum(t1 + min_tails[ma1][:, None],
+                          t0 + min_tails[ma0][:, None])
+    freq = np.bincount(per_pair.argmax(axis=0), minlength=P)
+    return np.argsort(-freq, kind="stable")
+
+
 def set_flags(perm, limit1: int, limit2: int, n: int) -> np.ndarray:
     """1 for scheduled job ids, 0 for unscheduled (reference: c_bound_johnson.c:180-188)."""
     flags = np.zeros(n, dtype=np.int64)
