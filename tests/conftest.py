@@ -10,22 +10,25 @@ import os
 
 if os.environ.get("TTS_TEST_TPU"):
     # hardware mode: keep the attached TPU backend so the pallas-kernel
-    # parity tests (tests/test_pallas_tpu.py) run; tests that need the
-    # 8-device virtual mesh are skipped below when fewer chips exist
+    # parity tests (tests/test_pallas_tpu.py) run; a distributed test is
+    # skipped below when it needs more chips than are attached: its
+    # `n_devices` parameter, or else the 8-device mesh of CPU mode
     import jax  # noqa: F401
 
     def pytest_collection_modifyitems(config, items):
         import jax as _jax
 
         import pytest as _pytest
-        if _jax.device_count() >= 8:
-            return
-        skip = _pytest.mark.skip(
-            reason="needs the 8-device mesh (CPU mode or a full slice)")
+        have = _jax.device_count()
         for item in items:
-            if ("distributed" in item.nodeid
-                    or "test_engine_distributed" in item.nodeid):
-                item.add_marker(skip)
+            if "distributed" not in item.nodeid:
+                continue
+            callspec = getattr(item, "callspec", None)
+            need = (callspec.params.get("n_devices", 8)
+                    if callspec is not None else 8)
+            if need > have:
+                item.add_marker(_pytest.mark.skip(
+                    reason=f"needs {need} devices, {have} attached"))
 else:
     os.environ["JAX_PLATFORMS"] = "cpu"
     flags = os.environ.get("XLA_FLAGS", "")

@@ -27,8 +27,10 @@ global pool is empty.
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import functools
+import threading
 
 import jax
 import jax.numpy as jnp
@@ -319,7 +321,8 @@ def member_body(tables, make_local_step, balance_period: int,
         s = jax.lax.fori_loop(0, balance_period,
                               lambda _, x: local_step(x), s)
         s = s._replace(best=jax.lax.pmin(s.best, AX))
-        return _balance_round(s, transfer_cap, min_transfer, limit)
+        with jax.named_scope("balance"):
+            return _balance_round(s, transfer_cap, min_transfer, limit)
 
     return body
 
@@ -359,8 +362,9 @@ def build_dist_loop(mesh, tables, make_local_step,
         s = s._replace(best=jnp.minimum(s.best, bound_cap))
 
         def cond(s: SearchState):
-            has_work = jax.lax.psum(s.size, AX) > 0
-            ok = jax.lax.psum(s.overflow.astype(jnp.int32), AX) == 0
+            with jax.named_scope("terminate"):
+                has_work = jax.lax.psum(s.size, AX) > 0
+                ok = jax.lax.psum(s.overflow.astype(jnp.int32), AX) == 0
             return has_work & ok & (s.iters < max_iters)
 
         body = member_body(tables, make_local_step, balance_period,
@@ -405,30 +409,38 @@ class DistResult:
                                             # accounting semantics
 
 
-def _shard_frontier(fr: Frontier, n_dev: int, capacity: int, jobs: int,
-                    init_best: int, limit: int | None = None):
+def _stripes(fr: Frontier, n_dev: int, width: int, limit: int):
     """Round-robin stripe the frontier across workers
-    (reference: roundRobin_distribution, Pool_atom.c:14-36). `limit`
-    (device.row_limit) bounds each stripe so seeding respects the
-    engine's usable-row invariant."""
-    if limit is None:
-        limit = capacity
+    (reference: roundRobin_distribution, Pool_atom.c:14-36), front-
+    aligned in `width` rows per worker: prmu (n_dev, jobs, width),
+    depth (n_dev, width), aux (n_dev, A, width) and the stripe sizes.
+    `limit` (device.row_limit) bounds each stripe so seeding respects
+    the engine's usable-row invariant."""
+    jobs = fr.prmu.shape[1]
     aux_w = 0 if fr.aux is None else fr.aux.shape[1]
-    prmu = np.zeros((n_dev, jobs, capacity), np.int16)
-    depth = np.zeros((n_dev, capacity), np.int16)
-    aux = np.zeros((n_dev, aux_w, capacity),
+    prmu = np.zeros((n_dev, jobs, width), np.int16)
+    depth = np.zeros((n_dev, width), np.int16)
+    aux = np.zeros((n_dev, aux_w, width),
                    fr.aux.dtype if aux_w else np.int32)
     sizes = np.zeros(n_dev, np.int32)
     for d in range(n_dev):
-        stripe_p = fr.prmu[d::n_dev]
-        stripe_d = fr.depth[d::n_dev]
-        n = len(stripe_d)
-        assert n <= limit
-        prmu[d, :, :n] = stripe_p.T
-        depth[d, :n] = stripe_d
+        n = len(fr.depth[d::n_dev])
+        assert n <= min(limit, width)
+        prmu[d, :, :n] = fr.prmu[d::n_dev].T
+        depth[d, :n] = fr.depth[d::n_dev]
         if aux_w:
             aux[d, :, :n] = fr.aux[d::n_dev].T
         sizes[d] = n
+    return prmu, depth, aux, sizes
+
+
+def _shard_frontier(fr: Frontier, n_dev: int, capacity: int, jobs: int,
+                    init_best: int, limit: int | None = None):
+    """The seeded state's leaves built on the host at full capacity (the
+    megabatch stacker and the CLI's balance profiler take host leaves;
+    `_DistDriver.seed` builds the same state on the mesh)."""
+    prmu, depth, aux, sizes = _stripes(
+        fr, n_dev, capacity, capacity if limit is None else limit)
     return (
         jnp.asarray(prmu), jnp.asarray(depth), jnp.asarray(aux),
         jnp.asarray(sizes),
@@ -440,6 +452,53 @@ def _shard_frontier(fr: Frontier, n_dev: int, capacity: int, jobs: int,
         jnp.zeros(n_dev, bool),
         jnp.zeros((n_dev, tele.enabled_width()), jnp.int64),
     )
+
+
+@functools.lru_cache(maxsize=16)
+def _seed_program(mesh, capacity: int, aux_rows: int, telemetry_width: int):
+    """The jitted program that builds a seeded state on the mesh: each
+    worker allocates its own zero pools and writes its stripe (padded to
+    a few rows) at their front, so nothing of pool size crosses from the
+    host. Every leaf comes out sharded on the worker axis, as the loop's
+    own outputs are, except a zero-size one (the aux pool of a problem
+    without per-node tables, the telemetry block with the flag off):
+    the TPU compiler overrides a pinned sharding of those with a
+    replicated one and refuses the program (seen compiling for a
+    described v5e 2x2), so `_DistDriver._pin_empty` commits them
+    afterwards."""
+    from jax.sharding import NamedSharding
+    shard = NamedSharding(mesh, P(AX))
+
+    def seeded(prmu, depth, aux, size, best):
+        n_dev = size.shape[0]
+
+        def pool(rows):
+            full = jnp.zeros(rows.shape[:-1] + (capacity,), rows.dtype)
+            return jax.lax.dynamic_update_slice(full, rows,
+                                                (0,) * rows.ndim)
+
+        zeros = jnp.zeros(n_dev, jnp.int64)
+        return SearchState(
+            prmu=pool(prmu), depth=pool(depth), aux=pool(aux), size=size,
+            best=jnp.full((n_dev,), best, jnp.int32),
+            tree=zeros, sol=zeros, iters=zeros, evals=zeros, sent=zeros,
+            recv=zeros, steals=zeros,
+            overflow=jnp.zeros(n_dev, bool),
+            telemetry=jnp.zeros((n_dev, telemetry_width), jnp.int64))
+
+    out = SearchState(*(shard,) * len(SearchState._fields))._replace(
+        aux=shard if aux_rows else None,
+        telemetry=shard if telemetry_width else None)
+    return jax.jit(seeded,
+                   in_shardings=(shard,) * 4 + (NamedSharding(mesh, P()),),
+                   out_shardings=out)
+
+
+def _seed_rows(stripe: int) -> int:
+    """Rows each worker's stripe is padded to on its way to the mesh: a
+    power of two (at least 8), so frontiers of nearby sizes share one
+    seed program."""
+    return max(8, 1 << (max(stripe, 1) - 1).bit_length())
 
 
 def _fetch(x) -> np.ndarray:
@@ -487,6 +546,31 @@ def fetch_state(state: SearchState) -> SearchState:
     global value so every process holds it — needed for checkpointing
     and pool growth)."""
     return SearchState(*(_fetch(x) for x in state))
+
+
+class _LoopCache:
+    """A bounded, least-recently-used map from a loop's key to its
+    jitted program: the cache of searches whose caller passes none, so
+    that same-shape searches in one process trace and compile their
+    loop once, as `device.search`'s module-level jit does."""
+
+    def __init__(self, size: int = 8):
+        self.size = size
+        self._fns: collections.OrderedDict = collections.OrderedDict()
+        self._lock = threading.Lock()
+
+    def get_or_build(self, key: tuple, build):
+        with self._lock:
+            fn = self._fns.get(key)
+            if fn is None:
+                fn = self._fns[key] = build()
+                while len(self._fns) > self.size:
+                    self._fns.popitem(last=False)
+            self._fns.move_to_end(key)
+            return fn
+
+
+_PROCESS_LOOPS = _LoopCache()
 
 
 class _DistDriver:
@@ -538,24 +622,22 @@ class _DistDriver:
                 self.mesh, self.tables, self.make_local_step,
                 self.balance_period, self.transfer_cap, self.min_transfer,
                 limit=self.limit(capacity), donate_pools=donate)
-            if self.loop_cache is not None:
-                # consult the shared cache ONCE per driver+capacity (the
-                # local memo absorbs the per-segment lookups), so its
-                # hit/miss counters read as requests-that-reused /
-                # actual-compiles
-                key = self.loop_key + (capacity, self.balance_period,
-                                       self.transfer_cap,
-                                       self.min_transfer,
-                                       self.limit(capacity))
-                if donate:
-                    # a donating executable has different buffer-alias
-                    # semantics: it must never be handed to a caller
-                    # that expects its inputs to survive
-                    key = key + ("donate",)
-                self._loops[memo_key] = self.loop_cache.get_or_build(
-                    key, build)
-            else:
-                self._loops[memo_key] = build()
+            # consult the cache ONCE per driver+capacity (the local memo
+            # absorbs the per-segment lookups), so a shared cache's
+            # hit/miss counters read as requests-that-reused /
+            # actual-compiles; without one, the process's own cache
+            # keeps same-shape searches on one trace and compile
+            key = self.loop_key + (capacity, self.balance_period,
+                                   self.transfer_cap, self.min_transfer,
+                                   self.limit(capacity))
+            if donate:
+                # a donating executable has different buffer-alias
+                # semantics: it must never be handed to a caller that
+                # expects its inputs to survive
+                key = key + ("donate",)
+            cache = (self.loop_cache if self.loop_cache is not None
+                     else _PROCESS_LOOPS)
+            self._loops[memo_key] = cache.get_or_build(key, build)
         return self._loops[memo_key]
 
     def commit(self, state: SearchState) -> SearchState:
@@ -570,14 +652,18 @@ class _DistDriver:
         rejects them on the next call. Pinning the loop's output
         shardings in the jit instead crashes the TPU compiler's Shardy
         import on a 4-chip mesh (seen compiling for a described v5e
-        2x2, PR 21). Moving a zero-size array costs nothing. Multi-
+        2x2, PR 21). A zero-size leaf holds no data, so a fresh one
+        from the host takes its place: resharding the loop's own output
+        would wait for the loop to finish (on 4 chips the host then
+        blocked for the whole solve inside the dispatch). Multi-
         controller runs have no AOT executables and cannot reshard a
         global array this way, so they keep the leaves as they are."""
         from jax.sharding import NamedSharding
         if jax.process_count() > 1:
             return state
         return SearchState(*(
-            jax.device_put(x, NamedSharding(self.mesh, s))
+            jax.device_put(np.zeros(x.shape, x.dtype),
+                           NamedSharding(self.mesh, s))
             if x.size == 0 else x
             for s, x in zip(self.spec_state, state)))
 
@@ -637,9 +723,14 @@ class _DistDriver:
         stripe = -(-max(len(frontier.depth), 1) // n_dev)
         while self.limit(capacity) < max(stripe, 1):
             capacity *= 2
-        state = _shard_frontier(frontier, n_dev, capacity, jobs, init_best,
-                                limit=self.limit(capacity))
-        return self.commit(SearchState(*state))
+        rows = _stripes(frontier, n_dev,
+                        min(_seed_rows(stripe), capacity),
+                        self.limit(capacity))
+        args = [_to_mesh(self.mesh, P(AX), x) for x in rows]
+        args.append(_to_mesh(self.mesh, P(), np.int32(init_best)))
+        program = _seed_program(self.mesh, capacity, rows[2].shape[1],
+                                tele.enabled_width())
+        return self._pin_empty(program(*args))
 
     # -------------------------------------------------- AOT pre-warm
 
@@ -1077,7 +1168,8 @@ def search(p_times: np.ndarray, lb_kind: int = 1, init_ub: int | None = None,
                            fused=fused_mode,
                            rung_profile=bool(rung_profile))
         if tables is None:
-            tables = prob.make_tables(table)
+            with tracelog.span("dist.tables"):
+                tables = prob.make_tables(table)
         adt = prob.aux_dtype(table)
         resumed = None
         if checkpoint_path and checkpoint.resume_path(checkpoint_path):
@@ -1224,7 +1316,8 @@ def search(p_times: np.ndarray, lb_kind: int = 1, init_ub: int | None = None,
                                               n_threads=host_threads)
                 fr.prmu, fr.depth = fr.prmu[dmask], fr.depth[dmask]
             fr.aux = prob.seed_aux(table, fr.prmu, fr.depth)
-            state = driver.seed(fr, capacity, jobs, init_best)
+            with tracelog.span("dist.seed", frontier=len(fr.depth)):
+                state = driver.seed(fr, capacity, jobs, init_best)
 
         if overlap is None:
             overlap = _cfg.env_flag(_cfg.OVERLAP_FLAG)
@@ -1382,9 +1475,15 @@ def search(p_times: np.ndarray, lb_kind: int = 1, init_ub: int | None = None,
             should_stop=stop_fn, overlap=use_overlap, grow_fn=grow_fn,
             stop_pending=stop_pending)
 
+    # the result's counters, in one fetch
+    with tracelog.span("engine.fetch"):
+        (best_dev, tree_dev, sol_dev, sizes, iters_dev, evals_dev,
+         sent_dev, recv_dev, steals_dev, telem) = checkpoint._fetch_many(
+            (out.best, out.tree, out.sol, out.size, out.iters, out.evals,
+             out.sent, out.recv, out.steals, out.telemetry), fire=False)
     h_tree = h_sol = h_expanded = 0
     host_stats = {}
-    best = int(_fetch(out.best).min())
+    best = int(best_dev.min())
     if client is not None:
         client.publish(best)   # the final fold: peers prune against it
     if session is not None:
@@ -1399,21 +1498,19 @@ def search(p_times: np.ndarray, lb_kind: int = 1, init_ub: int | None = None,
             "dev_improved": [session.dev_improved],
         }
 
-    tree_dev = _fetch(out.tree)
-    sol_dev = _fetch(out.sol)
-    sizes = _fetch(out.size)
-    iters_dev = _fetch(out.iters)
-    steals_dev = _fetch(out.steals)
     tracelog.event(
         "engine.complete", workers=n_dev,
         tree=int(tree_dev.sum()) + fr.tree + h_tree, best=best,
         iters=int(iters_dev.max()),
         balance_rounds=int(iters_dev.max()) // max(balance_period, 1),
         steals=int(steals_dev.sum()),
+        moved=int(sent_dev.sum()),
+        tree_max_over_mean=(float(tree_dev.max() / tree_dev.mean())
+                            if tree_dev.sum() > 0 else 1.0),
         complete=int(sizes.sum()) == 0)
     telemetry = None
-    if out.telemetry.shape[-1] > 0:
-        telemetry = tele.summarize(_fetch(out.telemetry))
+    if telem.shape[-1] > 0:
+        telemetry = tele.summarize(telem)
     res = DistResult(
         explored_tree=int(tree_dev.sum()) + fr.tree + h_tree,
         explored_sol=int(sol_dev.sum()) + fr.sol + h_sol,
@@ -1422,9 +1519,9 @@ def search(p_times: np.ndarray, lb_kind: int = 1, init_ub: int | None = None,
         per_device={
             "tree": tree_dev, "sol": sol_dev,
             "iters": iters_dev,
-            "evals": _fetch(out.evals),
-            "sent": _fetch(out.sent),
-            "recv": _fetch(out.recv),
+            "evals": evals_dev,
+            "sent": sent_dev,
+            "recv": recv_dev,
             "steals": steals_dev,
             "final_size": sizes,
             **host_stats,

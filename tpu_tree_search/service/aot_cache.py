@@ -362,10 +362,13 @@ class AOTCache:
 
     def store(self, key: tuple, compiled, key_repr: str = "",
               xla_cache_hit: bool = False) -> None:
-        """Queue persistence of a freshly compiled executable (writer
-        thread does serialize + CRC + atomic write). Serialization
-        failures are counted, never raised — a program the pin cannot
-        serialize still serves from memory.
+        """Persist a freshly compiled executable: serialize it here,
+        before its caller first runs it, and queue the bytes (the writer
+        thread does CRC + atomic write). On XLA:CPU a serialization
+        that races the executable's first run fails now and then
+        ("`LessThan` is not serializable"). Serialization failures are
+        counted, never raised — a program the pin cannot serialize
+        still serves from memory.
 
         An executable that XLA's persistent compilation cache served
         (`xla_cache_hit`) stays in memory on XLA:CPU: its bytes load,
@@ -374,11 +377,21 @@ class AOTCache:
         if xla_cache_hit and _cache_served_breaks():
             tracelog.event("aot_cache.skip_xla_cache_hit", key=key_repr)
             return
+        try:
+            payload = _serialize(compiled)
+        except Exception as e:  # noqa: BLE001 — per-program capability:
+            # the probe passing does not guarantee EVERY program
+            # round-trips on this pin; fall back to in-memory-only for
+            # this entry
+            self._count("_errors_c", "errors")
+            tracelog.event("aot_cache.serialize_unsupported",
+                           key=key_repr, error=repr(e))
+            return
         with self._close_lock:
             if self._closed:
                 return
-            self._q.put({"path": self.path_for(key),
-                         "compiled": compiled, "key_repr": key_repr})
+            self._q.put({"path": self.path_for(key), "payload": payload,
+                         "key_repr": key_repr})
 
     def drain(self) -> None:
         """Block until every queued entry is on disk (tests/shutdown)."""
@@ -408,16 +421,7 @@ class AOTCache:
 
     def _write(self, task: dict) -> None:
         path: pathlib.Path = task["path"]
-        try:
-            payload = _serialize(task["compiled"])
-        except Exception as e:  # noqa: BLE001 — per-program capability:
-            # the probe passing does not guarantee EVERY program
-            # round-trips on this pin; fall back to in-memory-only for
-            # this entry
-            self._count("_errors_c", "errors")
-            tracelog.event("aot_cache.serialize_unsupported",
-                           key=task["key_repr"], error=repr(e))
-            return
+        payload = task["payload"]
         header = json.dumps({
             "v": FORMAT, "fingerprint": self.fingerprint,
             "key": task["key_repr"], "created_unix": time.time(),
