@@ -54,8 +54,9 @@ def test_one_chip_phases_on_cpu(tiny, capsys):
 
 
 def test_four_chip_phase_on_cpu_mesh(tiny, monkeypatch, capsys):
-    # a narrow chunk, so the small tree's pools drift apart far enough
-    # (min_transfer = 2 * chunk) for the balance round to move nodes
+    # a narrow chunk; the small tree's pools drift apart past the donor
+    # threshold (min_transfer = 2 * min_seed) for the balance round to
+    # move nodes
     monkeypatch.setattr(chip_smoke, "SEGMENT_ITERS", 64)
     # the CPU loop has no Pallas call; its while loop stands in for one,
     # so the check below reads each phase's own compiled SPMD loop

@@ -51,7 +51,9 @@ def test_device_count_invariance_d32():
     a subprocess with its own platform config — must reproduce ta003's
     exact reference tree, with the water-filling balance plan running
     real multi-receiver rounds (sent > 0 across 32 pools seeded from one
-    root stripe)."""
+    root stripe). The donor threshold is set to 2 * chunk: the default,
+    2 * min_seed = 512 nodes above the mean, is more than these small
+    pools ever hold, so no round would move nodes."""
     import os
     import subprocess
     import sys
@@ -65,7 +67,7 @@ def test_device_count_invariance_d32():
         "out = distributed.search(taillard.processing_times(3),\n"
         "    lb_kind=2, init_ub=taillard.optimal_makespan(3),\n"
         "    n_devices=32, chunk=32, capacity=4096,\n"
-        "    balance_period=2, min_seed=256)\n"
+        "    balance_period=2, min_seed=256, min_transfer=64)\n"
         "assert out.complete\n"
         "assert out.explored_tree == 80062, out.explored_tree\n"
         "assert out.best == 1081, out.best\n"

@@ -206,9 +206,11 @@ def test_prewarm_readies_every_rung():
     p = PFSPInstance.synthetic(jobs=8, machines=3, seed=3).p_times
     cache = ExecutorCache()
     overlap = cfg.env_flag(cfg.OVERLAP_FLAG)
+    # min_seed sets the donor threshold each rung's loop is built with,
+    # so the warm and the search below share it
     how = distributed.prewarm(p, chunk=256, capacity=4096,
                               mesh=worker_mesh(4), loop_cache=cache,
-                              ladder=True, donate=overlap)
+                              ladder=True, donate=overlap, min_seed=4)
     assert how == "compile"
     n_rungs = len(rungs_for(256))
     assert len(cache.ledger_snapshot()) == n_rungs

@@ -26,10 +26,9 @@ from tpu_tree_search.utils import compile_cache
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
     try:
         topo = topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
@@ -38,7 +37,13 @@ def one_chip():
     # the persistent cache would store these compiles and fail to read
     # them back without a chip: keep it off for this module
     with compile_cache.disabled():
-        yield SingleDeviceSharding(topo.devices[0])
+        yield topo
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _sds(sharding, shape, dtype):
@@ -137,3 +142,49 @@ def test_pair_order_program_compiles(one_chip, inst):
     args = [_sds(one_chip, s, jnp.int32) for s in shapes]
     text = batched._strongest_first.lower(*args).compile().as_text()
     assert "sort" in text
+
+
+def test_balance_round_compiles_for_four_chips(topo):
+    # the flagship's round at its shapes (2^22-row pools of ta022, the
+    # defaults of a 4-chip 20x20 run): the exchange is slices, one
+    # all-to-all per pool array and in-place block writes, with no sort
+    # and no gather
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from tpu_tree_search.engine import device, distributed
+    from tpu_tree_search.parallel.mesh import WORKER_AXIS, shard_map
+
+    mesh = Mesh(np.asarray(topo.devices), (WORKER_AXIS,))
+    p = taillard.processing_times(22)
+    machines, jobs = p.shape
+    adt = device.aux_dtype(p)
+    cap, min_transfer = distributed.balance_defaults(
+        65536, jobs, machines, 4, 25, aux_itemsize=adt.itemsize)
+    capacity = 1 << 22
+    limit = min(device.row_limit(capacity, 65536, jobs),
+                capacity - 4 * cap)
+    shard = NamedSharding(mesh, P(WORKER_AXIS))
+    i64 = jnp.zeros((), jnp.int64).dtype
+    state = device.SearchState(
+        prmu=_sds(shard, (4, jobs, capacity), jnp.int16),
+        depth=_sds(shard, (4, capacity), jnp.int16),
+        aux=_sds(shard, (4, machines, capacity), adt),
+        size=_sds(shard, (4,), jnp.int32),
+        best=_sds(shard, (4,), jnp.int32),
+        tree=_sds(shard, (4,), i64), sol=_sds(shard, (4,), i64),
+        iters=_sds(shard, (4,), i64), evals=_sds(shard, (4,), i64),
+        sent=_sds(shard, (4,), i64), recv=_sds(shard, (4,), i64),
+        steals=_sds(shard, (4,), i64),
+        overflow=_sds(shard, (4,), jnp.bool_),
+        telemetry=_sds(shard, (4, 0), i64))
+    spec = tuple(P(WORKER_AXIS) for _ in device.SearchState._fields)
+
+    def body(*leaves):
+        s = distributed._local_state(*leaves)
+        return distributed._expand(distributed._balance_round(
+            s, cap, min_transfer, limit))
+
+    text = jax.jit(shard_map(body, mesh, in_specs=spec, out_specs=spec)
+                   ).lower(*state).compile().as_text()
+    assert " gather(" not in text and " sort(" not in text
+    assert text.count("all-to-all(") == 3

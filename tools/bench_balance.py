@@ -2,7 +2,8 @@
 
 Times `_balance_round` on the 8-worker virtual CPU mesh with
 20x20-class pools at chunk 32768 and a sweep of transfer_cap values
-(including the byte-budgeted default), reporting ms/round and the
+(including the default of distributed.balance_defaults, whose donor
+threshold every round uses), reporting ms/round and the
 all_to_all buffer footprint. Multi-chip hardware is not reachable from
 this environment, so absolute times are CPU-mesh numbers — the useful
 outputs are the RELATIVE cost vs transfer_cap and the buffer sizes,
@@ -80,11 +81,12 @@ def main():
             leaves.append(jnp.broadcast_to(x, (D,) + x.shape).copy())
     specs = device.SearchState(*(P("workers") for _ in base._fields))
 
-    A = machines
-    bytes_per_col = 2 * jobs + 4 * A + 2
-    caps = sorted({chunk // 2, chunk, 2 * chunk, 4 * chunk,
-                   max(min(4 * chunk, distributed.BALANCE_BYTE_BUDGET
-                           // (bytes_per_col * D)), 256)})
+    itemsize = device.aux_dtype(p).itemsize
+    bytes_per_col = 2 * jobs + itemsize * machines + 2
+    default_cap, min_transfer = distributed.balance_defaults(
+        chunk, jobs, machines, D, distributed.MIN_SEED,
+        aux_itemsize=itemsize)
+    caps = sorted({chunk // 2, chunk, 2 * chunk, 4 * chunk, default_cap})
     for cap in caps:
         limit = device.row_limit(capacity, chunk, jobs) - D * cap
 
@@ -93,7 +95,7 @@ def main():
             def body(*ls):
                 s = device.SearchState(*(x[0] for x in ls))
                 for _ in range(1):
-                    s = distributed._balance_round(s, cap, chunk // 2,
+                    s = distributed._balance_round(s, cap, min_transfer,
                                                    limit)
                 return tuple(x[None] for x in s)
             return shard_map(body, mesh,

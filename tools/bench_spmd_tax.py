@@ -91,7 +91,8 @@ def main():
 
     # the full SPMD program on a 1-chip mesh, same state stacked
     adt = device.aux_dtype(p)
-    tc = distributed.default_transfer_cap(chunk, jobs, machines, 1,
+    tc, mt = distributed.balance_defaults(chunk, jobs, machines, 1,
+                                          distributed.MIN_SEED,
                                           aux_itemsize=adt.itemsize)
     limit = min(device.row_limit(args.capacity, chunk, jobs),
                 args.capacity - tc)
@@ -100,8 +101,7 @@ def main():
         return functools.partial(device.step, t, lb, chunk, limit=lim)
 
     loop = distributed.build_dist_loop(
-        worker_mesh(1), tables, mls, args.balance_period, tc,
-        2 * chunk, limit)
+        worker_mesh(1), tables, mls, args.balance_period, tc, mt, limit)
     stacked = tuple(x[None] for x in state)
 
     def dist():

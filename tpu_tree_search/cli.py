@@ -1325,10 +1325,9 @@ def _write_csv_with_phases(args, p, init_ub, n_dev, elapsed, tree, sol,
             from .parallel.mesh import worker_mesh
 
             adt = dev.aux_dtype(p)
-            transfer_cap = dist.default_transfer_cap(
-                args.chunk, jobs, machines, n_dev,
+            transfer_cap, min_transfer = dist.balance_defaults(
+                args.chunk, jobs, machines, n_dev, args.m,
                 aux_itemsize=adt.itemsize)
-            min_transfer = 2 * args.chunk
             # the profiled round must honor _balance_round's contract
             # limit <= capacity - D*transfer_cap with limit >= 1; a
             # too-small capacity is GROWN (the same pre-grow rule as

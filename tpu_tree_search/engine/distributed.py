@@ -60,26 +60,36 @@ AX = WORKER_AXIS
 import warnings as _warnings  # noqa: E402
 
 # per-worker byte budget for one balance round's all_to_all buffers
-# (each way); caps the DEFAULT transfer_cap at production shapes — see
-# default_transfer_cap() and tools/bench_balance.py for the measured
-# tradeoff
+# (each way); caps the default transfer_cap of wide shapes
 BALANCE_BYTE_BUDGET = 64 << 20
 
+# default warm-up frontier per worker (the CLI's `-m` sets its own),
+# which also sets the default donor threshold (balance_defaults)
+MIN_SEED = 32
 
-def default_transfer_cap(chunk: int, jobs: int, machines: int,
-                         n_dev: int, aux_itemsize: int = 4) -> int:
-    """Default balance transfer cap: 4*chunk, byte-budgeted. The
-    all_to_all moves (2J + aux_itemsize*A + 2) bytes per column over
-    D*transfer_cap columns each way per worker; at production shapes
-    (chunk 32768, 20x20, D=8) the uncapped default is ~122 MB of
-    exchange buffer per worker per round — the cap bounds it to
-    BALANCE_BYTE_BUDGET. `aux_itemsize` is the pool aux dtype's width
-    (2 for the int16 classes, device.aux_dtype). SHARED by search() and
-    the CSV phase profiler (cli) so the profiled exchange is the one
-    production runs."""
+
+def balance_defaults(chunk: int, jobs: int, machines: int, n_dev: int,
+                     min_seed: int, aux_itemsize: int = 4
+                     ) -> tuple[int, int]:
+    """The balance round's default `(transfer_cap, min_transfer)`, the
+    one place every caller takes them from (search, prewarm, the
+    chunk ladder, megabatch, the tuner's probe, the CLI's phase
+    profiler, tools/bench_balance.py), so the profiled exchange is the
+    one production runs.
+
+    - `transfer_cap`, the most one pair moves in a round: one chunk,
+      which refills an empty receiver for one step. The all_to_all
+      moves (2J + aux_itemsize*A + 2) bytes per row over
+      D*transfer_cap rows each way per worker, which
+      BALANCE_BYTE_BUDGET bounds for wide shapes. `aux_itemsize` is the
+      pool aux dtype's width (device.aux_dtype).
+    - `min_transfer`, the donor threshold: 2 * min_seed nodes above the
+      mean, the reference's steal-half rule (a victim gives half its
+      pool when it holds at least ratio*m nodes, popBackBulk,
+      Pool_atom.c:154-178, ratio 2, m the `-m` warm-up size)."""
     bytes_per_col = 2 * jobs + aux_itemsize * machines + 2
     budget_cols = BALANCE_BYTE_BUDGET // (bytes_per_col * max(n_dev, 1))
-    return max(min(4 * chunk, budget_cols), 256)
+    return max(min(chunk, budget_cols), 1), 2 * min_seed
 
 
 # ---------------------------------------------------------------------------
@@ -179,15 +189,21 @@ def bfs_warmup(p_times: np.ndarray, lb_kind: int, init_ub: int | None,
 # Step 2: the SPMD search loop
 
 
+def block_starts(plan: jax.Array, me, size) -> tuple[jax.Array, jax.Array]:
+    """`(send_at, recv_at)`, each (D,), for worker `me` of pool size
+    `size` under the round's flow matrix `plan`: the row where the nodes
+    it sends to receiver e start (its donations, popped from the stack
+    top, laid out in receiver order), and the row where sender d's block
+    is written (the received nodes, appended in sender order)."""
+    my_out, my_in = plan[me], plan[:, me]
+    base = size - my_out.sum(dtype=jnp.int32)
+    return (base + jnp.cumsum(my_out, dtype=jnp.int32) - my_out,
+            base + jnp.cumsum(my_in, dtype=jnp.int32) - my_in)
+
+
 def _balance_round(s: SearchState, transfer_cap: int,
                    min_transfer: int, limit: int) -> SearchState:
     """One collective steal-half exchange (see parallel/balance.py).
-
-    `limit` is the usable-row bound every commit must respect; the loop
-    builder reserves `D * transfer_cap` rows of headroom above it (and
-    runs the local steps against the same tightened limit), so the
-    receive block write is ALWAYS in bounds — an overflowing round never
-    clamps onto live rows.
 
     The round is globally transactional: each worker's would-overflow
     flag (known before any data moves — a worker receives exactly
@@ -196,105 +212,89 @@ def _balance_round(s: SearchState, transfer_cap: int,
     overflow flag and the driver grows every pool and RESUMES from this
     state, losing nothing.
 
-    The pack/exchange/unpack (the gathers, the all_to_all, the sort) is
-    cond-gated on the plan being non-empty and fitting — a balanced
-    steady state pays one all_gather of the sizes, one tiny psum, and a
-    zero-block scratch write.
+    The all_to_all is cond-gated on the plan being non-empty and
+    fitting. Around it, each pool array moves as D blocks of
+    `transfer_cap` rows:
+
+    - pack (in rounds that move nodes): the rows a donor sends to
+      receiver e are contiguous from `send_at[e]` (block_starts; popped
+      from the stack top, which keeps the DFS locality of the
+      reference's popBack stealing), so each block is one dynamic_slice;
+    - unpack: sender d's block arrives front-packed with plan[d, me]
+      valid rows and is written at `recv_at[d]`, in sender order, so
+      each write covers the garbage tail of the one before it and the
+      last tail lands above the new cursor, where rows are garbage by
+      the pool invariant. A round that moves nothing writes zero blocks
+      at `limit`, which no live row reaches.
+
+    Bounds: every block starts at or below `limit` (sends inside the
+    live pool, receives at or below the new cursor, which passed the
+    overflow test), and `_DistDriver.limit` keeps
+    `limit <= capacity - D * transfer_cap`, so every block of
+    `transfer_cap` rows lies inside the pool. A clamped dynamic_slice
+    or dynamic_update_slice would read or overwrite live rows silently.
     """
-    J, capacity = s.prmu.shape
-    A = s.aux.shape[0]
     D = jax.lax.psum(1, AX)
     sizes = jax.lax.all_gather(s.size, AX)                  # (D,)
     plan = bal.exchange_plan(sizes, transfer_cap, min_transfer)
     me = jax.lax.axis_index(AX)
-    my_out = plan[me]                                       # (D,)
-    total_out = my_out.sum(dtype=jnp.int32)
+    send_at, recv_at = block_starts(plan, me, s.size)
+    total_out = plan[me].sum(dtype=jnp.int32)
     total_in = plan[:, me].sum(dtype=jnp.int32)
     base = s.size - total_out
-    n_recv = plan.shape[0] * transfer_cap
-    # Would-overflow is known BEFORE the exchange (each worker receives
-    # exactly plan[:, me].sum() nodes) and is decided globally: if ANY
-    # worker would overflow, NO worker exchanges or commits — every node
-    # keeps living in exactly one pool, the loop exits on the flag, and
-    # the driver grows every pool and resumes losslessly (the round-1
-    # design restarted from the warm-up frontier, discarding all
-    # explored work).
+    # Would-overflow is known BEFORE the exchange and is decided
+    # globally: if ANY worker would overflow, NO worker exchanges or
+    # commits — every node keeps living in exactly one pool, the loop
+    # exits on the flag, and the host grows every pool and resumes
+    # losslessly.
     ovf = jax.lax.psum((base + total_in > limit).astype(jnp.int32), AX) > 0
     # identical on every worker (plan and ovf are pure functions of the
     # all_gathered sizes), so the cond below cannot diverge across the
     # mesh and the collectives inside it are safe
     do_flow = (plan.sum() > 0) & ~ovf
 
-    def do_exchange(_):
-        # pack donated nodes (from the stack top) into per-receiver blocks
-        offs = jnp.cumsum(my_out, dtype=jnp.int32) - my_out
-        k = jnp.arange(transfer_cap, dtype=jnp.int32)
-        rows = base + offs[:, None] + k[None, :]            # (D, cap)
-        send_mask = k[None, :] < my_out[:, None]
-        rows_c = jnp.clip(rows, 0, capacity - 1).reshape(-1)
-        buf_prmu = jnp.take(s.prmu, rows_c, axis=1)         # (J, D*cap)
-        buf_aux = jnp.take(s.aux, rows_c, axis=1)           # (A, D*cap)
-        buf_depth = jnp.where(send_mask.reshape(-1),
-                              s.depth[rows_c], -1)[None, :]  # -1 = hole
+    def exchange(_):
+        def pack(x):
+            # D blocks of `transfer_cap` rows, the D axis first
+            return jnp.stack([
+                jax.lax.dynamic_slice_in_dim(x, send_at[e], transfer_cap,
+                                             axis=-1) for e in range(D)])
+        return tuple(jax.lax.all_to_all(pack(x), AX, 0, 0)
+                     for x in (s.prmu, s.depth, s.aux))
 
-        # all_to_all exchanges the per-receiver blocks (the D axis must
-        # be the split axis exactly)
-        def exchange(x):
-            rows = x.shape[0]
-            blocks = x.reshape(rows, D, transfer_cap)
-            return jax.lax.all_to_all(blocks, AX, 1, 1) \
-                .reshape(rows, D * transfer_cap)
+    def idle(_):
+        return tuple(jnp.zeros((D,) + x.shape[:-1] + (transfer_cap,),
+                               x.dtype) for x in (s.prmu, s.depth, s.aux))
 
-        rbuf_prmu = exchange(buf_prmu)
-        rbuf_aux = exchange(buf_aux)
-        rbuf_depth = exchange(buf_depth)
+    # The pools stay out of the cond: one carried through it takes the
+    # layout its block ops prefer, which at small chunks cost a relayout
+    # copy of the whole prmu pool in every round. A round that moves
+    # nothing writes its zero blocks at the limit, above every live row.
+    blocks = jax.lax.cond(do_flow, exchange, idle, None)
+    write_at = jnp.where(do_flow, recv_at, jnp.asarray(limit, jnp.int32))
 
-        # compact received nodes to the front of the block (same
-        # scatter-free scheme as device.step)
-        flat_depth = rbuf_depth.reshape(-1)
-        push = flat_depth >= 0
-        order = jnp.argsort(~push, stable=True)
-        return (jnp.take(rbuf_prmu, order, axis=1),
-                jnp.take(rbuf_aux, order, axis=1),
-                jnp.take(flat_depth, order).astype(jnp.int16),
-                push.sum(dtype=jnp.int32))
+    def unpack(x, got):
+        for d in range(D):
+            x = jax.lax.dynamic_update_slice_in_dim(x, got[d], write_at[d],
+                                                    axis=-1)
+        return x
 
-    def no_exchange(_):
-        return (jnp.zeros((J, n_recv), s.prmu.dtype),
-                jnp.zeros((A, n_recv), s.aux.dtype),
-                jnp.full((n_recv,), -1, s.depth.dtype),
-                jnp.int32(0))
-
-    recv_prmu, recv_aux, recv_depth, n_push = jax.lax.cond(
-        do_flow, do_exchange, no_exchange, 0)
-
-    # Commit (a skipped/aborted round routes its zero block to the
-    # scratch rows above `limit` — in bounds by the loop builder's
-    # headroom reservation, and never read because rows above the
-    # cursor are garbage by the pool invariant).
-    zero = jnp.zeros((), base.dtype)
-    write_at = jnp.where(do_flow, base, jnp.asarray(limit, base.dtype))
     keep = lambda new, old: jnp.where(do_flow, new, old)  # noqa: E731
     telem = s.telemetry
     if telem.shape[-1] > 0:
-        # steal-flow telemetry mirrors the sent/recv counters below,
-        # under the same committed-round guard
+        # steal-flow telemetry mirrors the sent/recv counters below
         t = telem.at[tele.O_STEAL_SENT].add(total_out.astype(jnp.int64))
-        t = t.at[tele.O_STEAL_RECV].add(n_push.astype(jnp.int64))
+        t = t.at[tele.O_STEAL_RECV].add(total_in.astype(jnp.int64))
         telem = keep(t, telem)
     return s._replace(
         telemetry=telem,
-        prmu=jax.lax.dynamic_update_slice(s.prmu, recv_prmu,
-                                          (zero, write_at)),
-        depth=jax.lax.dynamic_update_slice(s.depth, recv_depth,
-                                           (write_at,)),
-        aux=jax.lax.dynamic_update_slice(s.aux, recv_aux, (zero, write_at)),
-        size=keep(base + n_push, s.size),
+        prmu=unpack(s.prmu, blocks[0]), depth=unpack(s.depth, blocks[1]),
+        aux=unpack(s.aux, blocks[2]),
+        size=keep(base + total_in, s.size),
         sent=keep(s.sent + total_out.astype(jnp.int64), s.sent),
-        recv=keep(s.recv + n_push.astype(jnp.int64), s.recv),
-        steals=keep(s.steals + (n_push > 0).astype(jnp.int64), s.steals),
-        overflow=s.overflow | ovf,
-    )
+        recv=keep(s.recv + total_in.astype(jnp.int64), s.recv),
+        steals=keep(s.steals + (total_in > 0).astype(jnp.int64), s.steals),
+        overflow=s.overflow | ovf)
 
 
 def _local_state(*leaves):
@@ -850,7 +850,7 @@ def _problem_driver(problem, mesh, tables, table, lb_kind: int,
 
 def _ladder_plan(problem, mesh, tables, table, lb_kind: int, chunk: int,
                  balance_period: int, transfer_cap: int | None,
-                 min_transfer: int | None, adt, loop_cache,
+                 min_transfer: int | None, min_seed: int, adt, loop_cache,
                  rung_profile=None, fused_mode: str = "off"
                  ) -> tuple[tuple, dict]:
     """One _DistDriver per chunk-ladder rung (engine/ladder.rungs_for),
@@ -866,7 +866,7 @@ def _ladder_plan(problem, mesh, tables, table, lb_kind: int, chunk: int,
     `transfer_cap` / `min_transfer` are the CALLER's explicit values
     (applied to every rung when given — a cap sized for the tuned
     chunk over-reserves for the small rungs, which is safe); None
-    derives each rung's own (the byte-budget rule / 2*chunk).
+    derives each rung's own (balance_defaults).
 
     Shared by search() and prewarm() so a boot-warmed rung executable
     is key-identical to the one a ladder search builds.
@@ -889,11 +889,11 @@ def _ladder_plan(problem, mesh, tables, table, lb_kind: int, chunk: int,
         rungs = rungs_for(chunk, min_chunk=min_rung_for(lb_kind))
     cfgs = []
     for c in rungs:
-        tc = (transfer_cap if transfer_cap is not None
-              else default_transfer_cap(c, jobs, aux_rows, n_dev,
-                                        aux_itemsize=adt.itemsize))
-        mt = min_transfer if min_transfer is not None else 2 * c
-        cfgs.append((c, tc, mt))
+        tc, mt = balance_defaults(c, jobs, aux_rows, n_dev, min_seed,
+                                  aux_itemsize=adt.itemsize)
+        cfgs.append((c,
+                     transfer_cap if transfer_cap is not None else tc,
+                     min_transfer if min_transfer is not None else mt))
 
     def unified_limit(cap: int) -> int:
         return min(min(problem.usable_rows(cap, c, jobs),
@@ -911,7 +911,7 @@ def _ladder_plan(problem, mesh, tables, table, lb_kind: int, chunk: int,
 
 def prewarm(p_times: np.ndarray, lb_kind: int = 1, chunk: int = 64,
             capacity: int | None = None, balance_period: int = 4,
-            min_seed: int = 32, n_devices: int | None = None,
+            min_seed: int = MIN_SEED, n_devices: int | None = None,
             mesh=None, transfer_cap: int | None = None,
             min_transfer: int | None = None, loop_cache=None,
             donate: bool = False, ladder: bool | None = None,
@@ -964,7 +964,7 @@ def prewarm(p_times: np.ndarray, lb_kind: int = 1, chunk: int = 64,
     if ladder:
         rungs, drivers = _ladder_plan(
             prob, mesh, tables, table, lb_kind, chunk, balance_period,
-            transfer_cap, min_transfer, adt, loop_cache,
+            transfer_cap, min_transfer, min_seed, adt, loop_cache,
             rung_profile=rung_profile, fused_mode=fused_mode)
         if len(rungs) < 2:
             drivers = None             # single rung: plain path
@@ -972,11 +972,10 @@ def prewarm(p_times: np.ndarray, lb_kind: int = 1, chunk: int = 64,
         driver = drivers[max(drivers)]
     else:
         from .ladder import fused_for
-        if transfer_cap is None:
-            transfer_cap = default_transfer_cap(
-                chunk, jobs, aux_rows, mesh.devices.size,
-                aux_itemsize=adt.itemsize)
-        min_transfer = min_transfer or 2 * chunk
+        tc, mt = balance_defaults(chunk, jobs, aux_rows, mesh.devices.size,
+                                  min_seed, aux_itemsize=adt.itemsize)
+        transfer_cap = tc if transfer_cap is None else transfer_cap
+        min_transfer = mt if min_transfer is None else min_transfer
         driver = _problem_driver(prob, mesh, tables, table, lb_kind,
                                  chunk, balance_period, transfer_cap,
                                  min_transfer, adt, loop_cache,
@@ -1006,7 +1005,7 @@ def search(p_times: np.ndarray, lb_kind: int = 1, init_ub: int | None = None,
            n_devices: int | None = None, chunk: int | None = 64,
            capacity: int = 1 << 17, balance_period: int | None = 4,
            transfer_cap: int | None = None, min_transfer: int | None = None,
-           min_seed: int = 32, max_rounds: int | None = None,
+           min_seed: int = MIN_SEED, max_rounds: int | None = None,
            tables: BoundTables | None = None, mesh=None,
            segment_iters: int | None = None,
            checkpoint_path: str | None = None,
@@ -1028,7 +1027,9 @@ def search(p_times: np.ndarray, lb_kind: int = 1, init_ub: int | None = None,
     period is chosen for SPREAD of per-worker trees. The CPU mesh's
     wall-clock preference for sparse periods is an artifact of
     host-serialized collectives; do not retune this knob on the
-    virtual mesh.
+    virtual mesh. `transfer_cap` / `min_transfer` left None take
+    balance_defaults: one chunk per pair, and a donor threshold of
+    2 * `min_seed` nodes above the mean.
 
     With `segment_iters`/`checkpoint_path` the loop runs in bounded
     segments with heartbeat + checkpoint/resume between them — the
@@ -1212,17 +1213,15 @@ def search(p_times: np.ndarray, lb_kind: int = 1, init_ub: int | None = None,
             # derives per rung) and one unified limit — see _ladder_plan
             rungs, ladder_drivers = _ladder_plan(
                 prob, mesh, tables, table, lb_kind, chunk, balance_period,
-                transfer_cap, min_transfer, adt, loop_cache,
+                transfer_cap, min_transfer, min_seed, adt, loop_cache,
                 rung_profile=rung_profile, fused_mode=fused_mode)
             if len(rungs) < 2:
                 ladder_drivers = None      # chunk too small to ladder:
                 #                            plain single-driver path
-        if transfer_cap is None:
-            transfer_cap = default_transfer_cap(chunk, jobs,
-                                                prob.aux_rows(table),
-                                                mesh.devices.size,
-                                                aux_itemsize=adt.itemsize)
-        min_transfer = min_transfer or 2 * chunk
+        tc, mt = balance_defaults(chunk, jobs, prob.aux_rows(table), n_dev,
+                                  min_seed, aux_itemsize=adt.itemsize)
+        transfer_cap = tc if transfer_cap is None else transfer_cap
+        min_transfer = mt if min_transfer is None else min_transfer
 
         if ladder_drivers is not None:
             driver = ladder_drivers[chunk]   # the tuned top rung — also

@@ -299,7 +299,7 @@ def serve_batch(specs: list, problem="pfsp", lb_kind: int = 1,
                 balance_period: int | None = None,
                 transfer_cap: int | None = None,
                 min_transfer: int | None = None,
-                min_seed: int = 32,
+                min_seed: int = dist.MIN_SEED,
                 segment_iters: int = 512,
                 checkpoint_every: int = 1,
                 heartbeat=None, member_stop=None, on_member_done=None,
@@ -373,10 +373,10 @@ def serve_batch(specs: list, problem="pfsp", lb_kind: int = 1,
                        source=params.source, batch=B)
     if capacity is None:
         capacity = prob.default_capacity(tables0)
-    if transfer_cap is None:
-        transfer_cap = dist.default_transfer_cap(
-            chunk, jobs, aux_rows, n_dev, aux_itemsize=adt.itemsize)
-    min_transfer = min_transfer or 2 * chunk
+    tc, mt = dist.balance_defaults(chunk, jobs, aux_rows, n_dev, min_seed,
+                                   aux_itemsize=adt.itemsize)
+    transfer_cap = tc if transfer_cap is None else transfer_cap
+    min_transfer = mt if min_transfer is None else min_transfer
 
     def make_local_step(t, limit):
         # fused stays "off" (the default) under megabatch: the batched

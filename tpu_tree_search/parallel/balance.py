@@ -17,8 +17,10 @@ inside the compiled loop:
    popBackBulk, Pool_atom.c:154-178), capped by the static
    transfer-buffer size.
 3. Donors pop from the top of their stack (deepest nodes — preserving the
-   DFS locality the reference's popBack stealing keeps), pack into a
-   (workers, cap, ...) buffer, `all_to_all` it, receivers push valid rows.
+   DFS locality the reference's popBack stealing keeps): the rows for
+   each receiver are one contiguous slice, D slices of `cap` rows form
+   the send buffer, `all_to_all` moves it, and receivers append each
+   sender's rows in sender order (engine/distributed._balance_round).
 
 No locks, no victim retries, no communicator thread: the plan is a pure
 function of the gathered sizes, so every worker agrees on it by
@@ -62,7 +64,9 @@ def exchange_plan(sizes: jax.Array, cap: int, min_transfer: int) -> jax.Array:
     computes the same plan. Water-filling: workers above the mean donate
     half their surplus (steal-half, the reference's `ratio=2` semantics
     from popBackBulk, Pool_atom.c:154-178, and its `size >= 2m` threshold
-    via `min_transfer`), workers below the mean fill their deficit. Donor
+    via `min_transfer`, by default 2 * min_seed nodes above the mean,
+    engine/distributed.balance_defaults), workers below the mean fill
+    their deficit. Donor
     surpluses and receiver deficits are laid out as consecutive intervals
     on one shared flow axis; plan[d, e] is the overlap of donor d's and
     receiver e's intervals — so one hot worker feeds MANY starving

@@ -144,8 +144,9 @@ def search_distributed(n: int, g: int = 1, n_devices: int | None = None,
                        min_transfer: int | None = None, mesh=None):
     """Distributed N-Queens through the generic SPMD engine (the
     drop-in for the deleted nqueens_device.search_distributed, with
-    its exact 4*chunk / 2*chunk transfer defaults — the byte-budgeted
-    default_transfer_cap floor would re-size tiny-chunk test runs)."""
+    its exact 4*chunk / 2*chunk transfer defaults, which the tiny-chunk
+    tests were sized for; distributed.balance_defaults would re-size
+    them)."""
     from ..engine import distributed
     return distributed.search(
         table(n, g), problem="nqueens", lb_kind=0, n_devices=n_devices,

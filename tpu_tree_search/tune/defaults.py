@@ -51,8 +51,8 @@ BENCH_CHUNK_DEFAULT = 65536
 @dataclasses.dataclass(frozen=True)
 class Params:
     """One resolved dispatch configuration. ``transfer_cap`` None means
-    "derive from chunk via distributed.default_transfer_cap" (the byte-
-    budgeted rule); ``source`` records which tier produced it:
+    "derive from chunk via distributed.balance_defaults" (one chunk,
+    byte-budgeted); ``source`` records which tier produced it:
     ``default`` (this table), ``cache`` (a persisted tuned entry) or
     ``probe`` (freshly measured)."""
 

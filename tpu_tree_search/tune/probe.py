@@ -138,11 +138,11 @@ class ProbeHarness:
 
         chunk = int(chunk)
         balance_period = int(balance_period)
-        if transfer_cap is None:
-            transfer_cap = distributed.default_transfer_cap(
-                chunk, self.jobs, self.machines, 1,
-                aux_itemsize=self._adt.itemsize)
-        min_transfer = int(min_transfer or 2 * chunk)
+        tc, mt = distributed.balance_defaults(
+            chunk, self.jobs, self.machines, 1, distributed.MIN_SEED,
+            aux_itemsize=self._adt.itemsize)
+        transfer_cap = tc if transfer_cap is None else int(transfer_cap)
+        min_transfer = mt if min_transfer is None else int(min_transfer)
         limit = min(self.problem.usable_rows(self.capacity, chunk,
                                              self.jobs),
                     self.capacity - transfer_cap)
