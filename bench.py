@@ -276,31 +276,21 @@ def bench_ramp_drain(inst: int):
 
 def bench_hbm_bytes(p, ub, inst, lbs):
     """Step-HBM bytes of the compiled search loop, one LOWER-IS-BETTER
-    row per bound, stamped with the ``fused`` mode channel (the
-    TTS_FUSED resolution it measured) so tools/perf_sentry.py never
-    judges a fused allocation profile against unfused history
-    (cross-mode = SKIP, the overlap/ladder/megabatch rule). This is
-    the fused-kernel arc's acceptance metric: the fused route keeps
-    the dense child grid, the (1, N) bound row, the prune mask and the
-    partition keys out of HBM entirely.
+    row per bound.
 
     Measurement: the compiled loop's XLA memory_analysis temp-buffer
     bytes on EVERY backend — deterministic, and exactly the per-step
-    HBM working set the fused kernels shrink. A live
+    HBM working set. A live
     ``peak_bytes_in_use`` delta was rejected: the peak is a lifetime
     high-water the warm run of the same executable already
     establishes, so a warm-vs-measured delta reads ~0 on exactly the
     TPU/GPU backends that report it — a lower-is-better row whose
     floor is its steady state can never FAIL. TTS_BENCH_HBM=0 skips.
-    The tile is pinned small (64) so the fused kernels' per-tile
-    store slack (J*tile) stays a sliver of the frame at the bench
-    chunk."""
+    The tile stays pinned at 64 so the row matches its history."""
     import jax.numpy as jnp
 
-    from tpu_tree_search.ops import pallas_fused
     from tpu_tree_search.utils import config as cfg
 
-    fused_mode = pallas_fused.resolve_mode(None)
     # an explicit TTS_BENCH_CHUNK is honored (the row must describe
     # the same compiled program the run's throughput rows measured);
     # only the DEFAULT stays 512 — analysis-only lowering at the
@@ -315,7 +305,7 @@ def bench_hbm_bytes(p, ub, inst, lbs):
         lowered = device._run.lower(
             tables, state, lb_kind, chunk,
             jnp.asarray(60, jnp.int64), jnp.asarray(1, jnp.int32),
-            tile=tile, fused=fused_mode)
+            tile=tile)
         value = lowered.compile().memory_analysis() \
             .temp_size_in_bytes
         how = "memory_analysis_temp"
@@ -327,11 +317,10 @@ def bench_hbm_bytes(p, ub, inst, lbs):
             "how": how,
             "chunk": chunk,
             "tile": tile,
-            "fused": int(fused_mode != "off"),
             **STAMP,
         }
         print(json.dumps(row))
-        print(f"# hbm_bytes lb={lb_kind} fused={fused_mode} "
+        print(f"# hbm_bytes lb={lb_kind} "
               f"how={how} bytes={int(value):,}", file=sys.stderr)
 
 
@@ -549,13 +538,6 @@ def main():
         from tpu_tree_search.tune import Autotuner
         tuner = Autotuner(cache_dir=cfg.env_str("TTS_TUNE_CACHE"))
 
-    # fused-route mode channel: stamped ONLY when the fused kernels are
-    # on (TTS_FUSED resolution), so unfused rows keep matching their
-    # modeless history — the same stamping rule as "tuned"
-    from tpu_tree_search.ops import pallas_fused
-    fused_mode = pallas_fused.resolve_mode(None)
-    fused_row = {"fused": 1} if fused_mode != "off" else {}
-
     for lb_kind in lbs:
         tuned_row = {}
         if tuner is not None:
@@ -603,7 +585,6 @@ def main():
             "baseline": BASELINE_LABEL,
             **STAMP,
             **tuned_row,
-            **fused_row,
         }
         # with TTS_SEARCH_TELEMETRY=1 the row also captures SEARCH
         # efficiency (pruning quality, frontier position, pool

@@ -150,7 +150,7 @@ def kernels_in_step(inst: int, lb: int, chunk: int, capacity: int) -> bool:
     lowered = device._run.lower(
         batched.make_tables(p), state, lb, chunk,
         jnp.asarray(ceiling, dtype=state.iters.dtype),
-        jnp.asarray(1, dtype=jnp.int32), tile=1024, fused="off")
+        jnp.asarray(1, dtype=jnp.int32), tile=1024)
     return KERNEL_MARK in lowered.compile().as_text()
 
 

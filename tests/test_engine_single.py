@@ -63,22 +63,28 @@ def test_max_iters_truncation():
     assert got.iters == 3
 
 
-def test_tile_partition_invariance():
+@pytest.mark.parametrize("chunk,tile", [
+    (64, 64),       # tile == chunk: one tile per step
+    (128, 64),      # multi-tile grid
+    (96, 64),       # tile-remainder chunk: one batch-wide tile
+    (64, 1024),     # requested tile above the chunk: the shrink path
+    (512, 256),
+    (512, 128),
+])
+@pytest.mark.parametrize("lb_kind", [1, 2])
+def test_tile_partition_invariance(lb_kind, chunk, tile):
     """The expand tile size changes only the internal child-column order;
-    with a fixed UB the explored set — and so tree/sol/best — must be
-    identical across tile choices (guards the step/expand column-order
-    contract when default_tile shrinks tiles for big instances)."""
-    from tpu_tree_search.problems.pfsp import PFSPInstance
-
+    with a fixed UB the explored set — and so tree/sol/best — must equal
+    the sequential oracle's for every tile rule (guards the step/expand
+    column-order contract when default_tile shrinks tiles for big
+    instances, and the LB2 routes' regather under each geometry)."""
     inst = PFSPInstance.synthetic(jobs=8, machines=4, seed=5)
     ub = inst.brute_force_optimum()   # fixed-point UB => order-independent
-    base = device.search(inst.p_times, lb_kind=1, init_ub=ub,
-                         chunk=512, capacity=1 << 12, tile=512)
-    for tile in (256, 128):
-        out = device.search(inst.p_times, lb_kind=1, init_ub=ub,
-                            chunk=512, capacity=1 << 12, tile=tile)
-        assert (out.explored_tree, out.explored_sol, out.best) == \
-               (base.explored_tree, base.explored_sol, base.best)
+    want = seq.pfsp_search(inst, lb=lb_kind, init_ub=ub)
+    out = device.search(inst.p_times, lb_kind=lb_kind, init_ub=ub,
+                        chunk=chunk, capacity=1 << 12, tile=tile)
+    assert (out.explored_tree, out.explored_sol, out.best) == \
+           (want.explored_tree, want.explored_sol, want.best)
 
 
 @pytest.mark.parametrize("inst,chunk", [(31, 256), (111, 64)])

@@ -12,6 +12,10 @@ The contracts, pinned on the 8-device virtual CPU mesh:
 - the live rung rides checkpoint meta (``ladder_rung``) and resume
   replays on the recorded rung, with totals exactly matching an
   uninterrupted run;
+- measured rung admission: a tuned entry's rung admission profile
+  (Params.rung_modes) admits a rung iff its probed ms/iter beats the
+  tuned top rung's, degrading to the static floors when the profile
+  does not cover the top rung;
 - rung pre-readies are PLANNED compiles: compile_storm's signal stays
   at zero across a full ladder boot (every rung warms from abstract
   shapes — which also pins the explicit shardings cross-rung state
@@ -30,7 +34,7 @@ from tpu_tree_search.engine import distributed
 from tpu_tree_search.engine.ladder import (LADDER_MIN_CHUNK,
                                            LADDER_MIN_CHUNK_LB2,
                                            RungController, min_rung_for,
-                                           rungs_for)
+                                           rungs_for, rungs_from_profile)
 from tpu_tree_search.obs import tracelog
 from tpu_tree_search.parallel.mesh import worker_mesh
 from tpu_tree_search.problems.pfsp import PFSPInstance
@@ -79,6 +83,38 @@ def test_rung_geometry():
     # width costs 220 ms/iter on the CPU mesh vs 15 at 256)
     assert min_rung_for(2) == LADDER_MIN_CHUNK_LB2
     assert min_rung_for(1) == min_rung_for(0) == LADDER_MIN_CHUNK
+
+
+def test_rungs_from_profile_measured_admission():
+    prof = ({"chunk": 2048, "ms_per_iter": 10.0, "evals_per_s": 1e5},
+            {"chunk": 512, "ms_per_iter": 4.0, "evals_per_s": 6e4},
+            {"chunk": 128, "ms_per_iter": 20.0, "evals_per_s": 3e3})
+    # 512 beats the top's ms/iter -> admitted; 128 is slower per
+    # iteration than the tuned chunk -> a pure loss, dropped (the
+    # static LB2>=256 floor, as per-shape data)
+    assert rungs_from_profile(2048, prof) == (512, 2048)
+    # no profile / top rung not covered: the caller falls back to the
+    # static floors
+    assert rungs_from_profile(2048, None) is None
+    assert rungs_from_profile(1024, prof) is None
+    # a top row without a measured rate is no profile either
+    assert rungs_from_profile(2048, ({"chunk": 2048},) + prof[1:]) \
+        is None
+    # malformed rows (a stale or hand-edited cache) degrade, never
+    # crash a boot
+    junk = ({"chunk": "x"}, {"no": 1}, None)
+    assert rungs_from_profile(2048, tuple(junk) + prof) == (512, 2048)
+
+
+def test_rung_profile_consistent_with_static_ladder():
+    # sanity: profile admission returns a subset of the candidate
+    # geometry rungs_for generates (plus always the top rung)
+    prof = tuple({"chunk": c, "ms_per_iter": 1.0 + (c == 2048) * 9.0,
+                  "evals_per_s": 1e5}
+                 for c in rungs_for(2048, min_chunk=1))
+    rungs = rungs_from_profile(2048, prof)
+    assert 2048 in rungs
+    assert set(rungs) <= set(rungs_for(2048, min_chunk=1))
 
 
 def test_controller_covering_policy_and_momentum():

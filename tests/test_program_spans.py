@@ -153,19 +153,18 @@ def test_compile_listener_fills_the_innermost_span(log):
     assert not any(k in outer for k in tracelog.COMPILE_EVENTS.values())
 
 
-@pytest.mark.parametrize("inst,lb_kind,fused", [
-    (21, 1, "off"),         # LB1: bound, prune, compact, push
-    (21, 2, "off"),         # LB2 two-phase: prefilter and pair sweeps
-    (1, 2, "off"),          # LB2 dense route (few pairs)
-    (21, 2, "interpret"),   # the fused route and its spill branch
+@pytest.mark.parametrize("inst,lb_kind", [
+    (21, 1),         # LB1: bound, prune, compact, push
+    (21, 2),         # LB2 two-phase: prefilter and pair sweeps
+    (1, 2),          # LB2 dense route (few pairs)
 ])
-def test_lowered_loop_names_the_step_phases(inst, lb_kind, fused):
+def test_lowered_loop_names_the_step_phases(inst, lb_kind):
     p = taillard.processing_times(inst)
     tables = batched.make_tables(p)
     state = device.init_state(p.shape[1], 1 << 12, None, p_times=p)
     text = device._run.lower(
-        tables, state, lb_kind, 8, np.int32(4), np.int32(1),
-        fused=fused).as_text(debug_info=True)
+        tables, state, lb_kind, 8, np.int32(4),
+        np.int32(1)).as_text(debug_info=True)
     missing = [ph for ph in PHASES if f"/{ph}/" not in text]
     assert not missing, missing
 

@@ -20,7 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from tpu_tree_search.ops import batched, pallas_expand, pallas_fused
+from tpu_tree_search.ops import batched, pallas_expand
 from tpu_tree_search.problems import taillard
 from tpu_tree_search.utils import compile_cache
 
@@ -96,18 +96,6 @@ def test_lb2_pair_sweep_compiles(one_chip, inst):
                           _sds(one_chip, (jobs, width), jnp.bfloat16),
                           tile=tile)
     assert "tpu_custom_call" in text
-
-
-def test_fused_kernel_is_refused_by_mosaic(one_chip):
-    # why pallas_fused.resolve_mode raises for the hardware route: the
-    # in-kernel compaction sort has no Mosaic lowering. When this test
-    # fails, the kernel compiles and ROADMAP A2 can measure it.
-    args = (_tables(one_chip, 21), *_parents(one_chip, 20, 20, 2048),
-            _sds(one_chip, (), jnp.int32), _sds(one_chip, (), jnp.int32))
-    with jax.enable_x64(False), \
-            pytest.raises(NotImplementedError, match="sort"):
-        _compiled_text(pallas_fused.fused_expand, *args, tile=1024,
-                       cap_width=4096)
 
 
 def test_tile64_expand_is_refused_and_not_admitted(one_chip):

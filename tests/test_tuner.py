@@ -265,10 +265,7 @@ def test_search_consumes_tuned_entry(tmp_path):
 
 
 def test_tuning_cache_key_is_stable(monkeypatch):
-    from tpu_tree_search.ops import pallas_fused
-    monkeypatch.delenv(pallas_fused.FUSED_FLAG, raising=False)
-    monkeypatch.delenv(pallas_fused.FUSED_INTERPRET_FLAG,
-                       raising=False)
+    monkeypatch.delenv("TTS_TUNE_RUNGS", raising=False)
     k1 = Autotuner.key(20, 10, 1, 8)
     assert k1 == ("pfsp", 20, 10, 1, 8)
     c = TuningCache.__new__(TuningCache)   # path_for only needs root
@@ -276,19 +273,16 @@ def test_tuning_cache_key_is_stable(monkeypatch):
     c.root = pathlib.Path("/x")
     assert c.path_for(k1) == c.path_for(("pfsp", 20, 10, 1, 8))
     assert c.path_for(k1) != c.path_for(("pfsp", 20, 10, 2, 8))
-    # a fused boot keys its own entry (the sweep picks its chunk on
-    # the boot pipeline's rates — a matmul boot must never replay a
-    # fused-probed optimum, or vice versa); unfused keys keep their
-    # exact pre-fused identity
-    monkeypatch.setenv(pallas_fused.FUSED_FLAG, "1")
-    monkeypatch.setenv(pallas_fused.FUSED_INTERPRET_FLAG, "1")
-    assert Autotuner.key(20, 10, 1, 8) \
-        == ("pfsp", 20, 10, 1, 8, "fused", "interpret")
-    # a problem WITHOUT a fused pipeline (supports_fused False)
-    # measures identical rates either way — its key never splits on
-    # the env, so one optimum serves both boot modes
+    # the key never splits on an env: a boot that also gathers the
+    # rung admission profile replays (and refreshes) the same entry
+    monkeypatch.setenv("TTS_TUNE_RUNGS", "1")
+    assert Autotuner.key(20, 10, 1, 8) == k1
     assert Autotuner.key(6, 6, 1, 8, problem="tsp") \
         == ("tsp", 6, 6, 1, 8)
+    # only a megabatched dispatch appends a suffix
+    assert Autotuner.key(20, 10, 1, 8, batch=1) == k1
+    assert Autotuner.key(20, 10, 1, 8, batch=4) \
+        == ("pfsp", 20, 10, 1, 8, "batch", 4)
 
 
 # --------------------------------------------------------------- report
@@ -420,20 +414,15 @@ def test_resolve_probes_tsp_with_table_and_persists(tmp_path):
     assert t2.probes_run == 0
 
 
-# ------------------------------------------- per-rung profitability
+# ------------------------------------------- rung admission profile
 
 
 def test_tune_emits_rung_profile_and_cache_roundtrip(tmp_path,
                                                      monkeypatch):
-    # the winner's ladder rungs are probed too (below the static rung
-    # floor — measured admission subsumes it) and the mask persists
-    # with the entry; with TTS_FUSED off every rung's winner is the
-    # matmul pipeline and the fused rate column stays unmeasured.
-    # TTS_TUNE_RUNGS opts the matmul-only boot in (without it — or
-    # the fused route — rung probes are skipped: extra compiles with
-    # no pipeline choice to record)
-    monkeypatch.delenv("TTS_FUSED", raising=False)
-    monkeypatch.delenv("TTS_FUSED_INTERPRET", raising=False)
+    # under TTS_TUNE_RUNGS the winner's ladder rungs are probed too
+    # (below the static rung floor — measured admission subsumes it)
+    # and the profile persists with the entry: one row per rung with
+    # its measured rates
     monkeypatch.setenv("TTS_TUNE_RUNGS", "1")
     p = small()
     t1 = Autotuner(cache_dir=tmp_path / "tune", **TUNE_KW)
@@ -443,49 +432,24 @@ def test_tune_emits_rung_profile_and_cache_roundtrip(tmp_path,
     chunks = [r["chunk"] for r in params.rung_modes]
     assert params.chunk in chunks
     for row in params.rung_modes:
-        assert row["winner"] == "unfused"
+        assert set(row) == {"chunk", "ms_per_iter", "evals_per_s"}
         assert row["ms_per_iter"] > 0
-        assert row["evals_per_s_fused"] is None
-        assert row["evals_per_s_unfused"] > 0
+        assert row["evals_per_s"] > 0
     t2 = Autotuner(cache_dir=tmp_path / "tune", **TUNE_KW)
     p2 = t2.resolve(8, 3, 1, allow_probe=False)
     assert p2.source == "cache"
     assert tuple(p2.rung_modes) == tuple(params.rung_modes)
 
 
-@pytest.mark.slow  # both-pipeline interpret probes; runs in the CI fused leg
-def test_tune_rung_profile_measures_fused_pipeline(tmp_path,
-                                                   monkeypatch):
-    # with the fused route resolvable (interpret on the CPU mesh —
-    # the CI fused leg's environment), every rung is probed once per
-    # PIPELINE on identical warmed state and the mask records both
-    # rates; the winner is whichever measured faster, and the solve
-    # counts cannot differ between them (bit-parity), so either
-    # verdict is valid — what must hold is that the fused column was
-    # actually MEASURED
-    monkeypatch.setenv("TTS_FUSED", "1")
-    monkeypatch.setenv("TTS_FUSED_INTERPRET", "1")
-    t = Autotuner(cache_dir=tmp_path / "tune", **TUNE_KW)
-    params = t.resolve(8, 3, 1, allow_probe=True, p_times=small())
-    assert params.rung_modes
-    for row in params.rung_modes:
-        assert row["winner"] in ("fused", "unfused")
-        assert row["evals_per_s_unfused"] > 0
-        assert row["evals_per_s_fused"] is not None
-        assert row["evals_per_s_fused"] > 0
-
-
 def test_rung_probes_skipped_without_pipeline_choice(tmp_path,
                                                      monkeypatch):
-    # default boot (fused off, no TTS_TUNE_RUNGS): no rung probes run
-    # — each is an extra compile with no kernel-vs-matmul choice to
-    # record — and the entry persists without a mask (ladder admission
-    # falls back to the static floors, the pre-mask behavior)
-    monkeypatch.delenv("TTS_FUSED", raising=False)
+    # default boot (no TTS_TUNE_RUNGS): no rung probes run — each is
+    # an extra compile — and the entry persists without a profile
+    # (ladder admission falls back to the static floors)
     monkeypatch.delenv("TTS_TUNE_RUNGS", raising=False)
     t = Autotuner(cache_dir=tmp_path / "tune", **TUNE_KW)
     params = t.resolve(8, 3, 1, allow_probe=True, p_times=small())
     assert params.source == "probe"
     assert params.rung_modes is None
-    probed = {(r["chunk"], r.get("fused")) for r in t.ledger}
-    assert all(c in TUNE_KW["chunks"] for c, _ in probed)
+    probed = {r["chunk"] for r in t.ledger}
+    assert all(c in TUNE_KW["chunks"] for c in probed)

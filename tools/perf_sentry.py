@@ -103,12 +103,6 @@ def row_mode(row: dict):
         # race ratio must never be judged against a differently-sized
         # race's history — cross-width rows SKIP, never FAIL
         return ("portfolio", row["portfolio"])
-    if row.get("fused") is not None:
-        # the fused Pallas bound+prune+compact route (TTS_FUSED,
-        # ops/pallas_fused): a fused step's allocation profile or rate
-        # must never be judged against unfused history — the hbm_bytes
-        # family exists precisely to show the two DIFFER
-        return ("fused", row["fused"])
     if row.get("tuned") is not None:
         return ("tuned", row["tuned"])
     return None

@@ -38,7 +38,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..obs import tracelog
-from ..ops import batched, pallas_expand, pallas_fused, reference as ref
+from ..ops import batched, pallas_expand, reference as ref
 from ..ops.batched import BoundTables
 from ..utils import config as _cfg
 from . import telemetry as tele
@@ -228,10 +228,8 @@ def _col_major(x, G: int, J: int, TB: int):
 
 def _child_masks(p_depth, valid, G: int, J: int, TB: int):
     """The (1, N) child-slot mask family in the expand kernel's column
-    order — ONE construction shared by step()'s dense routes and the
-    fused spill branch, so the two can never drift (the spill cond's
-    bit-parity with the kernel path depends on it). Returns (depth_c,
-    mask); leaves are ``(depth_c + 1) == J`` within mask."""
+    order. Returns (depth_c, mask); leaves are ``(depth_c + 1) == J``
+    within mask."""
     depth_c = _col_major(p_depth, G, J, TB)
     valid_c = _col_major(valid[None, :], G, J, TB)
     slot_c = jnp.broadcast_to(
@@ -624,15 +622,10 @@ def _lb2_tail(tables: BoundTables, state: SearchState, children, caux,
     """Everything after the LB1 prune of the two-phase LB2 route, in
     W_-wide frames: the strong-pair head sweep, the mid prune+compact,
     the tail sweep, the final prune+compact and the pool block write.
-    Extracted to module level so the UNFUSED prefilter branches (which
-    regather survivors from their parents) and the FUSED route (whose
-    kernel emits the compacted survivor block directly,
-    ops/pallas_fused) run the exact same ops on the compacted block —
-    the two can never drift. Inputs: children (J, W_) i16, caux
-    (M+1, W_) i32, sched (SW, W_) i32, `ncand` live survivors in the
-    leading columns (the rest unread garbage — the scratch-margin
-    contract covers the pool write). Returns
-    (prmu, depth, aux, n_push, hsum, tsum[, tele_tail])."""
+    Inputs: children (J, W_) i16, caux (M+1, W_) i32, sched (SW, W_)
+    i32, `ncand` live survivors in the leading columns (the rest unread
+    garbage — the scratch-margin contract covers the pool write).
+    Returns (prmu, depth, aux, n_push, hsum, tsum[, tele_tail])."""
     J = children.shape[0]
     M = tables.p.shape[0]
     P = int(tables.ma0.shape[0])
@@ -756,235 +749,9 @@ def _lb2_tail(tables: BoundTables, state: SearchState, children, caux,
     return out
 
 
-def _leaf_scan(tables: BoundTables, p_prmu, p_depth, p_aux, valid):
-    """Parent-level leaf/eval statistics of one popped chunk — the
-    dense-grid quantities the unfused routes read off the (1, N) child
-    masks, computed in O(M*B) without materializing them (the fused
-    route's whole point is that the dense grid never exists in HBM).
-
-    A parent at depth J-1 has exactly ONE valid child (slot J-1), a
-    complete schedule; its LB1 as the kernels compute it is the chain
-    max_k(tmp_k + min_tails[k]) with every child-remain term zero —
-    replicated here term for term so `leaf_best` is bit-identical to
-    the dense route's masked min over leaf columns. Parents below J-1
-    contribute J - depth evaluated (all non-leaf) children; a parent
-    at J-1 contributes its one leaf. Returns
-    (leaf_best i32, n_leaf i64, evals i64)."""
-    J, B = p_prmu.shape
-    M = p_aux.shape[0]
-    d = p_depth.reshape(-1)                        # (B,) i32
-    leafp = (d == J - 1) & valid
-    # the lone unscheduled job of a depth-(J-1) parent sits at
-    # position J-1; its processing column via the J-step select
-    # (_regather's gather-free idiom)
-    a = p_prmu[J - 1:J, :].astype(jnp.int32)       # (1, B)
-    cp = jnp.zeros((M, B), jnp.int32)
-    for j in range(J):
-        cp = jnp.where(a == j, tables.p[:, j:j + 1], cp)
-    cf = p_aux[0:1] + cp[0:1]
-    tmp = cf
-    lb = tmp + tables.min_tails[0]
-    for k in range(1, M):
-        cf = jnp.maximum(cf, p_aux[k:k + 1]) + cp[k:k + 1]
-        tmp = jnp.maximum(tmp, cf)
-        lb = jnp.maximum(lb, tmp + tables.min_tails[k])
-    leaf_best = jnp.where(leafp, lb.reshape(-1), I32_MAX).min()
-    n_leaf = leafp.sum(dtype=jnp.int64)
-    evals = jnp.where(valid, (J - d).astype(jnp.int64), 0).sum()
-    return leaf_best, n_leaf, evals
-
-
-def _fused_step(tables: BoundTables, lb_kind: int, route, chunk: int,
-                TB: int, state: SearchState, p_prmu, p_depth, p_aux,
-                n, start, valid, limit, mode: str) -> SearchState:
-    """The fused bound+prune+compact route (ops/pallas_fused): the
-    dense child grid, its (1, N) bound row, the (N,) prune mask and
-    the (N,) partition keys never exist in HBM. The kernel emits the
-    compacted survivors (capped at the steady W = N/4 frame) plus a
-    count; leaves and eval totals come from the parent-level O(M*B)
-    scan (_leaf_scan); a rare survivor-overflow step (count > W) takes
-    the unfused pipeline via ONE lax.cond on bit-identical bound math,
-    so the explored set cannot depend on which branch ran. For LB2 the
-    kernel is the fused LB1 prefilter (also emitting the survivors'
-    scheduled-set bitmask) and the shared _lb2_tail runs the pair
-    sweeps over the compacted block — op-identical to the unfused
-    two-phase route. Telemetry: popped/evaluated buckets are
-    parent-level, branched buckets and the surviving-bound histogram
-    come off the compacted block, and the PRUNED-bound histogram is
-    the kernel's per-tile masked-add output — bound_hist_exact holds
-    without the pruned bounds ever touching HBM."""
-    J, capacity = state.prmu.shape
-    M = tables.p.shape[0]
-    B = chunk
-    G = B // TB
-    N = B * J
-    TELE = state.telemetry.shape[-1] > 0
-
-    with jax.named_scope("prune"):
-        leaf_best, n_leaf, evals_cnt = _leaf_scan(tables, p_prmu, p_depth,
-                                                  p_aux, valid)
-        best = jnp.minimum(state.best, leaf_best)
-        sol = state.sol + n_leaf
-    if TELE:
-        d = p_depth.reshape(-1)
-        wb = tele.depth_bucket(d, J)
-        popped_b = tele.bucket_counts(wb, valid)
-        # evaluated non-leaf children bucket by PARENT depth: J - d of
-        # them per valid parent below J-1, none at J-1 (its one child
-        # is the leaf) — the dense route's bucket_counts(child_b,
-        # mask & ~leaf) collapsed to parent-level weighted sums
-        w = jnp.where(valid & (d < J - 1), (J - d).astype(jnp.int64), 0)
-        evalnl_b = jnp.stack([jnp.sum(jnp.where(wb == k, w, 0))
-                              for k in range(tele.DEPTH_BUCKETS)])
-
-    # Survivor-cap width: the LB2 route caps at the steady N/4 frame
-    # (matching the unfused tail's steady branch; the rare overflow
-    # takes the spill cond below). The LB1 route runs uncapped — its
-    # unfused pipeline block-writes a full-N frame anyway, so a narrow
-    # cap would buy no frame bytes while costing a whole duplicated
-    # spill pipeline in the compiled program (MEASURED: capping LB1 at
-    # N/4 was a net LOSS, -8% vs +17% step-temp — the spill branch's
-    # dense pipeline and the kernel outputs are live across the cond
-    # boundary, so buffer assignment cannot overlay them).
-    if lb_kind == 2:
-        W = max(N // 4, 128)
-        narrow = W < N
-        if not narrow:
-            W = N
-    else:
-        W = N
-        narrow = False
-    # survivors-only frames as narrow as their consumers allow: the
-    # bound row only feeds the LB1 telemetry histogram (the LB2 tail
-    # re-bounds survivors with the pair sweeps), and the LB1 caux
-    # block can ride the pool's own narrow aux dtype — every output
-    # byte of the kernel is the fused route's whole HBM footprint
-    with jax.named_scope("bound"):
-        kch, kaux, kbnd, ksched, n_surv, khist = pallas_fused.fused_expand(
-            tables, p_prmu, p_depth, p_aux, n, best, lb_kind=1, tile=TB,
-            cap_width=W, with_sched=(route == "prefilter"),
-            tele_bins=tele.BOUND_BINS if TELE else 0,
-            with_bounds=(lb_kind != 2 and TELE),
-            aux_i16=(lb_kind != 2 and state.aux.dtype == jnp.int16),
-            interpret=(mode == "interpret"))
-    if limit is None:
-        limit = row_limit(capacity, B, J)
-
-    def dense_masks():
-        """The unfused routes' mask family (_child_masks — the same
-        ops step() traces) — built ONLY inside the rare spill
-        branches."""
-        depth_c, mask = _child_masks(p_depth, valid, G, J, TB)
-        is_leaf = ((depth_c + 1) == J) & mask
-        return depth_c, mask, is_leaf
-
-    def narrow_to_W(a, rows):
-        """The kernel block at frame width W. The kernel's frame is
-        always WPAD = W + store_sub(J*tile): the count-gated tail
-        stores carry one sub-block of slack past the survivor cap, so
-        every fused step pays this slice — a copy of each output at
-        width W. That cost is priced in (the measured HBM wins
-        include it); store_sub exists precisely to keep the slack —
-        and therefore this copy's source frame — one ~N/8 sub-block
-        instead of a whole tile. Clamping the kernel's final stores
-        to land the frame at exactly W would retire the copy; that is
-        hardware-round work (the cursor stores are being relowered
-        through Mosaic anyway, ROADMAP item 4)."""
-        if a.shape[1] == W:
-            return a
-        return jax.lax.slice(a, (0, 0), (rows, W))
-
-    if lb_kind != 2:
-        def fused_fit(_):
-            with jax.named_scope("push"):
-                children = narrow_to_W(kch, J)
-                caux = narrow_to_W(kaux, M + 1)
-                child_depth = caux[M].astype(jnp.int16)
-                prmu, depth, aux = _write_block(
-                    state, children, child_depth, caux, start, n_surv,
-                    limit)
-            out = (prmu, depth, aux, n_surv)
-            if TELE:
-                bnd = narrow_to_W(kbnd, 1)
-                livem = jnp.arange(W) < n_surv
-                pb = tele.depth_bucket(
-                    caux[M].astype(jnp.int32).reshape(-1) - 1, J)
-                out += (jnp.concatenate(
-                    [tele.bucket_counts(pb, livem),
-                     tele.bound_hist(bnd, livem, best)]),)
-            return out
-
-        # LB1 runs uncapped (W == N, see the cap comment above):
-        # n_surv can never exceed the frame, so there is no spill
-        # branch to trace — only the LB2 route carries one
-        outs = fused_fit(0)
-        prmu, depth, aux, n_push = outs[:4]
-        delta = None
-        if TELE:
-            DB = tele.DEPTH_BUCKETS
-            bh = outs[4]
-            delta = tele.step_delta(popped_b, bh[:DB],
-                                    evalnl_b - bh[:DB],
-                                    khist, bh[DB:])
-        with jax.named_scope("push"):
-            return _commit(state, prmu, depth, aux, n_push, best, sol,
-                           jnp.asarray(evals_cnt), limit, start,
-                           tele_delta=delta)
-
-    # --- route == "prefilter": the kernel was the fused LB1 prefilter
-    P = int(tables.ma0.shape[0])
-    KH = batched.PAIR_PREFILTER
-    SW = pallas_expand.sched_words(J)
-    debug_tap = bool(__debug__ and P > KH and _DEBUG_STEP)
-    ncand = n_surv
-
-    def fused_tail(_):
-        with jax.named_scope("compact"):
-            children = narrow_to_W(kch, J)
-            caux = narrow_to_W(kaux, M + 1)
-            sched = narrow_to_W(ksched, SW)
-        return _lb2_tail(tables, state, children, caux, sched, ncand,
-                         W, N, best, start, limit, debug_tap, TELE)
-
-    def spill_tail(_):
-        with jax.named_scope("bound"):
-            lb1b = pallas_expand.expand_bounds(
-                tables, p_prmu, p_depth, p_aux, lb_kind=1, tile=TB)
-        with jax.named_scope("prune"):
-            _, mask, is_leaf = dense_masks()
-            cand = (mask & ~is_leaf & (lb1b < best)).reshape(-1)
-        with jax.named_scope("compact"):
-            perm1 = _partition(cand)
-            children, caux, sched = _compact_from_parents(
-                tables, p_prmu, p_depth, p_aux, perm1, ncand, TB, N,
-                with_sched=True, two_phase=True, cap=N)
-        return _lb2_tail(tables, state, children, caux, sched, ncand,
-                         N, N, best, start, limit, debug_tap, TELE)
-
-    if narrow:
-        outs = jax.lax.cond(ncand <= W, fused_tail, spill_tail, 0)
-    else:
-        outs = fused_tail(0)
-    prmu, depth, aux, n_push, hsum, tsum = outs[:6]
-    if debug_tap:
-        state = state._replace(sent=hsum, recv=tsum,
-                               steals=n_push.astype(jnp.int64))
-    delta = None
-    if TELE:
-        DB, BB = tele.DEPTH_BUCKETS, tele.BOUND_BINS
-        branched_b = outs[6][:DB]
-        delta = tele.step_delta(
-            popped_b, branched_b, evalnl_b - branched_b,
-            khist + outs[6][DB:DB + BB], outs[6][DB + BB:])
-    with jax.named_scope("push"):
-        return _commit(state, prmu, depth, aux, n_push, best, sol,
-                       jnp.asarray(evals_cnt), limit, start,
-                       tele_delta=delta)
-
-
 def step(tables: BoundTables, lb_kind: int, chunk: int,
          state: SearchState, tile: int = 1024,
-         limit: int | None = None, fused: str = "off") -> SearchState:
+         limit: int | None = None) -> SearchState:
     """One pop->bound->prune->branch cycle (the compiled analogue of the
     reference per-thread hot loop, pfsp_multigpu_cuda.c:221-320).
 
@@ -1025,20 +792,7 @@ def step(tables: BoundTables, lb_kind: int, chunk: int,
         # cast back happens at the write below.
         p_aux = p_aux.astype(jnp.int32)
 
-    # --- fused bound+prune+compact route (ops/pallas_fused): STATIC
-    # gate — `fused` is a static argument threaded from the host-side
-    # mode resolution (never an env read at trace time); fused_ok
-    # admits only the interpreter route (no hardware route lowers
-    # yet). LB2's dense (few-pair) route and LB1_d stay unfused.
-    if (fused != "off"
-            and pallas_fused.fused_ok(fused, lb_kind)
-            and (lb_kind == 1 or route == "prefilter")):
-        return _fused_step(tables, lb_kind, route, B, TB, state,
-                           p_prmu, p_depth, p_aux, n, start, valid,
-                           limit, fused)
-
-    # --- masks in the kernel's child-slot column order (shared with
-    # the fused spill branches — _child_masks)
+    # --- masks in the kernel's child-slot column order
     with jax.named_scope("bound"):
         depth_c, mask = _child_masks(p_depth, valid, G, J, TB)  # (1, N)
 
@@ -1146,8 +900,7 @@ def step(tables: BoundTables, lb_kind: int, chunk: int,
 
         def tail_pipeline(W_):
             """Everything after the LB1 prune, in W_-wide frames
-            (_lb2_tail — shared with the fused route so the two cannot
-            drift).
+            (_lb2_tail).
 
             Run twice as the two branches of ONE lax.cond: the steady
             branch at W_ = N//4 (taken whenever ncand fits, ~93% of
@@ -1266,30 +1019,25 @@ def step(tables: BoundTables, lb_kind: int, chunk: int,
                        limit, start, tele_delta=delta)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("lb_kind", "chunk", "tile", "fused"))
+@functools.partial(jax.jit, static_argnames=("lb_kind", "chunk", "tile"))
 def _run(tables: BoundTables, state: SearchState, lb_kind: int, chunk: int,
          max_iters: jax.Array, drain_min: jax.Array,
-         tile: int = 1024, fused: str = "off") -> SearchState:
+         tile: int = 1024) -> SearchState:
     def cond(s: SearchState):
         return (s.size >= drain_min) & ~s.overflow & (s.iters < max_iters)
 
-    body = functools.partial(step, tables, lb_kind, chunk, tile=tile,
-                             fused=fused)
+    body = functools.partial(step, tables, lb_kind, chunk, tile=tile)
     return jax.lax.while_loop(cond, lambda s: body(state=s), state)
 
 
 def run(tables: BoundTables, state: SearchState, lb_kind: int, chunk: int,
         max_iters: int | None = None, tile: int = 1024,
-        drain_min: int = 1, fused=None) -> SearchState:
+        drain_min: int = 1) -> SearchState:
     """Run the search to exhaustion (or up to a cumulative `max_iters`) in
     one compiled loop (the analogue of pfsp_c.c:55-63's while(1)
     pop+decompose). `max_iters` is a traced scalar, NOT a static argument:
     segmented drivers pass a new ceiling every segment and must hit the
-    compile cache. `fused` (None = the TTS_FUSED env resolution,
-    ops/pallas_fused.resolve_mode) is resolved HERE, host-side, and rides
-    the jit key as a static mode string — flipping the knob retraces
-    instead of reusing a stale executable."""
+    compile cache."""
     jobs, capacity = state.prmu.shape[-2:]
     if int(np.asarray(state.size).max()) > row_limit(capacity, chunk, jobs):
         # Pool already fuller than the usable limit (e.g. capacity < the
@@ -1300,8 +1048,7 @@ def run(tables: BoundTables, state: SearchState, lb_kind: int, chunk: int,
                else max_iters)
     return _run(tables, state, lb_kind, chunk,
                 jnp.asarray(ceiling, dtype=state.iters.dtype),
-                jnp.asarray(max(drain_min, 1), dtype=jnp.int32), tile=tile,
-                fused=pallas_fused.resolve_mode(fused))
+                jnp.asarray(max(drain_min, 1), dtype=jnp.int32), tile=tile)
 
 
 def generic_step(problem, tables, lb_kind: int, chunk: int,
@@ -1423,29 +1170,24 @@ def generic_step(problem, tables, lb_kind: int, chunk: int,
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("problem", "lb_kind", "chunk", "tile",
-                                    "fused"))
+                   static_argnames=("problem", "lb_kind", "chunk", "tile"))
 def _run_problem(tables, state: SearchState, problem, lb_kind: int,
                  chunk: int, max_iters: jax.Array, drain_min: jax.Array,
-                 tile: int = 1024, fused: str = "off") -> SearchState:
+                 tile: int = 1024) -> SearchState:
     def cond(s: SearchState):
         return (s.size >= drain_min) & ~s.overflow & (s.iters < max_iters)
 
-    body = problem.make_step(tables, lb_kind, chunk, tile, None,
-                             fused=fused)
+    body = problem.make_step(tables, lb_kind, chunk, tile, None)
     return jax.lax.while_loop(cond, lambda s: body(s), state)
 
 
 def run_problem(problem, tables, state: SearchState, lb_kind: int,
                 chunk: int, max_iters: int | None = None,
-                tile: int = 1024, drain_min: int = 1,
-                fused=None) -> SearchState:
+                tile: int = 1024, drain_min: int = 1) -> SearchState:
     """Problem-generic `run`: the plugin's step (fast-path hook or
     generic_step) to exhaustion in one compiled loop. `max_iters` is a
     traced scalar like run()'s — segmented drivers hit the compile
-    cache across ceilings. `fused` resolves like run()'s (host-side,
-    static on the jit key); plugins without a fused fast path ignore
-    it."""
+    cache across ceilings."""
     jobs, capacity = state.prmu.shape[-2:]
     if int(np.asarray(state.size).max()) > \
             problem.usable_rows(capacity, chunk, jobs):
@@ -1459,7 +1201,7 @@ def run_problem(problem, tables, state: SearchState, lb_kind: int,
     return _run_problem(tables, state, problem, lb_kind, chunk,
                         jnp.asarray(ceiling, dtype=state.iters.dtype),
                         jnp.asarray(max(drain_min, 1), dtype=jnp.int32),
-                        tile=tile, fused=pallas_fused.resolve_mode(fused))
+                        tile=tile)
 
 
 def solve(problem, table: np.ndarray, lb_kind: int | None = None,

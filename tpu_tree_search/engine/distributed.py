@@ -39,7 +39,6 @@ from jax.sharding import PartitionSpec as P
 
 from ..obs import audit as obs_audit
 from ..obs import tracelog
-from ..ops import pallas_fused
 from ..ops import reference as ref
 from ..ops.batched import BoundTables
 from ..parallel import balance as bal
@@ -808,7 +807,7 @@ def _resolve_problem(problem):
 def _problem_driver(problem, mesh, tables, table, lb_kind: int,
                     chunk: int, balance_period: int, transfer_cap: int,
                     min_transfer: int, adt, loop_cache,
-                    limit_fn=None, fused: str = "off") -> "_DistDriver":
+                    limit_fn=None) -> "_DistDriver":
     """ONE construction shared by the serving path (search) and the
     boot pre-warm (prewarm), for ANY registered problem: the loop key
     and every trace-specializing knob come from here, so a pre-warmed
@@ -824,19 +823,10 @@ def _problem_driver(problem, mesh, tables, table, lb_kind: int,
     chunk-ladder passes the unified across-rung limit; None = this
     chunk's own row_limit)."""
     jobs = problem.slots(table)
-    if not getattr(problem, "supports_fused", False):
-        # a problem whose make_step IGNORES the mode must not key two
-        # program-identical executables apart (or invalidate its warm
-        # AOT entries when the knob flips between boots)
-        fused = "off"
 
     def make_local_step(t, limit):
-        return problem.make_step(t, lb_kind, chunk, 1024, limit,
-                                 fused=fused)
+        return problem.make_step(t, lb_kind, chunk, 1024, limit)
 
-    # the fused mode joins the key only when ON, so every persisted
-    # AOT/executor entry of the unfused route keeps its exact pre-fused
-    # identity (the same suffix discipline as the megabatch batch dim)
     return _DistDriver(
         mesh, tables, make_local_step, balance_period, transfer_cap,
         min_transfer,
@@ -844,15 +834,13 @@ def _problem_driver(problem, mesh, tables, table, lb_kind: int,
                                                               jobs)),
         loop_cache=loop_cache,
         loop_key=(problem.name, jobs, int(np.asarray(table).shape[0]),
-                  lb_kind, chunk, str(adt))
-        + (("fused", fused) if fused != "off" else ()))
+                  lb_kind, chunk, str(adt)))
 
 
 def _ladder_plan(problem, mesh, tables, table, lb_kind: int, chunk: int,
                  balance_period: int, transfer_cap: int | None,
                  min_transfer: int | None, min_seed: int, adt, loop_cache,
-                 rung_profile=None, fused_mode: str = "off"
-                 ) -> tuple[tuple, dict]:
+                 rung_profile=None) -> tuple[tuple, dict]:
     """One _DistDriver per chunk-ladder rung (engine/ladder.rungs_for),
     all built against a UNIFIED usable-row limit: the minimum over
     rungs of each rung's own scratch-margin + balance-headroom bound.
@@ -872,19 +860,15 @@ def _ladder_plan(problem, mesh, tables, table, lb_kind: int, chunk: int,
     is key-identical to the one a ladder search builds.
 
     `rung_profile` (tune/defaults Params.rung_modes — the tuner's
-    per-rung probe results) replaces the STATIC per-bound rung floor
+    rung admission profile) replaces the STATIC per-bound rung floor
     with measured admission (ladder.rungs_from_profile: a rung joins
     only when its probed ms/iter beats the tuned chunk's — subsuming
-    the PR-9 LB2>=256 constant for probed shapes) and selects each
-    rung's kernel-vs-matmul pipeline (ladder.fused_for) under the
-    `fused_mode` master switch."""
-    from .ladder import (fused_for, min_rung_for, rungs_for,
-                         rungs_from_profile)
+    the PR-9 LB2>=256 constant for probed shapes)."""
+    from .ladder import min_rung_for, rungs_for, rungs_from_profile
 
     jobs, aux_rows = problem.slots(table), problem.aux_rows(table)
     n_dev = mesh.devices.size
-    rungs = rungs_from_profile(chunk, rung_profile,
-                               fused_mode=fused_mode)
+    rungs = rungs_from_profile(chunk, rung_profile)
     if rungs is None:
         rungs = rungs_for(chunk, min_chunk=min_rung_for(lb_kind))
     cfgs = []
@@ -903,8 +887,7 @@ def _ladder_plan(problem, mesh, tables, table, lb_kind: int, chunk: int,
     drivers = {
         c: _problem_driver(problem, mesh, tables, table, lb_kind, c,
                            balance_period, tc, mt, adt, loop_cache,
-                           limit_fn=unified_limit,
-                           fused=fused_for(c, rung_profile, fused_mode))
+                           limit_fn=unified_limit)
         for c, tc, mt in cfgs}
     return tuple(sorted(drivers)), drivers
 
@@ -951,36 +934,28 @@ def prewarm(p_times: np.ndarray, lb_kind: int = 1, chunk: int = 64,
     adt = prob.aux_dtype(table)
     if ladder is None:
         ladder = _cfg.env_flag(_cfg.LADDER_FLAG)
-    # the fused-route mode joins the executable key (_problem_driver),
-    # so the warm must resolve it exactly as a real request would —
-    # warming the unfused key under TTS_FUSED=1 would be pure waste.
-    # `rung_profile` (the tuned entry's rung_modes mask, when the
-    # caller resolved one) must ride along for the same reason: a
-    # profile changes both the rung SET (rungs_from_profile) and each
-    # rung's fused suffix (fused_for), so warming without it would
-    # build keys a tuned dispatch never asks for.
-    fused_mode = pallas_fused.resolve_mode(None)
+    # `rung_profile` (the tuned entry's rung_modes profile, when the
+    # caller resolved one) must ride along: it changes the rung SET
+    # (rungs_from_profile), so warming without it would build keys a
+    # tuned dispatch never asks for.
     drivers = None
     if ladder:
         rungs, drivers = _ladder_plan(
             prob, mesh, tables, table, lb_kind, chunk, balance_period,
             transfer_cap, min_transfer, min_seed, adt, loop_cache,
-            rung_profile=rung_profile, fused_mode=fused_mode)
+            rung_profile=rung_profile)
         if len(rungs) < 2:
             drivers = None             # single rung: plain path
     if drivers is not None:
         driver = drivers[max(drivers)]
     else:
-        from .ladder import fused_for
         tc, mt = balance_defaults(chunk, jobs, aux_rows, mesh.devices.size,
                                   min_seed, aux_itemsize=adt.itemsize)
         transfer_cap = tc if transfer_cap is None else transfer_cap
         min_transfer = mt if min_transfer is None else min_transfer
         driver = _problem_driver(prob, mesh, tables, table, lb_kind,
                                  chunk, balance_period, transfer_cap,
-                                 min_transfer, adt, loop_cache,
-                                 fused=fused_for(chunk, rung_profile,
-                                                 fused_mode))
+                                 min_transfer, adt, loop_cache)
     # mirror seed()'s capacity pre-grow rule with the warm-up target as
     # the stripe estimate: at production capacities the loop never
     # fires (limit >> min_seed); at toy capacities it keeps the warmed
@@ -1139,7 +1114,6 @@ def search(p_times: np.ndarray, lb_kind: int = 1, init_ub: int | None = None,
             from ..problems.base import HostTierUnsupported
             raise HostTierUnsupported(prob.name)
         rung_profile = None
-        fused_mode = pallas_fused.resolve_mode(None)
         if chunk is None or balance_period is None:
             # adaptive-dispatch resolution for the knobs the caller left
             # open: tuned cache entry (zero probes — the hot path must
@@ -1159,14 +1133,13 @@ def search(p_times: np.ndarray, lb_kind: int = 1, init_ub: int | None = None,
                     transfer_cap = params.transfer_cap
             if balance_period is None:
                 balance_period = params.balance_period
-            # the tuner's per-rung kernel-vs-matmul profitability mask
-            # (Params.rung_modes) rides into rung construction below
+            # the tuner's rung admission profile (Params.rung_modes)
+            # rides into rung construction below
             rung_profile = params.rung_modes
             tracelog.event("tuner.resolve", chunk=chunk,
                            balance_period=balance_period,
                            source=params.source,
                            evals_per_s=params.evals_per_s,
-                           fused=fused_mode,
                            rung_profile=bool(rung_profile))
         if tables is None:
             with tracelog.span("dist.tables"):
@@ -1214,7 +1187,7 @@ def search(p_times: np.ndarray, lb_kind: int = 1, init_ub: int | None = None,
             rungs, ladder_drivers = _ladder_plan(
                 prob, mesh, tables, table, lb_kind, chunk, balance_period,
                 transfer_cap, min_transfer, min_seed, adt, loop_cache,
-                rung_profile=rung_profile, fused_mode=fused_mode)
+                rung_profile=rung_profile)
             if len(rungs) < 2:
                 ladder_drivers = None      # chunk too small to ladder:
                 #                            plain single-driver path
@@ -1227,12 +1200,9 @@ def search(p_times: np.ndarray, lb_kind: int = 1, init_ub: int | None = None,
             driver = ladder_drivers[chunk]   # the tuned top rung — also
             #   the seed/resume/commit driver (all rungs share its limit)
         else:
-            from .ladder import fused_for
             driver = _problem_driver(prob, mesh, tables, table, lb_kind,
                                      chunk, balance_period, transfer_cap,
-                                     min_transfer, adt, loop_cache,
-                                     fused=fused_for(chunk, rung_profile,
-                                                     fused_mode))
+                                     min_transfer, adt, loop_cache)
 
         session = None
         meta_rung = None          # the checkpoint's recorded ladder rung

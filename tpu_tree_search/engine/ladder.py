@@ -44,7 +44,7 @@ from ..obs import metrics as obs_metrics
 from ..obs import tracelog
 
 __all__ = ["RungController", "rungs_for", "min_rung_for",
-           "rungs_from_profile", "fused_for",
+           "rungs_from_profile",
            "set_memory_pressure", "memory_pressure",
            "LADDER_FACTOR", "LADDER_RUNGS", "LADDER_MIN_CHUNK",
            "LADDER_MIN_CHUNK_LB2"]
@@ -109,10 +109,10 @@ def rungs_for(chunk: int, n_rungs: int = LADDER_RUNGS,
 
 def _profile_rows(profile) -> dict:
     """Normalize a per-rung tuning profile (tune/defaults
-    Params.rung_modes — a tuple of {"chunk", "winner", "ms_per_iter",
-    ...} dicts, JSON-roundtripped through the TuningCache) into a
-    chunk-keyed dict. Malformed rows are dropped, not fatal — a stale
-    cache entry must degrade to the static floors, never crash a
+    Params.rung_modes — a tuple of {"chunk", "ms_per_iter",
+    "evals_per_s"} dicts, JSON-roundtripped through the TuningCache)
+    into a chunk-keyed dict. Malformed rows are dropped, not fatal — a
+    stale cache entry must degrade to the static floors, never crash a
     boot."""
     rows = {}
     for r in (profile or ()):
@@ -123,39 +123,17 @@ def _profile_rows(profile) -> dict:
     return rows
 
 
-def _selected_ms(chunk: int, row: dict, profile, fused_mode: str):
-    """The probed ms/iter of the pipeline THIS boot would actually run
-    on the rung (fused_for's selection), not the winner's: a rung whose
-    fused rate won the probe is still a pure loss on a TTS_FUSED=0
-    boot that can only run its slower matmul rate. Per-pipeline fields
-    (ms_per_iter_{unfused,fused}) fall back to the winner's
-    ms_per_iter only for masks persisted before they existed; a
-    present-but-None fused field means that rung's fused probe FAILED
-    — the boot would run the rung fused (fused_for's never-measured
-    guard), so returning the unfused rate here would admit the rung
-    on a rate it won't run. None: the caller refuses the rung (or,
-    for the top row, falls back to the static floors)."""
-    if fused_for(chunk, profile, fused_mode) == "off":
-        return row.get("ms_per_iter_unfused") or row.get("ms_per_iter")
-    if "ms_per_iter_fused" in row:
-        return row["ms_per_iter_fused"]
-    return row.get("ms_per_iter")          # pre-field mask schema
-
-
 def rungs_from_profile(chunk: int, profile,
                        n_rungs: int = LADDER_RUNGS,
-                       factor: int = LADDER_FACTOR,
-                       fused_mode: str = "off"
+                       factor: int = LADDER_FACTOR
                        ) -> tuple[int, ...] | None:
     """MEASURED rung admission — the per-shape subsumption of the
     static per-bound floor (min_rung_for): when the tuner probed this
     shape's rung ladder (Params.rung_modes, tune/tuner), a candidate
-    rung joins the ladder iff its measured ms/iter ON THE PIPELINE
-    THIS BOOT WILL RUN (`fused_mode` + the mask through fused_for —
-    _selected_ms) beats the tuned top rung's. A rung slower per
-    iteration than the tuned chunk is a pure loss — the ladder's
-    premise; the PR-9 LB2>=256 floor encoded that statically from one
-    measurement, here it is per-shape data. Returns None (caller
+    rung joins the ladder iff its measured ms/iter beats the tuned top
+    rung's. A rung slower per iteration than the tuned chunk is a pure
+    loss — the ladder's premise; the PR-9 LB2>=256 floor encoded that
+    statically from one measurement, here it is per-shape data. Returns None (caller
     falls back to the static floors) when the profile does not cover
     the top rung."""
     rows = _profile_rows(profile)
@@ -163,7 +141,7 @@ def rungs_from_profile(chunk: int, profile,
     top = rows.get(chunk)
     if top is None:
         return None
-    top_ms = _selected_ms(chunk, top, profile, fused_mode)
+    top_ms = top.get("ms_per_iter")
     if not top_ms:
         return None
     rungs = {chunk}
@@ -172,33 +150,10 @@ def rungs_from_profile(chunk: int, profile,
         row = rows.get(c)
         if row is None:
             continue
-        ms = _selected_ms(c, row, profile, fused_mode)
+        ms = row.get("ms_per_iter")
         if ms and ms < top_ms:
             rungs.add(c)
     return tuple(sorted(rungs))
-
-
-def fused_for(chunk: int, profile, fused_mode: str) -> str:
-    """Per-rung kernel-vs-matmul selection: the probed winner when the
-    profile covers the rung, else the resolved env mode
-    (ops/pallas_fused.resolve_mode). The env master switch gates
-    everything — a profile row can only REFINE a fused-enabled run
-    (send an unprofitable rung back to the matmul pipeline), never
-    enable fused while TTS_FUSED is off; either way the node
-    accounting is bit-identical, only the per-iteration cost moves.
-
-    An "unfused" verdict counts only when the fused pipeline was
-    actually MEASURED (evals_per_s_fused recorded): a mask probed
-    under TTS_TUNE_RUNGS=1 on a matmul-only boot records "unfused"
-    for every rung by construction, and honoring it here would let a
-    never-measured mask silently disable a later TTS_FUSED=1 boot."""
-    if fused_mode == "off":
-        return "off"
-    row = _profile_rows(profile).get(int(chunk))
-    if (row is not None and row.get("winner") == "unfused"
-            and row.get("evals_per_s_fused") is not None):
-        return "off"
-    return fused_mode
 
 
 class RungController:

@@ -379,11 +379,6 @@ def serve_batch(specs: list, problem="pfsp", lb_kind: int = 1,
     min_transfer = mt if min_transfer is None else min_transfer
 
     def make_local_step(t, limit):
-        # fused stays "off" (the default) under megabatch: the batched
-        # loop vmaps the step over the instance axis, and a vmapped
-        # pallas_call has no hardware batching rule — the matmul
-        # pipeline is the batched route until the fused kernels grow a
-        # native batch dim
         return prob.make_step(t, lb_kind, chunk, 1024, limit)
 
     driver = BatchedDriver(

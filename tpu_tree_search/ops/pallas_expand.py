@@ -88,14 +88,6 @@ def _bounds_kernel(lb_kind: int, J: int, M: int, TB: int,
 def _expand_math(lb_kind: int, J: int, M: int, TB: int,
                  p_ref, tails_ref, prmu_ref, depth_ref, front_ref,
                  children_ref, aux_ref, bounds_ref):
-    # COUPLED COPY: ops/pallas_fused._fused_kernel re-implements this
-    # math (one-hot child_p, remain matmul, cf chain, prefix-swap emit,
-    # LB1 chain) inline so it can fuse prune+compact behind it — the
-    # ref-write shapes differ too much to share the body today. ANY
-    # change to the math here must be mirrored there; the fused-vs-
-    # unfused bit-parity suite (tests/test_fused.py, the CI `fused`
-    # leg) fails on divergence. Extracting a value-level shared core
-    # is named in ROADMAP item 4's hardware-round follow-ons.
     emit = children_ref is not None
     N = J * TB
     prmu = prmu_ref[:].astype(jnp.int32)          # (J, TB)
@@ -207,7 +199,7 @@ def expand_tpu(tables: BoundTables, prmu_T, depth2, front_T,
     shape, but under 64-bit mode (which the package enables for its tree
     counters) mosaic fails to legalize ANY grid index_map on this JAX
     version — grid-free full-block kernels compile fine, and at ~20
-    fused vector ops per tile the per-call overhead is noise.
+    vector ops per tile the per-call overhead is noise.
     """
     J, B = prmu_T.shape
     M = front_T.shape[0]
@@ -289,9 +281,7 @@ def kernel_shape_ok(jobs: int, eff_tile: int, lb_kind: int,
                     machines: int | None = None) -> bool:
     """The backend-independent SHAPE half of :func:`kernel_ok` — the
     tile-family rule plus the lane and scoped-VMEM caps. Split out so
-    the rule can be checked without a chip (tests/test_tpu_compile.py),
-    and so a fused hardware route, once its kernel lowers (ROADMAP A2),
-    can share it: the fused math is the expand math."""
+    the rule can be checked without a chip (tests/test_tpu_compile.py)."""
     lane_cap = MAX_TILE_LANES // 2 if lb_kind == 2 else MAX_TILE_LANES
     return (eff_tile >= min_tile(jobs)
             # lane-aligned reshapes: the kernel's (J, TB) -> (1, J*TB)

@@ -109,12 +109,6 @@ class Problem:
     # engine raises HostTierUnsupported for host_fraction > 0 on a
     # plugin that has not opted in.
     supports_host_tier: bool = False
-    # whether make_step consumes the fused Pallas route's mode
-    # (ops/pallas_fused — PFSP-only): drivers and tuning-cache keys
-    # gate their ("fused", mode) suffix on it, so a problem whose
-    # step IGNORES the mode never splits program-identical
-    # executables or optima across key variants
-    supports_fused: bool = False
     lb_kinds: tuple = (1,)
     default_lb: int = 1
     # children per popped parent; None = slots (permutation problems'
@@ -247,19 +241,14 @@ class Problem:
         return br.child_depth.astype(jnp.int32) == J
 
     def make_step(self, tables, lb_kind: int, chunk: int, tile: int,
-                  limit: int | None, fused: str = "off"):
+                  limit: int | None):
         """SearchState -> SearchState step callable. The default wires
         the generic pop/bound/prune/branch/compact pipeline
         (engine/device.generic_step); plugins with a specialized
-        (Pallas) pipeline override this — the fast-path hook. `fused`
-        is the resolved fused-kernel mode (ops/pallas_fused — "off" |
-        "hw" | "interpret", always a STATIC string by the time it gets
-        here); the generic pipeline has no fused kernels and ignores
-        it, PFSP's override threads it into the device step's gate."""
+        (Pallas) pipeline override this — the fast-path hook."""
         import functools
 
         from ..engine.device import generic_step
-        del fused
         return functools.partial(generic_step, self, tables, lb_kind,
                                  chunk, tile=tile, limit=limit)
 

@@ -105,7 +105,6 @@ class PFSPProblem(base.Problem):
     name = "pfsp"
     leaf_in_evals = True
     supports_host_tier = True
-    supports_fused = True
     lb_kinds = (0, 1, 2)
     default_lb = 1
     telemetry_labels = {"objective": "makespan"}
@@ -172,10 +171,10 @@ class PFSPProblem(base.Problem):
             yield child, depth + 1, int(bound), depth + 1 == jobs
 
     def make_step(self, tables, lb_kind: int, chunk: int, tile: int,
-                  limit: int | None, fused: str = "off"):
+                  limit: int | None):
         from ..engine.device import step
         return functools.partial(step, tables, lb_kind, chunk,
-                                 tile=tile, limit=limit, fused=fused)
+                                 tile=tile, limit=limit)
 
 
 PROBLEM = base.register(PFSPProblem())

@@ -1150,9 +1150,9 @@ class SearchServer:
                 min_seed=shape["min_seed"], mesh=mesh,
                 loop_cache=self.cache,
                 problem=shape.get("problem", "pfsp"),
-                # a tuned entry's rung_modes mask changes the ladder's
-                # rung set and per-rung fused key suffixes — the warm
-                # must build the exact keys a tuned dispatch resolves
+                # a tuned entry's rung_modes profile changes the
+                # ladder's rung set — the warm must build the exact
+                # keys a tuned dispatch resolves
                 rung_profile=shape.get("rung_profile"),
                 # the pipelined driver dispatches the donated-pool
                 # variant; warm the one this server will actually run

@@ -62,14 +62,12 @@ class Params:
     source: str = "default"
     evals_per_s: float | None = None   # the winning probe's rate, when
     #                                    source is cache/probe
-    rung_modes: tuple | None = None    # per-rung kernel-vs-matmul
-    #   profitability mask (source cache/probe only): a tuple of
-    #   {"chunk", "winner": "fused"|"unfused", "ms_per_iter",
-    #   "evals_per_s_fused", "evals_per_s_unfused"} rows for the
+    rung_modes: tuple | None = None    # rung admission profile
+    #   (source cache/probe only, gathered under TTS_TUNE_RUNGS): a
+    #   tuple of {"chunk", "ms_per_iter", "evals_per_s"} rows for the
     #   winning chunk's ladder rungs, probed below the static rung
     #   floor too — engine/ladder.rungs_from_profile admits rungs from
-    #   it (subsuming the static LB2 floor) and ladder.fused_for picks
-    #   each rung's pipeline (ops/pallas_fused vs the matmul path)
+    #   it (subsuming the static LB2 floor)
 
 
 def shape_class(jobs: int, machines: int, problem: str = "pfsp",

@@ -52,8 +52,6 @@ class ProbeResult:
     underfilled: bool       # pool < chunk at window start: the rate is
     #                         a ramp rate, not a steady-state one —
     #                         the tuner deprioritizes these
-    fused: str = "off"      # fused-kernel mode the candidate ran under
-    #                         (ops/pallas_fused: "off"|"hw"|"interpret")
 
     def to_json(self) -> dict:
         return dataclasses.asdict(self)
@@ -104,7 +102,7 @@ class ProbeHarness:
             aux0=problem.seed_aux(self.p_times, prmu0, depth0))
         state = device.run_problem(problem, self.tables, state,
                                    self.lb_kind, warm_chunk,
-                                   max_iters=warm_iters, fused="off")
+                                   max_iters=warm_iters)
         state.size.block_until_ready()
         if bool(state.overflow) or int(state.size) == 0:
             raise ProbeError(
@@ -123,13 +121,8 @@ class ProbeHarness:
 
     def measure(self, chunk: int, balance_period: int,
                 transfer_cap: int | None = None,
-                min_transfer: int | None = None,
-                fused: str = "off") -> ProbeResult:
-        """Time one candidate configuration on the warmed state.
-        `fused` selects the step pipeline the candidate runs
-        (ops/pallas_fused mode string) — the kernel-vs-matmul
-        profitability probes measure the same rung twice, once per
-        mode, on identical state."""
+                min_transfer: int | None = None) -> ProbeResult:
+        """Time one candidate configuration on the warmed state."""
         import jax
         import jax.numpy as jnp
 
@@ -154,7 +147,7 @@ class ProbeHarness:
 
         def mls(t, lim):
             return self.problem.make_step(t, self.lb_kind, chunk, 1024,
-                                          lim, fused=fused)
+                                          lim)
 
         loop = distributed.build_dist_loop(
             worker_mesh(1), self.tables, mls, balance_period,
@@ -184,7 +177,7 @@ class ProbeHarness:
             ms_per_iter=round(best / max(iters, 1) * 1e3, 4),
             window_iters=iters, evals=evals, seconds=round(best, 6),
             pool_start=self.pool,
-            underfilled=self.pool < chunk, fused=fused)
+            underfilled=self.pool < chunk)
 
 
 def measure_balance_periods(p_times: np.ndarray, lb_kind: int,

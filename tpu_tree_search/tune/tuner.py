@@ -116,32 +116,11 @@ class Autotuner:
         # A megabatched dispatch (batch > 1) appends a ("batch", B)
         # suffix: solo keys keep their exact persisted layout, and a
         # batched optimum can never be served from — or clobber — the
-        # solo entry of the same shape. The resolved fused mode joins
-        # the same way (only when ON, and only for problems whose
-        # step HAS a fused pipeline — Problem.supports_fused): the
-        # sweep picks its chunk winner on the probing boot's pipeline
-        # rates, so an optimum probed under TTS_FUSED=1 must never be
-        # replayed by a matmul boot of the same shape (or vice versa)
-        # — each mode probes and persists its own entry, unfused
-        # entries keep their pre-fused identity. A problem without a
-        # fused pipeline measures identical rates either way:
-        # suffixing it would split one optimum across two keys and
-        # re-probe the same sweep at the next boot.
+        # solo entry of the same shape.
         base = (str(problem), int(jobs), int(machines), int(lb_kind),
                 int(n_workers))
         if batch is not None and int(batch) > 1:
             base = base + ("batch", int(batch))
-        from ..ops import pallas_fused
-        mode = pallas_fused.resolve_mode(None)
-        if mode != "off":
-            from ..problems import get as _get_problem
-            try:
-                fused_capable = getattr(_get_problem(str(problem)),
-                                        "supports_fused", False)
-            except KeyError:
-                fused_capable = False
-            if fused_capable:
-                base = base + ("fused", mode)
         return base
 
     # --------------------------------------------------------- resolve
@@ -216,13 +195,11 @@ class Autotuner:
         when no steady measurement state exists (callers fall back to
         defaults).
 
-        After the chunk/period winner is picked, the winning chunk's
-        LADDER rungs are probed too — each rung once per available
-        step pipeline (fused kernel vs the matmul path,
-        ops/pallas_fused) and BELOW the static rung floor — producing
-        the per-rung profitability mask (`Params.rung_modes`) that
-        engine/ladder consumes for measured rung admission and
-        per-rung fused selection."""
+        Under TTS_TUNE_RUNGS, the winning chunk's LADDER rungs are
+        probed too after the chunk/period winner is picked — BELOW the
+        static rung floor as well — producing the rung admission
+        profile (`Params.rung_modes`) that engine/ladder consumes for
+        measured rung admission."""
         key = self.key(jobs, machines, lb_kind, n_workers, problem)
         if p_times is None:
             if problem != "pfsp":
@@ -250,30 +227,11 @@ class Autotuner:
             warm_chunk=min(self.chunks), warm_iters=self.warm_iters,
             window_iters=self.window_iters, repeats=self.repeats,
             problem=problem)
-        # the boot's step pipeline decides what the sweep must
-        # measure: when the fused route resolves on, every candidate
-        # is probed on BOTH pipelines and judged by the better rate —
-        # the chunk winner must be chosen on rates the serving boot
-        # can actually run (the same rule rung admission applies one
-        # level down, ladder._selected_ms), and fused_for will route
-        # the winner chunk to its measured winner pipeline at serve
-        # time. Probes stay PFSP-only (the fused kernels are the PFSP
-        # fast path) and interpret admits every shape; when the hw
-        # route returns (ROADMAP A2), this gate must also apply the
-        # expand kernel's shape rule per shape so a kernel-rejected
-        # shape never pays fused probes the step would run unfused.
         from ..engine import ladder as _ladder
-        from ..ops import pallas_fused
-        from ..problems import get as _get_problem
         from ..utils import config as _cfg
-        fused_mode = pallas_fused.resolve_mode(None)
-        probe_fused = (fused_mode != "off" and lb_kind in (1, 2)
-                       and getattr(_get_problem(problem),
-                                   "supports_fused", False))
         with tracelog.span("tuner.sweep", jobs=jobs, machines=machines,
                            lb_kind=lb_kind, n_workers=n_workers) as sp:
             results = []
-            fused_results = {}
             for c in self.chunks:
                 try:
                     results.append(self._probe(
@@ -285,44 +243,24 @@ class Autotuner:
                     tracelog.event("tuner.candidate_dropped", chunk=c,
                                    error=repr(e))
                     continue
-                if probe_fused:
-                    try:
-                        fused_results[c] = self._probe(
-                            harness, c, defaults.BALANCE_PERIOD_DEFAULT,
-                            fused=fused_mode)
-                    except ProbeError as e:
-                        tracelog.event("tuner.candidate_dropped",
-                                       chunk=c, fused=fused_mode,
-                                       error=repr(e))
             if not results:
                 raise ProbeError(
                     f"no chunk candidate of {self.chunks} is "
                     f"measurable at capacity {capacity}")
 
-            def best_rate(r):
-                f = fused_results.get(r.chunk)
-                return max(r.evals_per_s,
-                           f.evals_per_s if f is not None else 0.0)
-
             # steady-state rates outrank ramp rates: an underfilled
             # candidate (pool < chunk at the window start) only wins
             # when every candidate is underfilled
             filled = [r for r in results if not r.underfilled]
-            best_chunk = max(filled or results, key=best_rate)
-            # the period sweep runs on the winner chunk's WINNING
-            # pipeline — the one the boot will serve on
-            win_fm, base = "off", best_chunk
-            fbest = fused_results.get(best_chunk.chunk)
-            if fbest is not None \
-                    and fbest.evals_per_s > best_chunk.evals_per_s:
-                win_fm, base = fused_mode, fbest
-            period_results = [base]
+            best_chunk = max(filled or results,
+                             key=lambda r: r.evals_per_s)
+            period_results = [best_chunk]
             for b in self.periods:
-                if b == base.balance_period:
+                if b == best_chunk.balance_period:
                     continue
                 try:
                     period_results.append(self._probe(
-                        harness, best_chunk.chunk, b, fused=win_fm))
+                        harness, best_chunk.chunk, b))
                 except ProbeError as e:
                     tracelog.event("tuner.candidate_dropped",
                                    balance_period=b, error=repr(e))
@@ -331,64 +269,32 @@ class Autotuner:
             sp.set(chunk=winner.chunk,
                    balance_period=winner.balance_period,
                    evals_per_s=winner.evals_per_s,
-                   probes=len(results) + len(fused_results)
-                   + len(period_results) - 1)
+                   probes=len(results) + len(period_results) - 1)
 
-            # --- per-rung kernel-vs-matmul profitability mask: probe
+            # --- rung admission profile: under TTS_TUNE_RUNGS, probe
             # the winning chunk's LADDER rungs — below the static rung
             # floor too (min_chunk=1), since measured admission
             # (engine/ladder.rungs_from_profile) subsumes the floor —
-            # once per available step pipeline on the same warmed
-            # state. The mask persists with the winner and decides
-            # each rung's fused-vs-matmul dispatch at serve time.
-            # Probed only when there is a pipeline CHOICE to record
-            # (the fused route resolves on) or the operator asks
-            # (TTS_TUNE_RUNGS) — each rung is an extra compile, and a
-            # matmul-only boot gains nothing from paying several of
-            # them per shape (ladder admission then uses the static
-            # floors, exactly the pre-mask behavior).
+            # on the same warmed state. The profile persists with the
+            # winner. Off by default: each rung is an extra compile,
+            # and without a profile ladder admission uses the static
+            # floors.
             rung_modes = []
-            memo = {(r.chunk, r.balance_period, r.fused): r
-                    for r in results + list(fused_results.values())
-                    + period_results}
+            memo = {(r.chunk, r.balance_period): r
+                    for r in results + period_results}
             rungs = (_ladder.rungs_for(winner.chunk, min_chunk=1)
-                     if probe_fused or _cfg.env_flag("TTS_TUNE_RUNGS")
-                     else ())
+                     if _cfg.env_flag("TTS_TUNE_RUNGS") else ())
             for c in rungs:
-                rows = {}
-                for fm in ("off",) + ((fused_mode,) if probe_fused
-                                      else ()):
-                    k = (c, winner.balance_period, fm)
-                    try:
-                        rows[fm] = memo.get(k) or self._probe(
-                            harness, c, winner.balance_period,
-                            fused=fm)
-                    except ProbeError as e:
-                        tracelog.event("tuner.candidate_dropped",
-                                       chunk=c, fused=fm,
-                                       error=repr(e))
-                if "off" not in rows:
+                try:
+                    r = memo.get((c, winner.balance_period)) \
+                        or self._probe(harness, c, winner.balance_period)
+                except ProbeError as e:
+                    tracelog.event("tuner.candidate_dropped", chunk=c,
+                                   error=repr(e))
                     continue
-                ru = rows["off"]
-                rf = rows.get(fused_mode) if probe_fused else None
-                win = ("fused" if rf is not None
-                       and rf.evals_per_s > ru.evals_per_s
-                       else "unfused")
-                best_r = rf if win == "fused" else ru
-                rung_modes.append({
-                    "chunk": int(c), "winner": win,
-                    "ms_per_iter": best_r.ms_per_iter,
-                    # per-pipeline rates too: rung ADMISSION must judge
-                    # the pipeline a consuming boot actually runs
-                    # (ladder._selected_ms) — a fused-won rung read by
-                    # a TTS_FUSED=0 boot runs its unfused rate
-                    "ms_per_iter_unfused": ru.ms_per_iter,
-                    "ms_per_iter_fused":
-                        rf.ms_per_iter if rf is not None else None,
-                    "evals_per_s_unfused": ru.evals_per_s,
-                    "evals_per_s_fused":
-                        rf.evals_per_s if rf is not None else None,
-                })
+                rung_modes.append({"chunk": int(c),
+                                   "ms_per_iter": r.ms_per_iter,
+                                   "evals_per_s": r.evals_per_s})
         sweep_s = time.perf_counter() - t0
         if self._probe_h is not None:
             self._probe_h.observe(sweep_s)
@@ -402,8 +308,7 @@ class Autotuner:
             "sweep_seconds": round(sweep_s, 3),
             "rung_modes": rung_modes,
             "probes": [r.to_json()
-                       for r in results + list(fused_results.values())
-                       + period_results[1:]],
+                       for r in results + period_results[1:]],
         }
         if self.cache is not None:
             self.cache.store(key, payload,
@@ -418,8 +323,8 @@ class Autotuner:
         return params
 
     def _probe(self, harness: ProbeHarness, chunk: int,
-               balance_period: int, fused: str = "off"):
-        r = harness.measure(chunk, balance_period, fused=fused)
+               balance_period: int):
+        r = harness.measure(chunk, balance_period)
         with self._lock:
             self.probes_run += 1
             self.ledger.append(r.to_json())

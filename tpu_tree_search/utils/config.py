@@ -500,15 +500,6 @@ KNOBS: dict[str, Knob] = _knob_table(
     Knob("TTS_DEBUG_STEP", "flag", False,
          "compile jax.debug taps into the device step (trace-time "
          "flag; debug builds only)"),
-    Knob("TTS_FUSED", "flag", False,
-         "fused Pallas bound+prune+compact route (ops/pallas_fused): "
-         "pruned children never touch HBM; static per executable, "
-         "bit-identical counts on/off. On a TPU backend it raises: "
-         "Mosaic cannot lower the kernels' sort (ROADMAP A2)"),
-    Knob("TTS_FUSED_INTERPRET", "flag", False,
-         "run the fused kernels under the Pallas interpreter on "
-         "non-TPU backends (the CI kernel-logic leg; no effect on "
-         "TPU)"),
     # --- resilience
     Knob("TTS_RETRY_ATTEMPTS", "int", RETRY_ATTEMPTS_DEFAULT,
          "in-place retries of transient I/O / dispatch errors"),
@@ -550,9 +541,9 @@ KNOBS: dict[str, Knob] = _knob_table(
     Knob("TTS_TUNE_WARM", "int", TUNE_WARM_ITERS_DEFAULT,
          "warm-up iterations before a probe's measured window"),
     Knob("TTS_TUNE_RUNGS", "flag", False,
-         "tune(): probe the winner's ladder rungs for the per-rung "
-         "profitability mask even when the fused route is off "
-         "(matmul-only rung admission data; extra compiles per probe)"),
+         "tune(): probe the winner's ladder rungs for the rung "
+         "admission profile the chunk ladder reads (extra compiles "
+         "per probe)"),
     # --- observability
     Knob("TTS_TRACE_FILE", "str", None,
          "flight-recorder JSONL sink path (unset = ring buffer only)"),
@@ -769,8 +760,8 @@ KNOBS: dict[str, Knob] = _knob_table(
          "enough that runs span many segments — the race only saves "
          "bound evals when losers cancel mid-tree)", "bench"),
     Knob("TTS_BENCH_HBM", "flag", True,
-         "bench: emit the step-HBM-bytes row (fused-mode channel; "
-         "compiled-loop memory_analysis temp bytes on every backend "
+         "bench: emit the step-HBM-bytes row (compiled-loop "
+         "memory_analysis temp bytes on every backend "
          "— a live peak-bytes delta reads ~0 once the warm run "
          "establishes the lifetime high-water)",
          "bench"),
