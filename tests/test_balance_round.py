@@ -222,24 +222,49 @@ def test_block_starts_stay_inside_the_pool_at_the_limit():
 
 def test_exchange_plan_steal_half_threshold():
     """The flagship's defaults (chunk 65536, 20x20, 4 chips, -m 25): a
-    chip donates half its surplus once it holds 2*m = 50 nodes above
-    the mean, where the former 2*chunk threshold moved nothing."""
+    chip donates its whole surplus once it holds 2*m = 50 nodes above
+    the mean (the reference's steal threshold), where the former 2*chunk
+    threshold moved nothing."""
     cap, min_transfer = distributed.balance_defaults(
         65536, 20, 20, 4, 25, aux_itemsize=2)
     assert (cap, min_transfer) == (65536, 50)
     # mean 1000, one donor 150 above it, one receiver 150 below
     at = np.asarray(bal.exchange_plan(
         jnp.asarray([1150, 1000, 1000, 850], jnp.int32), cap, min_transfer))
-    assert at[0].sum() == 75 and at[0, 3] == 75 and at.sum() == 75
+    assert at[0].sum() == 150 and at[0, 3] == 150 and at.sum() == 150
     below = np.asarray(bal.exchange_plan(
         jnp.asarray([1049, 1000, 1000, 951], jnp.int32), cap, min_transfer))
     assert below.sum() == 0
     edge = np.asarray(bal.exchange_plan(
         jnp.asarray([1050, 1000, 1000, 950], jnp.int32), cap, min_transfer))
-    assert edge[0, 3] == 25 and edge.sum() == 25
+    assert edge[0, 3] == 50 and edge.sum() == 50
     old = np.asarray(bal.exchange_plan(
         jnp.asarray([1150, 1000, 1000, 850], jnp.int32), cap, 2 * 65536))
     assert old.sum() == 0
+
+
+@pytest.mark.parametrize("n_dev", [2, 4, 8])
+def test_exchange_plan_fills_every_chip_to_the_mean(n_dev):
+    """With the cap at least the largest deficit and no threshold, one
+    round water-fills: receivers end at the mean, donors keep at most
+    the remainder of the mean's division above it, no row is lost."""
+    rng = np.random.default_rng(n_dev)
+    plan_fn = jax.jit(lambda s, cap: bal.exchange_plan(s, cap, 0),
+                      static_argnums=1)
+    for hi in (8, 100, 5000) * 40:
+        sizes = rng.integers(0, hi + 1, n_dev).astype(np.int32)
+        total = int(sizes.sum())
+        mean, rem = total // n_dev, total % n_dev
+        cap = max(int((mean - sizes).max()), 1)
+        plan = np.asarray(plan_fn(jnp.asarray(sizes), cap))
+        assert (plan >= 0).all() and np.diag(plan).sum() == 0
+        after = sizes - plan.sum(axis=1) + plan.sum(axis=0)
+        assert after.sum() == total
+        assert (after >= mean).all() and (after <= mean + rem).all()
+        recv, donor = sizes < mean, sizes >= mean
+        assert (after[recv] == mean).all()
+        assert (plan[recv].sum() == 0) and (plan[:, donor].sum() == 0)
+        assert (after[donor] <= sizes[donor]).all()
 
 
 def test_transfer_cap_is_one_chunk_within_the_byte_budget():
@@ -252,7 +277,8 @@ def test_transfer_cap_is_one_chunk_within_the_byte_budget():
 
 
 # ta003 LB2 at UB = opt on 4 workers with the default knobs: the largest
-# worker's tree over the mean (1.33 on the CPU mesh). Without balancing
+# worker's tree over the mean (1.23 on the CPU mesh; 1.33 when a donor
+# gave half its surplus). Without balancing
 # (an unreachable donor threshold) one worker explores 1.86x the mean.
 SPREAD_BOUND = 1.4
 
@@ -272,6 +298,21 @@ def test_default_knobs_spread_a_skewed_tree():
     off = distributed.search(p, min_transfer=2**30, **kw)
     assert off.explored_tree == 80062
     assert _tree_over_mean(off) > 1.5
+
+
+def test_whole_surplus_spreads_ta003():
+    """ta003 LB2 at UB = opt, chunk 512, 4 workers, -m 8: the round
+    fills every chip to the mean, so the largest chip's tree stays near
+    the mean and the search ends in fewer iterations. Halving the
+    surplus instead read 1.371 and 56 iterations here."""
+    p = taillard.processing_times(3)
+    res = distributed.search(p, lb_kind=2,
+                             init_ub=taillard.optimal_makespan(3),
+                             n_devices=D, chunk=512, capacity=1 << 16,
+                             min_seed=8)
+    assert (res.explored_tree, res.best) == (80062, 1081)
+    assert _tree_over_mean(res) <= 1.2
+    assert int(res.per_device["iters"].max()) <= 50
 
 
 class _Captured(Exception):

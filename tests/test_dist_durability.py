@@ -33,8 +33,9 @@ def test_exchange_plan_multi_receiver():
     assert plan[0].sum() > 0
     assert (plan[0] > 0).sum() >= 2        # multiple receivers
     assert plan[0, 0] == 0                 # no self-flow
-    # donors never give more than half their surplus
-    assert plan[0].sum() <= (100 - 25) // 2
+    # every receiver below the mean is filled, the donor ends at it
+    np.testing.assert_array_equal(plan[0, 1:], [25, 25, 25])
+    assert 100 - plan[0].sum() == 25
 
 
 def test_exchange_plan_balanced_is_empty():

@@ -9,7 +9,8 @@ on a 4-device mesh of the virtual CPU devices:
 - same-shape searches in one process lower their loop once;
 - the search's spans: `dist.tables` and `dist.seed` inside
   `request.prepare`, `engine.fetch` after the run, and the
-  `engine.complete` event's balance counters.
+  `engine.complete` event's balance counters, `recv_per_steal` (rows per
+  receiving round) beside `transfer_cap` among them.
 """
 
 import functools
@@ -234,6 +235,10 @@ def test_one_shot_search_spans_its_seed_and_fetch(log):
     assert done["tree_max_over_mean"] == pytest.approx(
         trees.max() / trees.mean())
     assert done["tree"] == res.explored_tree
+    recv, steals = res.per_device["recv"], res.per_device["steals"]
+    assert steals.sum() > 0
+    assert done["recv_per_steal"] == pytest.approx(recv.sum() / steals.sum())
+    assert done["transfer_cap"] == 64      # one chunk per pair
 
 
 def test_segmented_search_keeps_its_segment_spans(log):

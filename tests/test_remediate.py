@@ -112,13 +112,14 @@ def test_stall_remediation_full_lifecycle(fresh_obs, tmp_path,
         # straight into segments (a cold compile would eat the drill's
         # timing budget, not change its semantics)
         warm = srv.submit(SearchRequest(
-            p_times=inst.p_times, lb_kind=1, segment_iters=16, **KW))
+            p_times=inst.p_times, lb_kind=1, segment_iters=8, **KW))
         assert srv.result(warm, timeout=300).state == "DONE"
-        # wedge EARLY (segment 2 of a ~5-segment solve) so real work
-        # remains after the preempt — a wedge in the last segment
-        # would let completion win the race against the stop flag
+        # wedge EARLY (segment 2 of a 4-segment solve: 32 iterations)
+        # so real work remains after the preempt — a wedge in the last
+        # segment would let completion win the race against the stop
+        # flag
         rid = srv.submit(SearchRequest(
-            p_times=inst.p_times, lb_kind=1, segment_iters=16,
+            p_times=inst.p_times, lb_kind=1, segment_iters=8,
             checkpoint_every=1, faults="wedge_executor=2:4.0", **KW))
         rec = srv.result(rid, timeout=300)
         assert rec.state == "DONE", (rec.state, rec.error)

@@ -83,9 +83,11 @@ def balance_defaults(chunk: int, jobs: int, machines: int, n_dev: int,
       BALANCE_BYTE_BUDGET bounds for wide shapes. `aux_itemsize` is the
       pool aux dtype's width (device.aux_dtype).
     - `min_transfer`, the donor threshold: 2 * min_seed nodes above the
-      mean, the reference's steal-half rule (a victim gives half its
-      pool when it holds at least ratio*m nodes, popBackBulk,
-      Pool_atom.c:154-178, ratio 2, m the `-m` warm-up size)."""
+      mean, the reference's steal threshold (a victim is robbed once it
+      holds at least ratio*m nodes, popBackBulk, Pool_atom.c:154-178,
+      ratio 2, m the `-m` warm-up size). A donor past it gives its whole
+      surplus down to the mean, not the reference's half: the plan sees
+      every worker's size (parallel/balance.exchange_plan)."""
     bytes_per_col = 2 * jobs + aux_itemsize * machines + 2
     budget_cols = BALANCE_BYTE_BUDGET // (bytes_per_col * max(n_dev, 1))
     return max(min(chunk, budget_cols), 1), 2 * min_seed
@@ -202,7 +204,8 @@ def block_starts(plan: jax.Array, me, size) -> tuple[jax.Array, jax.Array]:
 
 def _balance_round(s: SearchState, transfer_cap: int,
                    min_transfer: int, limit: int) -> SearchState:
-    """One collective steal-half exchange (see parallel/balance.py).
+    """One collective exchange that water-fills the pools to the mean
+    (see parallel/balance.py).
 
     The round is globally transactional: each worker's would-overflow
     flag (known before any data moves — a worker receives exactly
@@ -1004,7 +1007,9 @@ def search(p_times: np.ndarray, lb_kind: int = 1, init_ub: int | None = None,
     host-serialized collectives; do not retune this knob on the
     virtual mesh. `transfer_cap` / `min_transfer` left None take
     balance_defaults: one chunk per pair, and a donor threshold of
-    2 * `min_seed` nodes above the mean.
+    2 * `min_seed` nodes above the mean; a donor gives its whole surplus
+    over the mean, so a round fills every receiver to the mean up to
+    the cap.
 
     With `segment_iters`/`checkpoint_path` the loop runs in bounded
     segments with heartbeat + checkpoint/resume between them — the
@@ -1474,6 +1479,10 @@ def search(p_times: np.ndarray, lb_kind: int = 1, init_ub: int | None = None,
         balance_rounds=int(iters_dev.max()) // max(balance_period, 1),
         steals=int(steals_dev.sum()),
         moved=int(sent_dev.sum()),
+        transfer_cap=driver.transfer_cap,
+        # rows per receiving round: near transfer_cap, the cap binds
+        recv_per_steal=(float(recv_dev.sum() / steals_dev.sum())
+                        if steals_dev.sum() > 0 else 0.0),
         tree_max_over_mean=(float(tree_dev.max() / tree_dev.mean())
                             if tree_dev.sum() > 0 else 1.0),
         complete=int(sizes.sum()) == 0)

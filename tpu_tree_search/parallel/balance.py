@@ -11,11 +11,13 @@ inside the compiled loop:
 1. `all_gather` the pool sizes (every worker sees the global picture —
    the analogue of the Allgather of `local_need`).
 2. Compute a deterministic exchange plan, identically on every worker:
-   workers above the mean donate half their surplus, workers below fill
-   their deficit, matched by interval overlap so one donor can feed many
-   receivers (steal-half, the reference's `ratio=2` semantics from
-   popBackBulk, Pool_atom.c:154-178), capped by the static
-   transfer-buffer size.
+   workers above the mean donate their whole surplus, workers below
+   fill their deficit, matched by interval overlap so one donor can feed
+   many receivers, capped by the static transfer-buffer size. One round
+   water-fills every worker to the mean. The reference's pairwise
+   steal-half (popBackBulk, Pool_atom.c:154-178, ratio 2) splits one
+   victim's pool with one thief, who sees no other size; the plan sees
+   every size, so its fixed point is the mean, not half the gap.
 3. Donors pop from the top of their stack (deepest nodes — preserving the
    DFS locality the reference's popBack stealing keeps): the rows for
    each receiver are one contiguous slice, D slices of `cap` rows form
@@ -39,11 +41,11 @@ import numpy as np
 
 def waterfill_counts(total: int, m: int) -> np.ndarray:
     """(m,) per-worker pool sizes for an m-way water-filled split of
-    `total` nodes: the terminal fixed point exchange_plan's
-    surplus/deficit flow converges to (max-min difference <= 1, lower
-    worker ids carry the remainder — exactly the counts a round-robin
-    stripe `d::m` produces, matching the warm-up seeding's
-    roundRobin_distribution idiom).
+    `total` nodes: the fixed point exchange_plan's surplus/deficit
+    flow fills to (max-min difference <= 1, lower worker ids carry the
+    remainder — exactly the counts a round-robin stripe `d::m`
+    produces, matching the warm-up seeding's roundRobin_distribution
+    idiom).
 
     Host-side numpy on purpose: this is the elastic-resume half of the
     water-filling machinery (engine/checkpoint.reshard_state re-splits
@@ -61,25 +63,29 @@ def exchange_plan(sizes: jax.Array, cap: int, min_transfer: int) -> jax.Array:
     """(D, D) flow matrix: plan[d, e] nodes move d -> e this round.
 
     Pure function of the globally-known sizes vector, so every worker
-    computes the same plan. Water-filling: workers above the mean donate
-    half their surplus (steal-half, the reference's `ratio=2` semantics
-    from popBackBulk, Pool_atom.c:154-178, and its `size >= 2m` threshold
-    via `min_transfer`, by default 2 * min_seed nodes above the mean,
-    engine/distributed.balance_defaults), workers below the mean fill
-    their deficit. Donor
-    surpluses and receiver deficits are laid out as consecutive intervals
-    on one shared flow axis; plan[d, e] is the overlap of donor d's and
-    receiver e's intervals — so one hot worker feeds MANY starving
-    workers in a single round (the r-th-fullest/r-th-emptiest pairing it
-    replaces moved work to exactly one receiver per donor per round,
-    which converges D× slower on wide meshes). Per-pair flow is capped
-    at `cap`, the static width of the all_to_all transfer buffer.
+    computes the same plan. Water-filling to the mean: a worker at least
+    `min_transfer` nodes above the mean (the reference's `size >= 2m`
+    steal threshold, popBackBulk, Pool_atom.c:154-178; by default
+    2 * min_seed, engine/distributed.balance_defaults) donates its whole
+    surplus, and workers below the mean fill their deficit. Not the
+    reference's half: its thief sees one victim's pool, this plan sees
+    every size, so one round moves every worker to the mean instead of
+    halving the gap. A receiver ends at most at the mean and a donor at
+    least at it, so no pool grows past the largest size before the
+    round. Donor surpluses and receiver deficits are laid out as
+    consecutive intervals on one shared flow axis; plan[d, e] is the
+    overlap of donor d's and receiver e's intervals — so one hot worker
+    feeds MANY starving workers in a single round (the
+    r-th-fullest/r-th-emptiest pairing it replaces moved work to exactly
+    one receiver per donor per round, which converges D× slower on wide
+    meshes). Per-pair flow is capped at `cap`, the static width of the
+    all_to_all transfer buffer.
     """
     D = sizes.shape[0]
     sizes = sizes.astype(jnp.int32)
     mean = sizes.sum() // D
     surplus = jnp.where(sizes - mean >= min_transfer,
-                        (sizes - mean) // 2, 0)              # donors
+                        sizes - mean, 0)                     # donors
     deficit = jnp.clip(mean - sizes, 0, None)                # receivers
     d_lo = (jnp.cumsum(surplus) - surplus)[:, None]          # (D, 1)
     d_hi = d_lo + surplus[:, None]
